@@ -602,6 +602,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     slo_us = (tuple(float(v) for v in args.slo_us.split(","))
               if args.slo_us else None)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     out = run(fast=args.fast, backends=tuple(args.backends.split(",")),
               n_requests=args.requests, qps=args.qps, loadgen=args.loadgen,
               n_replicas=args.replicas, steps=args.steps, seed=args.seed,

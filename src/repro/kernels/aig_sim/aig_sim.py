@@ -34,11 +34,11 @@ def _kernel(f0_ref, f1_ref, pis_ref, out_ref, *, n_pis: int, n_ands: int):
     def body(i, carry):
         l0 = f0_ref[i]
         l1 = f1_ref[i]
-        v0 = pl.load(out_ref, (pl.ds(l0 >> 1, 1), slice(None)))
-        v1 = pl.load(out_ref, (pl.ds(l1 >> 1, 1), slice(None)))
+        v0 = out_ref[pl.ds(l0 >> 1, 1), :]
+        v1 = out_ref[pl.ds(l1 >> 1, 1), :]
         v0 = v0 ^ (-(l0 & 1))
         v1 = v1 ^ (-(l1 & 1))
-        pl.store(out_ref, (pl.ds(1 + n_pis + i, 1), slice(None)), v0 & v1)
+        out_ref[pl.ds(1 + n_pis + i, 1), :] = v0 & v1
         return carry
 
     jax.lax.fori_loop(0, n_ands, body, 0)

@@ -13,27 +13,29 @@ cap it far below the jnp scan oracle and below JSC-M/L-scale netlists.
 ``lut_eval_streamed_pallas`` — the streamed, tiled, double-buffered
 rebuild. The wire plane lives in HBM (``memory_space=ANY``) with rows
 renumbered level-major (``repro.synth.executor.compile_tile_plan``) so
-every tile of ``T`` slots writes one contiguous row band. The per-tile
-plan tensors (INIT masks + leaf indices) stream HBM→VMEM through
-two-slot scratch buffers: tile ``t+1``'s DMAs start before tile ``t``'s
-fold, so the plan fetch hides behind compute (the double-buffering
-idiom of the sglang-jax quad-buffered flash-attention bench). The fold
-itself is batched over the whole tile — one ``(T, 2^k, bw)`` select
-cascade instead of ``T`` scalar-indexed row walks — and the result is
-stored as a single contiguous band write.
+every tile of ``T`` slots writes one contiguous row band. Each tile's
+scalars (band base, INIT bits, leaf indices) travel as one packed
+``(R, 128)`` record (``pack_tile_meta``) that streams HBM→SMEM through a
+two-slot buffer: tile ``t+1``'s record DMA starts before tile ``t``'s
+fold, so the plan fetch hides behind compute. The band is stored with
+one contiguous DMA.
+
+Mosaic slices HBM and VMEM refs only at whole (8, 128) tiles, and only
+DMAs may touch an ``ANY`` ref. So the plane is 3-D, ``(rows, 1, W)``,
+with the word axis padded to 128 lanes: one wire row is then a
+leading-dim slice that a DMA may move, and the const-0 and PI rows are
+written by DMA too.
 
 Leaf gathering is the one mode-dependent step (``gather=``):
 
+  * ``"dma"`` — the default on every backend, and the only mode that
+    compiles for the chip: each tile's unique leaf rows are staged
+    HBM→VMEM by per-row async copies into a two-slot stage buffer, and
+    slots fold from stage-local indices read as SMEM scalars.
   * ``"fancy"`` — one vector gather ``plane[leaf_rows]`` per tile.
-    Interpreter-only: Mosaic has no arbitrary-row vector gather, but
-    the Pallas interpreter (and therefore every CPU benchmark row and
-    CI test in this repo) executes it as a single jnp gather, which is
-    where the measured ~30x win over the monolithic kernel comes from.
-  * ``"dma"`` — the TPU-shaped path: each tile's unique leaf rows are
-    staged HBM→VMEM by per-row async copies into a two-slot stage
-    buffer and slots fold from stage-local indices (SMEM scalars).
-    Bit-identical to ``"fancy"`` (the test suite runs both); used by
-    default on a real TPU backend.
+    Interpreter-only: Mosaic has no arbitrary-row vector gather and no
+    vector access to HBM. Bit-identical to ``"dma"`` (the test suite
+    runs both).
 
 Levelization guarantees every leaf lives on a strictly earlier level,
 so tile-order execution is a topological order; padded slots inside a
@@ -46,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,8 +58,9 @@ GATHER_MODES = ("fancy", "dma")
 
 
 def default_gather() -> str:
-    """``"fancy"`` under the interpreter, ``"dma"`` on a real TPU."""
-    return "fancy" if jax.default_backend() != "tpu" else "dma"
+    """``"dma"`` on every backend: the CPU suite's default path is the
+    one the chip runs (``"fancy"`` is interpreter-only)."""
+    return "dma"
 
 
 # ---------------------------------------------------------------------------
@@ -72,16 +76,15 @@ def _kernel(leaf_ref, ow_ref, tt_ref, pis_ref, out_ref, *,
 
     def body(i, carry):
         # INIT masks for slot i, broadcast over the word tile
-        tt = pl.load(tt_ref, (pl.ds(i, 1), slice(None)))         # (1, n_tt)
+        tt = tt_ref[pl.ds(i, 1), :]                               # (1, n_tt)
         state = jnp.broadcast_to(tt.reshape(n_tt, 1), (n_tt, bw))
         size = n_tt
         for j in range(k - 1, -1, -1):   # static unroll: Shannon fold
             half = size // 2
-            sel = pl.load(out_ref,
-                          (pl.ds(leaf_ref[i, j], 1), slice(None)))  # (1, bw)
+            sel = out_ref[pl.ds(leaf_ref[i, j], 1), :]            # (1, bw)
             state = (state[:half] & ~sel) | (state[half:size] & sel)
             size = half
-        pl.store(out_ref, (pl.ds(ow_ref[i], 1), slice(None)), state)
+        out_ref[pl.ds(ow_ref[i], 1), :] = state
         return carry
 
     jax.lax.fori_loop(0, n_slots, body, 0)
@@ -123,6 +126,50 @@ def lut_eval_pallas(pi_words: jax.Array, leaf_idx: jax.Array,
 # Streamed, tiled, double-buffered kernel (HBM wire plane, T slots/step)
 # ---------------------------------------------------------------------------
 
+LANES = 128   # Mosaic slices HBM/VMEM refs only at whole 128-lane tiles
+
+
+def _meta_layout(T: int, G: int, k: int):
+    """Offsets of one tile's scalar record in the ``meta`` operand:
+    out_base, then ``leaf_loc`` (T*k), ``gather_rows`` (G) and the INIT
+    bits packed as ``n_words`` int32 words per slot."""
+    if k > 6:
+        raise ValueError(f"k={k}: a slot's INIT bits must fit two words")
+    n_words = -(-(1 << k) // 32)
+    loc = 1
+    grow = loc + T * k
+    init = grow + G
+    size = init + T * n_words
+    return loc, grow, init, n_words, -(-size // LANES)
+
+
+def pack_tile_meta(tplan) -> np.ndarray:
+    """A ``TilePlan``'s per-tile scalars as the streamed kernel's
+    ``meta`` operand: (n_tiles, R, 128) int32, one record per tile laid
+    out by ``_meta_layout``. A 128-lane minor dim is what lets one DMA
+    per tile move the whole record into SMEM on the chip."""
+    n_tiles, T, k = tplan.n_tiles, tplan.tile_rows, tplan.k
+    loc, grow, init, n_words, rows = _meta_layout(T, tplan.gather_cap, k)
+    flat = np.zeros((n_tiles, rows * LANES), np.int64)
+    flat[:, 0] = tplan.out_base
+    flat[:, loc:grow] = tplan.leaf_loc.reshape(n_tiles, T * k)
+    flat[:, grow:init] = tplan.gather_rows
+    bits = (np.asarray(tplan.tt_tiles) & 1).astype(np.int64)  # (n, T, 2^k)
+    bits = np.pad(bits, ((0, 0), (0, 0), (0, n_words * 32 - bits.shape[2])))
+    words = (bits.reshape(n_tiles, T, n_words, 32)
+             << np.arange(32, dtype=np.int64)).sum(axis=3)
+    flat[:, init:init + T * n_words] = words.reshape(n_tiles, -1)
+    return flat.astype(np.uint32).view(np.int32).reshape(n_tiles, rows, LANES)
+
+
+def _init_masks(words, r):
+    """INIT masks of one LUT: ``words`` its packed INIT bits (scalars or
+    a trailing axis), ``r`` the row index of each mask -> 0 / -1 int32
+    per row (the Shannon fold's starting state)."""
+    word = words[0] if len(words) == 1 else jnp.where(r < 32, *words)
+    return -((word >> (r & 31)) & 1)
+
+
 def _tile_fold(tt_tile, ins, *, T: int, n_tt: int, k: int, bw: int):
     """Batched Shannon fold of one tile: tt_tile (T, 2^k) INIT masks,
     ins (T, k, bw) gathered leaf planes -> (T, bw) output planes."""
@@ -136,76 +183,83 @@ def _tile_fold(tt_tile, ins, *, T: int, n_tt: int, k: int, bw: int):
     return state[:, 0, :]
 
 
-def _streamed_kernel(ob_ref, pi_ref, tt_hbm, leaf_hbm, loc_hbm, grow_hbm,
-                     plane_ref, *, n_pis: int, n_tiles: int, T: int,
-                     G: int, k: int, bw: int, gather: str):
+def _streamed_kernel(pi_ref, meta_hbm, plane_ref, *, n_pis: int,
+                     n_tiles: int, T: int, G: int, k: int, bw: int,
+                     gather: str):
     n_tt = 1 << k
-    col = pl.program_id(0) * bw
-    plane_ref[0, pl.ds(col, bw)] = jnp.zeros((bw,), jnp.int32)
-    plane_ref[pl.ds(1, n_pis), pl.ds(col, bw)] = pi_ref[...]
+    loc_at, grow_at, init_at, n_words, _ = _meta_layout(T, G, k)
+    cols = pl.ds(pl.program_id(0) * bw, bw)
+
+    # The plane lives in HBM (ANY), which only DMAs may touch: write the
+    # const-0 row from a zeroed VMEM row and the PI rows straight from
+    # the VMEM input block, and land both before any tile reads them.
+    def write_head(zero, sems):
+        zero[...] = jnp.zeros((1, 1, bw), jnp.int32)
+        const_row = pltpu.make_async_copy(
+            zero, plane_ref.at[pl.ds(0, 1), :, cols], sems.at[0])
+        pi_rows = pltpu.make_async_copy(
+            pi_ref, plane_ref.at[pl.ds(1, n_pis), :, cols], sems.at[1])
+        const_row.start()
+        pi_rows.start()
+        const_row.wait()
+        pi_rows.wait()
+
+    pl.run_scoped(write_head, zero=pltpu.VMEM((1, 1, bw), jnp.int32),
+                  sems=pltpu.SemaphoreType.DMA((2,)))
 
     if gather == "fancy":
-        def body(ttbuf, lfbuf, tt_sem, lf_sem):
-            def tt_dma(slot, t):
-                return pltpu.make_async_copy(tt_hbm.at[t], ttbuf.at[slot],
-                                             tt_sem.at[slot])
+        def body(metabuf, sem):
+            def meta_dma(slot, t):
+                return pltpu.make_async_copy(meta_hbm.at[t], metabuf.at[slot],
+                                             sem.at[slot])
 
-            def lf_dma(slot, t):
-                return pltpu.make_async_copy(leaf_hbm.at[t], lfbuf.at[slot],
-                                             lf_sem.at[slot])
-
-            tt_dma(0, 0).start()
-            lf_dma(0, 0).start()
+            meta_dma(0, 0).start()
 
             def tile_step(t, carry):
                 slot = jax.lax.rem(t, 2)
-                nxt = jax.lax.rem(t + 1, 2)
 
-                # double buffering: tile t+1's plan tensors stream in
-                # while tile t folds
+                # double buffering: tile t+1's record streams in while
+                # tile t folds
                 @pl.when(t + 1 < n_tiles)
                 def _():
-                    tt_dma(nxt, t + 1).start()
-                    lf_dma(nxt, t + 1).start()
+                    meta_dma(1 - slot, t + 1).start()
 
-                tt_dma(slot, t).wait()
-                lf_dma(slot, t).wait()
-                leaves = lfbuf[slot]                        # (T, k) rows
-                ins = plane_ref[leaves, pl.ds(col, bw)]     # (T, k, bw)
-                out = _tile_fold(ttbuf[slot], ins,
-                                 T=T, n_tt=n_tt, k=k, bw=bw)
-                plane_ref[pl.ds(ob_ref[t], T), pl.ds(col, bw)] = out
+                meta_dma(slot, t).wait()
+                rec = metabuf[slot].reshape(-1)
+                leaves = rec[grow_at:grow_at + G][
+                    rec[loc_at:grow_at].reshape(T, k)]        # (T, k) rows
+                words = rec[init_at:init_at + T * n_words].reshape(
+                    T, n_words)
+                r = jnp.arange(n_tt, dtype=jnp.int32)
+                masks = _init_masks([words[:, i:i + 1]
+                                     for i in range(n_words)], r)
+                ins = plane_ref[leaves, 0, cols]             # (T, k, bw)
+                out = _tile_fold(masks, ins, T=T, n_tt=n_tt, k=k, bw=bw)
+                plane_ref[pl.ds(rec[0], T), 0, cols] = out
                 return carry
 
             jax.lax.fori_loop(0, n_tiles, tile_step, 0)
 
         pl.run_scoped(body,
-                      ttbuf=pltpu.VMEM((2, T, n_tt), jnp.int32),
-                      lfbuf=pltpu.VMEM((2, T, k), jnp.int32),
-                      tt_sem=pltpu.SemaphoreType.DMA((2,)),
-                      lf_sem=pltpu.SemaphoreType.DMA((2,)))
+                      metabuf=pltpu.VMEM((2,) + meta_hbm.shape[1:],
+                                         jnp.int32),
+                      sem=pltpu.SemaphoreType.DMA((2,)))
         return
 
     # gather == "dma": stage each tile's unique leaf rows HBM->VMEM by
     # per-row async copies; slots fold from stage-local SMEM indices.
-    def body(ttbuf, locbuf, growbuf, stage, outbuf,
-             tt_sem, loc_sem, grow_sem, stage_sem, st_sem):
-        def tt_dma(slot, t):
-            return pltpu.make_async_copy(tt_hbm.at[t], ttbuf.at[slot],
-                                         tt_sem.at[slot])
+    def body(metabuf, stage, outbuf, meta_sem, stage_sem, st_sem):
+        def scalar(slot, i):
+            return metabuf[slot, i // LANES, i % LANES]
 
-        def loc_dma(slot, t):
-            return pltpu.make_async_copy(loc_hbm.at[t], locbuf.at[slot],
-                                         loc_sem.at[slot])
-
-        def grow_dma(slot, t):
-            return pltpu.make_async_copy(grow_hbm.at[t], growbuf.at[slot],
-                                         grow_sem.at[slot])
+        def meta_dma(slot, t):
+            return pltpu.make_async_copy(meta_hbm.at[t], metabuf.at[slot],
+                                         meta_sem.at[slot])
 
         def stage_row_dma(slot, g):
-            row = growbuf[slot, g]
+            row = scalar(slot, grow_at + g)
             return pltpu.make_async_copy(
-                plane_ref.at[pl.ds(row, 1), pl.ds(col, bw)],
+                plane_ref.at[pl.ds(row, 1), :, cols],
                 stage.at[slot, pl.ds(g, 1)], stage_sem.at[slot])
 
         def issue_stage(slot):
@@ -220,67 +274,57 @@ def _streamed_kernel(ob_ref, pi_ref, tt_hbm, leaf_hbm, loc_hbm, grow_hbm,
                 return carry
             jax.lax.fori_loop(0, G, wait_one, 0)
 
-        # warmup: tile 0's plan tensors, then its staged leaf rows
-        tt_dma(0, 0).start()
-        loc_dma(0, 0).start()
-        grow_dma(0, 0).start()
-        grow_dma(0, 0).wait()
+        # warmup: tile 0's record, then its staged leaf rows (the head
+        # rows they may read have landed above)
+        meta_dma(0, 0).start()
+        meta_dma(0, 0).wait()
         issue_stage(0)
+        r = jax.lax.broadcasted_iota(jnp.int32, (n_tt, bw), 0)
 
         def tile_step(t, carry):
             slot = jax.lax.rem(t, 2)
-            nxt = jax.lax.rem(t + 1, 2)
+            nxt = 1 - slot
 
             @pl.when(t + 1 < n_tiles)
             def _():
-                tt_dma(nxt, t + 1).start()
-                loc_dma(nxt, t + 1).start()
-                grow_dma(nxt, t + 1).start()
+                meta_dma(nxt, t + 1).start()
 
             wait_stage(slot)
-            tt_dma(slot, t).wait()
-            loc_dma(slot, t).wait()
 
             def slot_step(s, carry):
-                tt_row = ttbuf[slot, s]                       # (2^k,)
-                state = jnp.broadcast_to(tt_row[:, None], (n_tt, bw))
+                state = _init_masks(
+                    [scalar(slot, init_at + s * n_words + i)
+                     for i in range(n_words)], r)             # (2^k, bw)
                 size = n_tt
                 for j in range(k - 1, -1, -1):
                     half = size // 2
-                    sel = pl.load(
-                        stage, (slot, pl.ds(locbuf[slot, s, j], 1),
-                                slice(None)))                 # (1, bw)
+                    sel = stage[slot, scalar(slot, loc_at + s * k + j)]
                     state = ((state[:half] & ~sel)
                              | (state[half:size] & sel))
                     size = half
-                pl.store(outbuf, (pl.ds(s, 1), slice(None)), state)
+                outbuf[s] = state
                 return carry
 
             jax.lax.fori_loop(0, T, slot_step, 0)
             st = pltpu.make_async_copy(
-                outbuf,
-                plane_ref.at[pl.ds(ob_ref[t], T), pl.ds(col, bw)],
+                outbuf, plane_ref.at[pl.ds(scalar(slot, 0), T), :, cols],
                 st_sem)
             st.start()
             st.wait()     # band landed: tile t+1 may stage-read any row
 
             @pl.when(t + 1 < n_tiles)
             def _():
-                grow_dma(nxt, t + 1).wait()
+                meta_dma(nxt, t + 1).wait()
                 issue_stage(nxt)
             return carry
 
         jax.lax.fori_loop(0, n_tiles, tile_step, 0)
 
     pl.run_scoped(body,
-                  ttbuf=pltpu.VMEM((2, T, n_tt), jnp.int32),
-                  locbuf=pltpu.SMEM((2, T, k), jnp.int32),
-                  growbuf=pltpu.SMEM((2, G), jnp.int32),
-                  stage=pltpu.VMEM((2, G, bw), jnp.int32),
-                  outbuf=pltpu.VMEM((T, bw), jnp.int32),
-                  tt_sem=pltpu.SemaphoreType.DMA((2,)),
-                  loc_sem=pltpu.SemaphoreType.DMA((2,)),
-                  grow_sem=pltpu.SemaphoreType.DMA((2,)),
+                  metabuf=pltpu.SMEM((2,) + meta_hbm.shape[1:], jnp.int32),
+                  stage=pltpu.VMEM((2, G, 1, bw), jnp.int32),
+                  outbuf=pltpu.VMEM((T, 1, bw), jnp.int32),
+                  meta_sem=pltpu.SemaphoreType.DMA((2,)),
                   stage_sem=pltpu.SemaphoreType.DMA((2,)),
                   st_sem=pltpu.SemaphoreType.DMA)
 
@@ -289,45 +333,43 @@ def _streamed_kernel(ob_ref, pi_ref, tt_hbm, leaf_hbm, loc_hbm, grow_hbm,
     jax.jit,
     static_argnames=("n_pis", "n_tiles", "tile_rows", "gather_cap",
                      "n_rows", "k", "block_w", "gather", "interpret"))
-def lut_eval_streamed_pallas(pi_words: jax.Array, tt_tiles: jax.Array,
-                             leaf_tiles: jax.Array, leaf_loc: jax.Array,
-                             gather_rows: jax.Array, out_base: jax.Array,
+def lut_eval_streamed_pallas(pi_words: jax.Array, meta: jax.Array,
                              n_pis: int, n_tiles: int, tile_rows: int,
                              gather_cap: int, n_rows: int, k: int,
                              block_w: int = DEFAULT_BW,
-                             gather: str = "fancy",
+                             gather: str = "dma",
                              interpret: bool = True) -> jax.Array:
     """Streamed walk over a level-major tile plan (see
-    ``repro.synth.executor.compile_tile_plan`` for the tensor layout).
+    ``repro.synth.executor.compile_tile_plan`` for the plan itself).
 
-    pi_words: (n_pis, W) int32; tt_tiles: (n_tiles, T, 2^k) int32 INIT
-    masks; leaf_tiles: (n_tiles, T, k) int32 plane-row leaf indices;
-    leaf_loc / gather_rows: the stage-local remap used by the ``"dma"``
-    gather mode; out_base: (n_tiles,) int32 first plane row of each
-    tile's contiguous output band. Returns the renumbered wire plane
+    pi_words: (n_pis, W) int32; meta: (n_tiles, R, 128) int32 per-tile
+    records from ``pack_tile_meta``. Returns the renumbered wire plane
     (n_rows, W) int32 — row 0 const-0, rows 1..n_pis the inputs, then
     one band of ``T`` rows per tile (pad rows hold 0).
+
+    Inside, the word axis is padded to whole 128-lane tiles and each
+    wire row is its own ``(1, words)`` slab of a 3-D plane, so every
+    DMA the kernel makes slices only whole tiles. A row still costs one
+    vreg row at any W <= 128, so the padding adds DMA bytes, not folds.
     """
     if gather not in GATHER_MODES:
         raise ValueError(f"unknown gather mode {gather!r} "
                          f"(expected one of {GATHER_MODES})")
     _, w = pi_words.shape
-    assert w % block_w == 0, (w, block_w)
-    grid = (w // block_w,)
-    return pl.pallas_call(
+    bw = -(-max(block_w, 1) // LANES) * LANES
+    wp = -(-w // bw) * bw
+    words = jnp.pad(pi_words, ((0, 0), (0, wp - w))).reshape(n_pis, 1, wp)
+    plane = pl.pallas_call(
         functools.partial(_streamed_kernel, n_pis=n_pis, n_tiles=n_tiles,
-                          T=tile_rows, G=gather_cap, k=k, bw=block_w,
+                          T=tile_rows, G=gather_cap, k=k, bw=bw,
                           gather=gather),
-        grid=grid,
+        grid=(wp // bw,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # out_base
-            pl.BlockSpec((n_pis, block_w), lambda i: (0, i)),    # pi block
-            pl.BlockSpec(memory_space=pltpu.ANY),                # tt tiles
-            pl.BlockSpec(memory_space=pltpu.ANY),                # leaf rows
-            pl.BlockSpec(memory_space=pltpu.ANY),                # leaf_loc
-            pl.BlockSpec(memory_space=pltpu.ANY),                # gather_rows
+            pl.BlockSpec((n_pis, 1, bw), lambda i: (0, 0, i)),   # pi block
+            pl.BlockSpec(memory_space=pl.ANY),                   # meta
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((n_rows, w), jnp.int32),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((n_rows, 1, wp), jnp.int32),
         interpret=interpret,
-    )(out_base, pi_words, tt_tiles, leaf_tiles, leaf_loc, gather_rows)
+    )(words, meta)
+    return plane[:, 0, :w]

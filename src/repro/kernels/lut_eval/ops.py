@@ -68,12 +68,12 @@ def lut_eval_streamed(pi_words: np.ndarray, tplan,
     kernel; returns the renumbered (tplan.n_rows, W) uint32 wire plane
     (use ``tplan.out_idx`` / ``tplan.row_of_wire`` to pull outputs).
 
-    pi_words: (n_pis, W) uint32. ``gather=None`` picks the fancy-gather
-    path under the interpreter and the staged-DMA path on a real TPU
-    (``lut_eval.default_gather``); ``spec.tile.block_w`` sets the word
-    tile (``tile_rows`` geometry is baked into the plan itself).
+    pi_words: (n_pis, W) uint32. ``gather=None`` picks the staged-DMA
+    path the chip runs (``lut_eval.default_gather``);
+    ``spec.tile.block_w`` sets the word tile (``tile_rows`` geometry is
+    baked into the plan itself).
     """
-    from .lut_eval import default_gather
+    from .lut_eval import default_gather, pack_tile_meta
 
     spec = DEFAULT_SPEC if spec is None else spec
     pi_words = np.ascontiguousarray(pi_words, np.uint32)
@@ -87,18 +87,12 @@ def lut_eval_streamed(pi_words: np.ndarray, tplan,
         vals = np.zeros((tplan.n_rows, w), np.uint32)
         vals[1: tplan.n_pis + 1] = pi_words
         return vals
-    bw = spec.tile.clamp_block_w(w)
-    pad = (-w) % bw
-    if pad:
-        pi_words = np.concatenate(
-            [pi_words, np.zeros((tplan.n_pis, pad), np.uint32)], axis=1)
     out = lut_eval_streamed_pallas(
         jnp.asarray(pi_words.view(np.int32)),
-        jnp.asarray(np.ascontiguousarray(tplan.tt_tiles).view(np.int32)),
-        jnp.asarray(tplan.leaf_tiles), jnp.asarray(tplan.leaf_loc),
-        jnp.asarray(tplan.gather_rows), jnp.asarray(tplan.out_base),
+        jnp.asarray(pack_tile_meta(tplan)),
         n_pis=tplan.n_pis, n_tiles=tplan.n_tiles,
         tile_rows=tplan.tile_rows, gather_cap=tplan.gather_cap,
-        n_rows=tplan.n_rows, k=tplan.k, block_w=bw, gather=gather,
+        n_rows=tplan.n_rows, k=tplan.k,
+        block_w=spec.tile.clamp_block_w(w), gather=gather,
         interpret=interpret)
-    return np.ascontiguousarray(np.asarray(out)[:, :w]).view(np.uint32)
+    return np.ascontiguousarray(np.asarray(out)).view(np.uint32)

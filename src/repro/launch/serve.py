@@ -269,6 +269,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     slo_us = (tuple(float(v) for v in args.slo_us.split(","))
               if args.slo_us else None)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "logic":
         serve_logic(args.jsc, args.train_steps, args.requests, args.pallas,
                     backend=args.backend, engine=args.engine,

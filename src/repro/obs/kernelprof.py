@@ -216,7 +216,8 @@ def profile_tile_plan(tplan, w_words: int = 128, iters: int = 3,
     import jax.numpy as jnp
 
     from repro.kernels.lut_eval.lut_eval import (default_gather,
-                                                 lut_eval_streamed_pallas)
+                                                 lut_eval_streamed_pallas,
+                                                 pack_tile_meta)
     from repro.kernels.spec import default_interpret
 
     if interpret is None:
@@ -227,22 +228,17 @@ def profile_tile_plan(tplan, w_words: int = 128, iters: int = 3,
     words = rng.integers(0, 1 << 31, (max(tplan.n_pis, 1), w_words),
                          dtype=np.int64)
     jwords = jnp.asarray(words.astype(np.int32))
-    tt = jnp.asarray(np.ascontiguousarray(tplan.tt_tiles).view(np.int32))
-    leaf = jnp.asarray(tplan.leaf_tiles)
-    loc = jnp.asarray(tplan.leaf_loc)
-    grows = jnp.asarray(tplan.gather_rows)
-    ob = jnp.asarray(tplan.out_base)
+    meta = jnp.asarray(pack_tile_meta(tplan))
     fanins = tile_plan_fanins(tplan)
 
     prefix_us = []
     for n in range(1, tplan.n_tiles + 1):
         def fn(w, n=n):
             return lut_eval_streamed_pallas(
-                w, tt[:n], leaf[:n], loc[:n], grows[:n], ob[:n],
-                n_pis=tplan.n_pis, n_tiles=n, tile_rows=tplan.tile_rows,
-                gather_cap=tplan.gather_cap, n_rows=tplan.n_rows,
-                k=tplan.k, block_w=min(128, w_words), gather=gather,
-                interpret=interpret)
+                w, meta[:n], n_pis=tplan.n_pis, n_tiles=n,
+                tile_rows=tplan.tile_rows, gather_cap=tplan.gather_cap,
+                n_rows=tplan.n_rows, k=tplan.k, block_w=min(128, w_words),
+                gather=gather, interpret=interpret)
 
         prefix_us.append(_time_us(fn, jwords, iters=iters))
     rows = []

@@ -78,7 +78,7 @@ class BitplaneAggregator:
             x = np.concatenate(
                 [x, np.zeros((self.pad_rows - x.shape[0], x.shape[1]),
                              x.dtype)])
-        codes = np.asarray(bn.net.quantize_inputs(x), np.int64)
+        codes = bn.quantize_codes(x).astype(np.int64)
         planes = np.empty((codes.shape[1] * bn.in_bits, codes.shape[0]),
                           np.uint8)
         for b in range(bn.in_bits):     # wire i*in_bits+b = bit b of code i

@@ -225,18 +225,26 @@ def build_logic_replicas(net, n_classes: int, n_replicas: int = 1,
     """Data-parallel ``LogicEngine`` replicas behind one dispatch point.
 
     Each replica owns its own engine (own jit cache / synthesized
-    netlist); with a mesh active, batches route through the
-    ``repro.dist`` sharding rules on their way in. ``engine`` selects
-    the bitplane backend's netlist executor (numpy fold or the
-    ``kernels.lut_eval`` device pipeline). ``exec_seed_us`` seeds every
-    replica's execution-time EWMA with a calibrated kernelprof estimate.
+    netlist); without a mesh, replica ``i`` lives on
+    ``jax.devices()[i % n_devices]``, so four replicas on a four-chip
+    host hold one chip each. With a mesh active, batches route through
+    the ``repro.dist`` sharding rules on their way in instead.
+    ``engine`` selects the bitplane backend's netlist executor (numpy
+    fold or the ``kernels.lut_eval`` device pipeline). ``exec_seed_us``
+    seeds every replica's execution-time EWMA with a calibrated
+    kernelprof estimate.
     """
+    import jax
+
     from repro.serving.engine import LogicEngine
 
+    devices = jax.devices()
     fns = []
-    for _ in range(n_replicas):
+    for i in range(n_replicas):
         eng = LogicEngine(net, n_classes, max_batch=max_batch,
-                          backend=backend, engine=engine)
+                          backend=backend, engine=engine,
+                          device=None if mesh is not None
+                          else devices[i % len(devices)])
         fns.append(mesh_placed(eng.scheduler_executor(), mesh))
     return ReplicaSet(fns, policy=policy, n_features=net.n_inputs,
                       exec_seed_us=exec_seed_us)

@@ -46,6 +46,10 @@ class LogicEngine:
     ``"pallas-streamed"`` through the streamed/tiled kernel (pack →
     levels → complement → argmax in one jit either way). Custom engines
     registered via ``executors.register`` work here unchanged.
+
+    ``device`` (a ``jax.Device``) pins this engine's arrays and jitted
+    calls to one device, so replicas can each own a chip; ``None`` uses
+    JAX's default device.
     """
 
     net: LogicNetwork
@@ -56,6 +60,7 @@ class LogicEngine:
     backend: str = "gather"
     engine: str = "numpy"               # bitplane netlist executor
     synth_effort: int = 1
+    device: Any = None
 
     def __post_init__(self):
         if self.use_pallas and self.backend == "gather":
@@ -64,7 +69,8 @@ class LogicEngine:
             from repro.serve.aggregate import BitplaneAggregator
             from repro.synth import compile_logic_network
             self.bitnet = compile_logic_network(
-                self.net, effort=self.synth_effort, engine=self.engine)
+                self.net, effort=self.synth_effort, engine=self.engine,
+                device=self.device)
             # padded aggregator: one quantizer shape for every flush size
             self._fn = BitplaneAggregator(self.bitnet, self.n_classes,
                                           pad_rows=self.max_batch)
@@ -77,7 +83,9 @@ class LogicEngine:
                 self.net(x, use_pallas=use_pallas)
                 [..., : self.n_classes], axis=-1))
         # warm the jit cache at the serving batch size
-        self._fn(jnp.zeros((self.max_batch, self.net.n_inputs), jnp.float32))
+        self._fn(jax.device_put(
+            np.zeros((self.max_batch, self.net.n_inputs), np.float32),
+            self.device))
 
     def exec_batch(self, x: np.ndarray) -> np.ndarray:
         """One evaluation: (B <= max_batch, F) -> (B,) int32 argmax.
@@ -93,7 +101,7 @@ class LogicEngine:
         pad = self.max_batch - n
         if pad:
             x = np.concatenate([x, np.zeros((pad, x.shape[1]), x.dtype)])
-        return np.asarray(self._fn(jnp.asarray(x)))[:n]
+        return np.asarray(self._fn(jax.device_put(x, self.device)))[:n]
 
     def classify(self, x: np.ndarray) -> np.ndarray:
         """Synchronous batched classification."""

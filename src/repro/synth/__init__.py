@@ -53,7 +53,8 @@ def synthesize(aig: AIG, effort: int = 1, k: int = 6,
 def compile_logic_network(net, effort: int = 1, k: int = 6,
                           engine: str = "numpy",
                           interpret=None,
-                          verify: bool = False) -> BitplaneNetwork:
+                          verify: bool = False,
+                          device=None) -> BitplaneNetwork:
     """LogicNetwork -> optimized mapped netlist, ready to execute.
 
     ``engine`` names an executor in the ``repro.synth.executors``
@@ -63,8 +64,9 @@ def compile_logic_network(net, effort: int = 1, k: int = 6,
     and the only engine whose wire plane may exceed VMEM).
     ``verify=True`` additionally runs the ``repro.check`` lint +
     equivalence passes over every synthesis stage (CheckFailure on the
-    first counterexample)."""
+    first counterexample). ``device`` pins the device engines to one
+    ``jax.Device``."""
     return BitplaneNetwork.from_logic_network(net, effort=effort, k=k,
                                               engine=engine,
                                               interpret=interpret,
-                                              verify=verify)
+                                              verify=verify, device=device)

@@ -155,9 +155,10 @@ class TilePlan:
     0 to their own (never-read) pad row — no per-slot validity branch
     and no dump row.
 
-    ``leaf_tiles`` holds plane-row leaf indices for the interpreter's
-    vector-gather path; ``gather_rows``/``leaf_loc`` are the staged-DMA
-    remap for the TPU path: ``gather_rows[t]`` lists the tile's unique
+    ``leaf_tiles`` holds plane-row leaf indices, the reference that
+    ``repro.check`` holds the staged remap to; ``gather_rows``/
+    ``leaf_loc`` are that remap, which the kernel reads
+    (``pack_tile_meta``): ``gather_rows[t]`` lists the tile's unique
     leaf rows (padded by re-reading row 0) and
     ``leaf_loc[t, s, j]`` is slot ``s``'s position of leaf ``j`` inside
     that staged buffer. ``row_of_wire`` maps the original executor wire
@@ -423,6 +424,10 @@ class _DeviceExecutor:
     ``out_levels`` gather and per-request argmax. Distinct batch shapes
     retrace; serving callers pin the shape (``pad_rows``) so the hot
     path compiles once.
+
+    Plan tensors and every input are committed to the network's
+    ``device`` when it has one, so each jit runs there (one replica per
+    chip); with ``device=None`` they land on JAX's default device.
     """
 
     name = "device"
@@ -436,14 +441,20 @@ class _DeviceExecutor:
         self._jnp = jnp
         self.spec = DEFAULT_SPEC if spec is None else spec
         self.interpret = self.spec.resolve_interpret(interpret)
+        self.device = bitnet.device
         self.in_bits = bitnet.in_bits
         self.out_bits = bitnet.out_bits
-        self._levels = jnp.asarray(bitnet.out_levels)
+        self._levels = self._put(np.asarray(bitnet.out_levels, np.float32))
         self._apply = jax.jit(self._apply_codes)
         self._argmax_codes = jax.jit(self._argmax_from_codes,
                                      static_argnames=("n_classes",))
         self._argmax_words = jax.jit(self._argmax_from_words,
                                      static_argnames=("n_classes",))
+
+    def _put(self, a: np.ndarray):
+        """Host array -> a jax array on this executor's device."""
+        import jax
+        return jax.device_put(np.asarray(a), self.device)
 
     # ---- jit-traced building blocks -------------------------------------
 
@@ -497,24 +508,25 @@ class _DeviceExecutor:
     # ---- host-facing API -------------------------------------------------
 
     def apply_codes(self, codes: np.ndarray) -> np.ndarray:
-        jnp = self._jnp
-        out = self._apply(jnp.asarray(np.asarray(codes), jnp.int32))
+        out = self._apply(self._put(np.asarray(codes, np.int32)))
         return np.asarray(out).astype(np.int64)
 
     def classify_codes(self, codes, n_classes: int) -> np.ndarray:
-        jnp = self._jnp
         return np.asarray(self._argmax_codes(
-            jnp.asarray(codes, jnp.int32), n_classes=n_classes))
+            self._put(np.asarray(codes, np.int32)), n_classes=n_classes))
+
+    def device_labels(self, pi_words: np.ndarray, n_classes: int):
+        """Packed PI words -> per-lane argmax labels, left on the
+        device (a jax array of ``W * 32`` labels, not yet awaited)."""
+        words = self._put(
+            np.ascontiguousarray(pi_words, np.uint32).view(np.int32))
+        return self._argmax_words(words, n_classes=n_classes)
 
     def classify_words(self, pi_words: np.ndarray, n_rows: int,
                        n_classes: int) -> np.ndarray:
         """Packed PI words straight to the device; only the per-request
         argmax labels come back (the serve aggregation hot path)."""
-        jnp = self._jnp
-        words = jnp.asarray(
-            np.ascontiguousarray(pi_words, np.uint32).view(np.int32))
-        labels = self._argmax_words(words, n_classes=n_classes)
-        return np.asarray(labels)[:n_rows]
+        return np.asarray(self.device_labels(pi_words, n_classes))[:n_rows]
 
     def classify_packed(self, pi_words: np.ndarray, n_rows: int,
                         n_classes: int) -> np.ndarray:
@@ -530,16 +542,15 @@ class _PallasExecutor(_DeviceExecutor):
     def __init__(self, bitnet: "BitplaneNetwork",
                  interpret: Optional[bool] = None, spec=None):
         super().__init__(bitnet, interpret=interpret, spec=spec)
-        jnp = self._jnp
         dp = compile_device_plan(bitnet.mapped, bitnet._plan)
         self.dp = dp
         self.n_slots = dp.n_levels * dp.level_width
-        self._leaf = jnp.asarray(dp.leaf_idx.reshape(-1, dp.k), jnp.int32)
-        self._tt = jnp.asarray(np.ascontiguousarray(
+        self._leaf = self._put(dp.leaf_idx.reshape(-1, dp.k))
+        self._tt = self._put(np.ascontiguousarray(
             dp.tt_bits.reshape(-1, 1 << dp.k)).view(np.int32))
-        self._ow = jnp.asarray(dp.out_wires.reshape(-1), jnp.int32)
-        self._out_idx = jnp.asarray(dp.out_idx, jnp.int32)
-        self._neg = jnp.asarray(np.where(dp.out_neg, -1, 0), jnp.int32)
+        self._ow = self._put(dp.out_wires.reshape(-1).astype(np.int32))
+        self._out_idx = self._put(dp.out_idx.astype(np.int32))
+        self._neg = self._put(np.where(dp.out_neg, -1, 0).astype(np.int32))
 
     def _eval_words(self, words):
         from repro.kernels.lut_eval.lut_eval import lut_eval_pallas
@@ -576,8 +587,8 @@ class _StreamedExecutor(_DeviceExecutor):
                  interpret: Optional[bool] = None, spec=None,
                  gather: Optional[str] = None, use_cache: bool = True):
         super().__init__(bitnet, interpret=interpret, spec=spec)
-        jnp = self._jnp
-        from repro.kernels.lut_eval.lut_eval import default_gather
+        from repro.kernels.lut_eval.lut_eval import (default_gather,
+                                                     pack_tile_meta)
         dp = compile_device_plan(bitnet.mapped, bitnet._plan)
         if use_cache and spec is None:
             from repro.kernels.lut_eval import autotune
@@ -591,35 +602,26 @@ class _StreamedExecutor(_DeviceExecutor):
         self.dp = dp
         self.tp = tp
         self.gather = default_gather() if gather is None else gather
-        self._tt_tiles = jnp.asarray(np.ascontiguousarray(
-            tp.tt_tiles).view(np.int32))
-        self._leaf_tiles = jnp.asarray(tp.leaf_tiles)
-        self._leaf_loc = jnp.asarray(tp.leaf_loc)
-        self._gather_rows = jnp.asarray(tp.gather_rows)
-        self._out_base = jnp.asarray(tp.out_base)
-        self._out_idx = jnp.asarray(tp.out_idx, jnp.int32)
-        self._neg = jnp.asarray(np.where(tp.out_neg, -1, 0), jnp.int32)
+        self._meta = self._put(pack_tile_meta(tp))
+        self._out_idx = self._put(tp.out_idx.astype(np.int32))
+        self._neg = self._put(np.where(tp.out_neg, -1, 0).astype(np.int32))
 
     def _eval_words(self, words):
         from repro.kernels.lut_eval.lut_eval import lut_eval_streamed_pallas
         jnp = self._jnp
         tp = self.tp
         w = words.shape[1]
-        bw = self.spec.tile.clamp_block_w(w)
-        pad = (-w) % bw
-        if pad:
-            words = jnp.pad(words, ((0, 0), (0, pad)))
         if tp.n_tiles == 0 or tp.n_pis == 0:     # constant network
-            plane = jnp.zeros((tp.n_rows, words.shape[1]), jnp.int32)
+            plane = jnp.zeros((tp.n_rows, w), jnp.int32)
             plane = plane.at[1: tp.n_pis + 1].set(words)
         else:
             plane = lut_eval_streamed_pallas(
-                words, self._tt_tiles, self._leaf_tiles, self._leaf_loc,
-                self._gather_rows, self._out_base, n_pis=tp.n_pis,
-                n_tiles=tp.n_tiles, tile_rows=tp.tile_rows,
-                gather_cap=tp.gather_cap, n_rows=tp.n_rows, k=tp.k,
-                block_w=bw, gather=self.gather, interpret=self.interpret)
-        return (plane[self._out_idx] ^ self._neg[:, None])[:, :w]
+                words, self._meta, n_pis=tp.n_pis, n_tiles=tp.n_tiles,
+                tile_rows=tp.tile_rows, gather_cap=tp.gather_cap,
+                n_rows=tp.n_rows, k=tp.k,
+                block_w=self.spec.tile.clamp_block_w(w),
+                gather=self.gather, interpret=self.interpret)
+        return plane[self._out_idx] ^ self._neg[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -639,10 +641,14 @@ class BitplaneNetwork:
     with ``executors.register``). Unknown names raise
     ``UnknownEngineError`` listing the registered engines. All engines
     are bit-identical on every reachable input.
+
+    ``device`` (a ``jax.Device``) pins the device engines' plan tensors,
+    jitted calls and input quantization to that device; ``None`` leaves
+    them on JAX's default device.
     """
 
     def __init__(self, net, mapped: MappedNetwork, engine: str = "numpy",
-                 interpret: Optional[bool] = None, spec=None):
+                 interpret: Optional[bool] = None, spec=None, device=None):
         from .executors import get as _get_engine
         self._factory = _get_engine(engine)    # typed error on bad name
         self.net = net
@@ -650,6 +656,7 @@ class BitplaneNetwork:
         self.engine = engine
         self.interpret = interpret
         self.spec = spec
+        self.device = device
         # lazy import: this module loads during repro.serve/__init__
         # (via aggregate), while repro.obs pulls repro.serve.metrics —
         # a module-level import here would close an import cycle
@@ -657,7 +664,6 @@ class BitplaneNetwork:
         self.tracer = NULL_TRACER
         self._plan = _compile_plan(mapped)
         self._exec = None
-        self._device_compat: Optional[_PallasExecutor] = None
         self.in_bits = net.in_spec.code_bits
         last = net.layers[-1]
         self.out_bits = last.out_spec.code_bits
@@ -667,12 +673,13 @@ class BitplaneNetwork:
     def from_logic_network(cls, net, effort: int = 1, k: int = 6,
                            engine: str = "numpy",
                            interpret: Optional[bool] = None,
-                           verify: bool = False) -> "BitplaneNetwork":
+                           verify: bool = False,
+                           device=None) -> "BitplaneNetwork":
         from . import synthesize        # lazy: package init imports us
         from .from_sop import network_to_aig
         bn = cls(net, synthesize(network_to_aig(net), effort=effort, k=k,
                                  verify=verify),
-                 engine=engine, interpret=interpret)
+                 engine=engine, interpret=interpret, device=device)
         if verify:
             from repro.check.pipeline import preflight
             from repro.check.report import require_ok
@@ -687,21 +694,12 @@ class BitplaneNetwork:
                                        spec=self.spec)
         return self._exec
 
-    @property
-    def device(self) -> _DeviceExecutor:
-        """The fused on-device executor (built lazily on first use).
-
-        For device engines this is ``executor`` itself; under the numpy
-        engine it builds the monolithic pallas executor on the side, so
-        callers that want a device path regardless of the configured
-        engine (profiling, checks) keep working."""
-        ex = self.executor
-        if isinstance(ex, _DeviceExecutor):
-            return ex
-        if self._device_compat is None:
-            self._device_compat = _PallasExecutor(
-                self, interpret=self.interpret, spec=self.spec)
-        return self._device_compat
+    def quantize_codes(self, x) -> np.ndarray:
+        """Real inputs -> (B, n_inputs) input codes, quantized on this
+        network's device."""
+        import jax
+        with jax.default_device(self.device):
+            return np.asarray(self.net.quantize_inputs(x))
 
     def apply_codes(self, codes: np.ndarray) -> np.ndarray:
         """(B, n_inputs) input codes -> (B, n_out_neurons) output codes."""
@@ -709,12 +707,11 @@ class BitplaneNetwork:
 
     def __call__(self, x) -> np.ndarray:
         """Real inputs -> decoded real outputs (LogicNetwork contract)."""
-        codes = np.asarray(self.net.quantize_inputs(x))
-        return self.out_levels[self.apply_codes(codes)]
+        return self.out_levels[self.apply_codes(self.quantize_codes(x))]
 
     def classify(self, x, n_classes: int) -> np.ndarray:
-        codes = np.asarray(self.net.quantize_inputs(x))
-        return self.executor.classify_codes(codes, n_classes)
+        return self.executor.classify_codes(self.quantize_codes(x),
+                                            n_classes)
 
     def classify_packed(self, pi_words: np.ndarray, n_rows: int,
                         n_classes: int) -> np.ndarray:

@@ -161,6 +161,33 @@ def test_streamed_multi_tile_levels():
                                           gather=gather))
 
 
+def test_pack_tile_meta_round_trips_the_tile_plan():
+    """The streamed kernel's per-tile record holds exactly the plan's
+    band base, staged-gather remap and INIT bits, in 128-lane rows."""
+    from repro.kernels.lut_eval.lut_eval import (LANES, _meta_layout,
+                                                 pack_tile_meta)
+    from repro.synth import compile_tile_plan
+    from repro.synth.executor import _compile_plan as cp
+    mapped = _random_mapped(4, 10, 4)
+    tp = compile_tile_plan(cp(mapped), mapped.n_pis, mapped.k, tile_rows=8)
+    T, G, k = tp.tile_rows, tp.gather_cap, tp.k
+    loc, grow, init, n_words, rows = _meta_layout(T, G, k)
+    meta = pack_tile_meta(tp)
+    assert meta.shape == (tp.n_tiles, rows, LANES)
+    assert meta.dtype == np.int32
+    flat = meta.reshape(tp.n_tiles, -1)
+    np.testing.assert_array_equal(flat[:, 0], tp.out_base)
+    np.testing.assert_array_equal(flat[:, loc:grow].reshape(tp.leaf_loc.shape),
+                                  tp.leaf_loc)
+    np.testing.assert_array_equal(flat[:, grow:init], tp.gather_rows)
+    words = flat[:, init:init + T * n_words].view(np.uint32).reshape(
+        tp.n_tiles, T, n_words)
+    r = np.arange(1 << k)
+    bits = (words[:, :, r // 32] >> (r % 32)) & 1
+    np.testing.assert_array_equal(bits, tp.tt_tiles & 1)
+    assert not flat[:, init + T * n_words:].any()
+
+
 def test_tile_plan_structure():
     from repro.synth import compile_tile_plan
     from repro.synth.executor import _compile_plan as cp

@@ -1,0 +1,141 @@
+"""The served path's kernels compile for a TPU v5e at real widths.
+
+Nothing runs here: each test lowers and compiles for a described (not
+attached) v5e chip, which refuses what the Pallas interpreter lets
+through — slices not aligned to the (8, 128) tiling, vector access to
+HBM refs, too much SMEM or VMEM. The topology is described inside a
+fixture, never at import, so that only the worker given this file loads
+the TPU compiler.
+"""
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.lut_eval.lut_eval import (_meta_layout, lut_eval_pallas,
+                                             lut_eval_streamed_pallas)
+
+K = 6
+N_CLASSES = 5
+# JSC-S as served: 16 inputs x 2 bits = 32 PIs, ~90 LUTs in 4 levels;
+# loadgen's max_batch=256 rows pack into W = 8 words.
+JSC_S = dict(n_pis=32, n_slots=96, n_tiles=4, gather_cap=96)
+# JSC-L-sized tile plan: 16 inputs x 3 bits = 48 PIs, ~12k LUT slots.
+JSC_L = dict(n_pis=48, n_tiles=380, gather_cap=192)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:        # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+def _custom_call_hlo(compiled) -> str:
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("w", [8, 128])
+def test_monolithic_kernel_compiles_jsc_s(one_chip, w):
+    n, s = JSC_S["n_pis"], JSC_S["n_slots"]
+    n_wires = 1 + n + s
+
+    def run(words, leaf, tt, ow):
+        return lut_eval_pallas(words, leaf, tt, ow, n_pis=n, n_slots=s,
+                               n_wires=n_wires, k=K, block_w=min(w, 128),
+                               interpret=False)
+
+    compiled = jax.jit(run).lower(
+        _shape(one_chip, n, w), _shape(one_chip, s, K),
+        _shape(one_chip, s, 1 << K), _shape(one_chip, s)).compile()
+    _custom_call_hlo(compiled)
+
+
+def _compile_streamed(sharding, n_pis, n_tiles, gather_cap, w,
+                      tile_rows=32):
+    n_rows = 1 + n_pis + n_tiles * tile_rows
+    rows = _meta_layout(tile_rows, gather_cap, K)[-1]
+
+    def run(words, meta):
+        return lut_eval_streamed_pallas(
+            words, meta, n_pis=n_pis, n_tiles=n_tiles, tile_rows=tile_rows,
+            gather_cap=gather_cap, n_rows=n_rows, k=K, block_w=min(w, 128),
+            gather="dma", interpret=False)
+
+    return jax.jit(run).lower(_shape(sharding, n_pis, w),
+                              _shape(sharding, n_tiles, rows, 128)).compile()
+
+
+@pytest.mark.parametrize("w", [8, 128])
+def test_streamed_dma_kernel_compiles_jsc_s(one_chip, w):
+    """The serving shape (W = 8) and a lane-aligned one (W = 128)."""
+    _custom_call_hlo(_compile_streamed(
+        one_chip, JSC_S["n_pis"], JSC_S["n_tiles"], JSC_S["gather_cap"], w))
+
+
+@pytest.mark.parametrize("w", [8, 256])
+def test_streamed_dma_kernel_compiles_jsc_l(one_chip, w):
+    """A JSC-L-sized plan: the monolithic kernel's SMEM leaf table does
+    not fit at this size, so the streamed engine is its only path."""
+    _custom_call_hlo(_compile_streamed(
+        one_chip, JSC_L["n_pis"], JSC_L["n_tiles"], JSC_L["gather_cap"], w))
+
+
+def _random_netlist(n_pis: int, n_ands: int, n_outs: int, seed: int = 0):
+    from repro.synth import AIG, synthesize
+    rng = np.random.default_rng(seed)
+    aig = AIG(n_pis)
+    lits = [2 * (i + 1) for i in range(n_pis)]
+    for _ in range(n_ands):
+        a, b = rng.choice(len(lits), 2, replace=False)
+        lits.append(aig.and2(lits[a] ^ int(rng.integers(2)),
+                             lits[b] ^ int(rng.integers(2))))
+    tail = lits[n_pis + n_ands // 2:]
+    aig.outputs = [int(tail[i]) for i in rng.choice(len(tail), n_outs,
+                                                    replace=False)]
+    return synthesize(aig)
+
+
+def test_streamed_executor_classify_compiles(one_chip):
+    """The fused classify jit the aggregator calls (pack -> streamed
+    kernel -> complement -> decode -> argmax) at the serving shape."""
+    from repro.synth.executor import _compile_plan, _StreamedExecutor
+
+    mapped = _random_netlist(JSC_S["n_pis"], 600, N_CLASSES * 3)
+    assert mapped.n_luts > 32               # more than one tile
+    # the executor reads only these attributes of its BitplaneNetwork
+    bitnet = types.SimpleNamespace(
+        mapped=mapped, _plan=_compile_plan(mapped), in_bits=2, out_bits=3,
+        out_levels=np.arange(8, dtype=np.float32), device=None)
+    ex = _StreamedExecutor(bitnet, interpret=False, use_cache=False)
+    assert ex.gather == "dma"
+    compiled = ex._argmax_words.lower(
+        _shape(one_chip, JSC_S["n_pis"], 8), n_classes=N_CLASSES).compile()
+    _custom_call_hlo(compiled)
