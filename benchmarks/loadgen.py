@@ -128,21 +128,17 @@ def replay_busy_server(arrivals_us: np.ndarray,
 # Scheduler-driven load generators
 # ---------------------------------------------------------------------------
 
-def _wire_online(sched, executor, sinks, profiler) -> None:
-    """Attach streaming sinks (windowed metrics / burn monitors) and the
-    online profiler to a freshly built scheduler."""
+def _wire_sinks(sched, sinks) -> None:
+    """Attach streaming sinks (windowed metrics / burn monitors) to a
+    freshly built scheduler."""
     for s in (sinks or []):
         sched.metrics.add_sink(s)
-    if profiler is not None:
-        profiler.attach(scheduler=sched)
-        if hasattr(executor, "reseed_exec_estimate"):   # ReplicaSet
-            profiler.attach(replicas=executor)
 
 
 def run_open_loop(executor, xs: np.ndarray, qps: float, seed: int = 0,
                   max_batch: int = 256, max_wait_us: float = 200.0,
                   tracer=None, exec_estimate_us: Optional[float] = None,
-                  sinks: Optional[Sequence] = None, profiler=None):
+                  sinks: Optional[Sequence] = None):
     """Real-time Poisson open loop into a threaded scheduler."""
     from repro.serve import MicroBatchScheduler, RequestRejected, SchedConfig
 
@@ -150,7 +146,7 @@ def run_open_loop(executor, xs: np.ndarray, qps: float, seed: int = 0,
     cfg = SchedConfig(max_batch=max_batch, max_wait_us=max_wait_us,
                       max_queue=2 * n, exec_estimate_us=exec_estimate_us)
     sched = MicroBatchScheduler(executor, cfg, tracer=tracer)
-    _wire_online(sched, executor, sinks, profiler)
+    _wire_sinks(sched, sinks)
     sched.start()
     arrivals = poisson_arrivals_us(n, qps, seed)
     futs: List = [None] * n
@@ -172,7 +168,7 @@ def run_slo_lanes(executor, xs: np.ndarray, qps: float,
                   max_batch: int = 256, max_wait_us: float = 200.0,
                   tight_every: int = 4, tracer=None,
                   exec_estimate_us: Optional[float] = None,
-                  sinks: Optional[Sequence] = None, profiler=None):
+                  sinks: Optional[Sequence] = None):
     """Two-lane SLO open loop: every ``tight_every``-th request rides
     lane 0 (tight SLO), the rest lane 1 (loose SLO). Deadlines default
     from the per-lane table; expired requests are shed with a typed
@@ -186,7 +182,7 @@ def run_slo_lanes(executor, xs: np.ndarray, qps: float,
                       lane_slo_us=tuple(slo_us),
                       exec_estimate_us=exec_estimate_us)
     sched = MicroBatchScheduler(executor, cfg, tracer=tracer)
-    _wire_online(sched, executor, sinks, profiler)
+    _wire_sinks(sched, sinks)
     sched.start()
     arrivals = poisson_arrivals_us(n, qps, seed)
     lanes = np.where(np.arange(n) % tight_every == 0, 0,
@@ -214,7 +210,7 @@ def run_slo_lanes(executor, xs: np.ndarray, qps: float,
 def run_closed_loop(executor, xs: np.ndarray, concurrency: int = 32,
                     max_batch: int = 256, max_wait_us: float = 200.0,
                     tracer=None, exec_estimate_us: Optional[float] = None,
-                    sinks: Optional[Sequence] = None, profiler=None):
+                    sinks: Optional[Sequence] = None):
     """Fixed in-flight submit→wait workers (peak throughput probe)."""
     from repro.serve import MicroBatchScheduler, SchedConfig
 
@@ -222,7 +218,7 @@ def run_closed_loop(executor, xs: np.ndarray, concurrency: int = 32,
     cfg = SchedConfig(max_batch=max_batch, max_wait_us=max_wait_us,
                       max_queue=2 * n, exec_estimate_us=exec_estimate_us)
     sched = MicroBatchScheduler(executor, cfg, tracer=tracer)
-    _wire_online(sched, executor, sinks, profiler)
+    _wire_sinks(sched, sinks)
     sched.start()
     results = np.full((n,), -1, np.int32)
     it = iter(range(n))
@@ -440,25 +436,14 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
         est = exec_est_us.get(b)
         executor = engines[b].scheduler_executor()
         sinks = None
-        profiler = None
         if registry is not None:
             # streaming per-lane windows for this backend's sections,
             # published into the registry (lands in trace otherData
             # and/or the caller's live /metrics endpoint)
-            from repro.obs import OnlineProfiler, WindowedMetrics
+            from repro.obs import WindowedMetrics
             wm = WindowedMetrics(window_us=250_000.0)
             wm.publish(registry, f"{b}.windows")
             sinks = [wm]
-            if est is not None and est > 0:
-                # close the calibration loop: sampled real-traffic
-                # device timings blend into the LatencyTable and
-                # re-seed the flush margin + least_slack EWMAs live
-                profiler = OnlineProfiler(lut_table, predicted_us=est,
-                                          sample_every=4)
-                profiler.publish(registry, f"{b}.online_profile")
-                agg = getattr(engines[b], "_fn", None)
-                if agg is not None and hasattr(agg, "on_device_us"):
-                    agg.on_device_us = profiler.observe
         if n_replicas > 1:              # independent data-parallel engines
             # least_slack so the slo_lanes section measures the same
             # deadline-aware dispatch the launch --sched path runs;
@@ -472,8 +457,7 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
         if loadgen in ("open", "both"):
             got, snap = run_open_loop(executor, xs, offered, seed=seed,
                                       max_batch=max_batch, tracer=tracer,
-                                      exec_estimate_us=est, sinks=sinks,
-                                      profiler=profiler)
+                                      exec_estimate_us=est, sinks=sinks)
             if registry is not None:
                 registry.register(f"{b}.open_loop",
                                   lambda snap=snap: snap)
@@ -487,7 +471,7 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
                                              seed=seed, max_batch=max_batch,
                                              tracer=tracer,
                                              exec_estimate_us=est,
-                                             sinks=sinks, profiler=profiler)
+                                             sinks=sinks)
             if registry is not None:
                 registry.register(f"{b}.slo_lanes",
                                   lambda snap=snap: snap)
@@ -507,8 +491,7 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
         if loadgen in ("closed", "both"):
             got, snap = run_closed_loop(executor, xs, max_batch=max_batch,
                                         tracer=tracer,
-                                        exec_estimate_us=est, sinks=sinks,
-                                        profiler=profiler)
+                                        exec_estimate_us=est, sinks=sinks)
             if registry is not None:
                 registry.register(f"{b}.closed_loop",
                                   lambda snap=snap: snap)
@@ -521,16 +504,6 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
             fn = getattr(engines[b], "_fn", None)
             if hasattr(fn, "publish"):          # aggregator occupancy
                 fn.publish(registry, f"{b}.aggregate")
-        if profiler is not None:
-            st = profiler.stats()
-            rec["online_profile"] = {
-                "n_observed": st["n_observed"],
-                "n_sampled": st["n_sampled"],
-                "scale": round(st["scale"], 4),
-                "estimate_us": round(st["estimate_us"], 2)}
-            print(f"[loadgen] {b}: online profile blended scale "
-                  f"{st['scale']:.3f} over {st['n_sampled']} samples "
-                  f"(estimate {st['estimate_us']:.1f}us/batch)")
         out["backends"][b] = rec
     out["argmax_identical_across_backends"] = bool(all(
         np.array_equal(direct[b], direct[backends[0]]) for b in backends))

@@ -25,8 +25,8 @@ code instead of leaving the field looking forgotten, and the lint
 rejects a field listed in both ``_GUARDED_BY`` and ``_LOCK_FREE`` as a
 conflicting annotation. Both annotations cover ``repro.serve`` and the
 shared-mutable classes of ``repro.obs`` (windowed metrics, burn-rate
-monitor, online profiler — all fed from scheduler/executor/client
-threads concurrently).
+monitor — both fed from scheduler/executor/client threads
+concurrently).
 
 **Reject-reason coverage.** Every constant on ``RejectReason`` must
 have (a) a real code path in ``repro.serve`` that raises/records it and
@@ -48,7 +48,7 @@ SERVE_DIR = _REPO_ROOT / "src" / "repro" / "serve"
 OBS_DIR = _REPO_ROOT / "src" / "repro" / "obs"
 TEST_DIR = _REPO_ROOT / "tests"
 SERVE_FILES = ("sched.py", "replica.py", "aggregate.py")
-OBS_FILES = ("window.py", "slo.py", "online.py")
+OBS_FILES = ("window.py", "slo.py")
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,12 @@ gates the properties downstream analysis leans on:
     rejects allocate a trace id but record only an ``i reject``
     instant, never an async begin;
   * **flush reasons** — any ``flush_reason`` arg must come from
-    ``repro.obs.trace.FLUSH_REASONS``;
+    ``repro.obs.trace.FLUSH_REASONS``, and a ``sched_wait`` span's
+    ``reason`` from ``WAIT_REASONS``;
+  * **batch-free spans** — ``sched_wait``, ``gc`` and ``compile``
+    (``repro.obs.analyze.UNBATCHED_SPANS``) are thread spans: they nest
+    or are disjoint like any other on their thread, and carry no
+    ``args.batch``;
   * **terminal outcomes** — every ``e request`` must state how the
     request ended (``ok``/``shed``/``error``/``shutdown``).
 
@@ -48,6 +53,16 @@ def _flush_reasons() -> Tuple[str, ...]:
     return FLUSH_REASONS
 
 
+def _wait_reasons() -> Tuple[str, ...]:
+    from repro.obs.trace import WAIT_REASONS
+    return WAIT_REASONS
+
+
+def _unbatched_spans() -> Tuple[str, ...]:
+    from repro.obs.analyze import UNBATCHED_SPANS
+    return UNBATCHED_SPANS
+
+
 def check_trace(events: Iterable, n_dropped: int = 0,
                 report: Optional[CheckReport] = None) -> CheckReport:
     """Validate a sequence of ``TraceEvent`` records (from
@@ -55,6 +70,8 @@ def check_trace(events: Iterable, n_dropped: int = 0,
     rep = report if report is not None else CheckReport("trace")
     evs = list(events)
     reasons = _flush_reasons()
+    wait_reasons = _wait_reasons()
+    unbatched = _unbatched_spans()
     truncated = n_dropped > 0
 
     def pairing_issue(code: str, msg: str, where: str) -> None:
@@ -86,6 +103,16 @@ def check_trace(events: Iterable, n_dropped: int = 0,
                       f"{reasons}", where)
         rep.checked += 1
 
+        if ev.ph == "X" and ev.name in unbatched:
+            if ev.name == "sched_wait" and (ev.args or {}).get(
+                    "reason") not in wait_reasons:
+                rep.error(PASS, "bad-wait-reason",
+                          f"sched_wait reason "
+                          f"{(ev.args or {}).get('reason')!r} not in "
+                          f"{wait_reasons}", where)
+            if "batch" in (ev.args or {}):
+                rep.error(PASS, "batch-on-unbatched",
+                          f"{ev.name!r} span carries a batch id", where)
         if ev.ph == "X":
             if ev.dur_us < 0:
                 rep.error(PASS, "negative-dur",
@@ -243,10 +270,12 @@ def check_trace_file(path: str,
 
 def synthetic_trace_events() -> Tuple[List, int]:
     """Drive a FakeClock scheduler through every lifecycle edge — size
-    flush, max-wait flush, expiry shed, admission reject, drain — and
-    return ``(events, n_dropped)``. The ``--passes trace`` fallback
-    when no ``--trace-file`` is given: validates the *live*
-    instrumentation, not a canned fixture."""
+    flush, max-wait flush, expiry shed, admission reject, drain — with a
+    gc pause inside, and return ``(events, n_dropped)``. The
+    ``--passes trace`` fallback when no ``--trace-file`` is given:
+    validates the *live* instrumentation, not a canned fixture."""
+    import gc
+
     import numpy as np
 
     from repro.obs.trace import SpanTracer
@@ -255,6 +284,7 @@ def synthetic_trace_events() -> Tuple[List, int]:
 
     clk = FakeClock()
     tracer = SpanTracer(clock=clk, capacity=4096)
+    tracer.attach_process_hooks()        # a gc pause inside the run
     s = MicroBatchScheduler(
         lambda x: x.sum(axis=-1),
         SchedConfig(max_batch=4, max_wait_us=200.0, max_queue=8,
@@ -262,6 +292,7 @@ def synthetic_trace_events() -> Tuple[List, int]:
         clock=clk, tracer=tracer)
     futs = [s.submit(np.full((1, 3), i, np.float32)) for i in range(4)]
     s.poll()                             # size flush
+    gc.collect()
     futs.append(s.submit(np.ones((2, 3), np.float32)))
     clk.advance_us(250.0)
     s.poll()                             # max-wait flush
@@ -272,6 +303,7 @@ def synthetic_trace_events() -> Tuple[List, int]:
     except RequestRejected:
         pass
     s.drain()                            # expiry shed for the stale one
+    tracer.detach_process_hooks()
     for f in futs:
         try:
             f.result(0)

@@ -6,7 +6,13 @@ NullaNet Tiny's whole pitch is latency, so latency has to be visible
   trace      — thread-safe ring-buffer span tracer (injectable clock,
                near-zero overhead when disabled); every request carries
                submit → queue-wait → batch-formation (with flush
-               reason) → pack → dispatch → device-exec → scatter spans;
+               reason) → pack (quantize, bitpack) → dispatch →
+               device-exec (h2d, fetch) → scatter spans joined by one
+               batch id, beside the dispatch thread's waits, gc pauses
+               and compiles. While a ``jax.profiler`` trace is active,
+               every thread span is also a ``TraceAnnotation`` of the
+               same name, so it lands in the device trace beside the
+               ``XLA Ops``;
   export     — Chrome trace-event JSON (opens in Perfetto / chrome://
                tracing) and structured JSONL event export;
   registry   — one counters/gauges/histograms registry that
@@ -27,9 +33,6 @@ NullaNet Tiny's whole pitch is latency, so latency has to be visible
                of one end-of-run snapshot;
   slo        — multi-window SLO burn-rate monitor with alert callbacks,
                the scheduler's optional degradation hook;
-  online     — sampled real-traffic device timings blended back into
-               the ``LatencyTable`` so flush margins track the live
-               device;
   promexport — Prometheus text-exposition rendering of a registry
                snapshot plus a stdlib pull endpoint
                (``launch.serve --metrics-port``).
@@ -50,7 +53,6 @@ from .kernelprof import (EmptyLatencyTable, LatencyTable,
 from .analyze import TraceReport, analyze_events, analyze_trace
 from .window import BucketRing, WindowedMetrics
 from .slo import BurnAlert, BurnRateMonitor
-from .online import OnlineProfiler
 from .promexport import MetricsServer, to_prometheus_text
 
 __all__ = [
@@ -64,6 +66,5 @@ __all__ = [
     "TraceReport", "analyze_events", "analyze_trace",
     "BucketRing", "WindowedMetrics",
     "BurnAlert", "BurnRateMonitor",
-    "OnlineProfiler",
     "MetricsServer", "to_prometheus_text",
 ]

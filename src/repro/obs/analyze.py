@@ -8,10 +8,14 @@ reconstruction leans on two structural facts of the serving stack:
     request's begin/instants/end pair up across threads by id;
   * the scheduler serializes batches on one dispatch thread and thread
     spans record at context *exit*, so each batch appears in buffer
-    order as ``[e queue_wait]*n → X batch_form → (X aggregate_pack,
-    X device_exec, X replica_dispatch) → X exec → [e request]*n →
-    X scatter`` — a linear scan with a current-batch state machine
-    rebinds every request to the batch that served it.
+    order as ``[e queue_wait]*n → X batch_form → (X quantize, X bitpack,
+    X aggregate_pack, X h2d, X fetch, X device_exec, X replica_dispatch)
+    → X exec → [e request]*n → X scatter`` — a linear scan with a
+    current-batch state machine rebinds every request to the batch that
+    served it. The batch spans and the ``queue_wait`` ends also carry
+    the batch's ``args.batch`` id. Spans that belong to no batch
+    (``UNBATCHED_SPANS``: the dispatch thread's waits, gc pauses,
+    compiles) may fall anywhere in that order and are skipped.
 
 Per-request phase decomposition (all µs):
 
@@ -58,6 +62,9 @@ from .trace import TraceEvent
 RECON_PHASES = ("queue_wait", "batch_form", "pack", "dispatch",
                 "device_exec")
 ALL_PHASES = RECON_PHASES + ("scatter", "unattributed")
+# thread spans outside any batch: the dispatch thread waiting for
+# arrivals or a flush deadline, interpreter gc pauses, XLA compiles
+UNBATCHED_SPANS = ("sched_wait", "gc", "compile")
 
 # absolute slop floor (µs) under the relative tolerance: SystemClock
 # traces pay a few clock reads between span edges, and the scheduler
@@ -86,7 +93,6 @@ class BatchRecord:
     device_us: float = 0.0
     exec_us: float = 0.0
     scatter_us: float = 0.0
-    kernel_us: float = 0.0          # lut_eval spans inside device_exec
     members: List[int] = dataclasses.field(default_factory=list)
 
     @property
@@ -214,7 +220,6 @@ class TraceReport:
         reasons: Dict[str, int] = {}
         for b in self.batches:
             reasons[b.flush_reason] = reasons.get(b.flush_reason, 0) + 1
-        kernel = sum(b.kernel_us for b in self.batches)
         return {
             "n_events": self.n_events,
             "n_requests": len(self.requests),
@@ -224,7 +229,6 @@ class TraceReport:
             "outcomes": outcomes,
             "flush_reasons": reasons,
             "phases_us": self.phase_summary(),
-            "kernel_us_total": kernel,
             "lanes": self.lane_summary(),
             "reconciliation": self.reconciliation(),
         }
@@ -290,7 +294,7 @@ def analyze_events(events: Sequence[TraceEvent],
                 r.batch = None      # never dispatched
                 if ev.scope_id in pending:
                     pending.remove(ev.scope_id)
-        elif ev.ph == "X":
+        elif ev.ph == "X" and ev.name not in UNBATCHED_SPANS:
             if ev.name == "batch_form":
                 current = BatchRecord(
                     idx=len(batches),
@@ -311,8 +315,6 @@ def analyze_events(events: Sequence[TraceEvent],
                 current.exec_us += ev.dur_us
             elif current is not None and ev.name == "scatter":
                 current.scatter_us += ev.dur_us
-            elif current is not None and ev.cat == "kernel":
-                current.kernel_us += ev.dur_us
         elif ev.ph == "i":
             if ev.name == "reject":
                 counts["rejects"] += 1

@@ -267,10 +267,9 @@ class LatencyTable:
     explode on a noisy sweep, which once fed the flush margin a
     nonsense estimate.
 
-    ``scale`` is an online correction factor: calibration happens on an
-    idle device, serving happens on a busy one, and
-    ``repro.obs.online.OnlineProfiler`` blends the live measured/
-    predicted ratio into it so scheduler flush margins track the
+    ``scale`` is a correction factor: calibration happens on an idle
+    device, serving happens on a busy one, and ``blend_scale`` folds a
+    measured/predicted ratio into it so estimates can track the
     machine as it actually is.
     """
 

@@ -4,10 +4,14 @@ Two event families, mirroring the Chrome trace-event model so export is
 a straight mapping:
 
   * **thread spans** (``ph="X"``) — work done start-to-finish on one
-    thread: batch formation, aggregate pack, device exec, scatter.
+    thread: batch formation, aggregate pack (``quantize``,
+    ``bitpack``), device exec (``h2d``, ``fetch``), scatter, and the
+    dispatch thread's ``sched_wait`` for arrivals or a flush deadline.
     Nested calls on the same thread nest in Perfetto by time
     containment, so the aggregator's ``pack``/``device_exec`` spans
-    render inside the scheduler's ``exec`` span with no extra plumbing.
+    render inside the scheduler's ``exec`` span with no extra plumbing;
+    the batch spans also carry the scheduler's ``args.batch`` id, which
+    the requests' ``queue_wait`` ends share.
   * **async spans** (``ph="b"/"n"/"e"``) — one per *request*, keyed by
     a tracer-allocated id threaded through ``ServeRequest``/
     ``ServeFuture``: begun retroactively at the request's enqueue
@@ -16,6 +20,14 @@ a straight mapping:
     complete/shed/error. Async spans cross threads — enqueue time is
     stamped on the client thread, all recording happens scheduler-side
     — which is exactly what thread spans cannot express.
+
+While a ``jax.profiler`` trace is active, every thread span an enabled
+tracer records is also a ``jax.profiler.TraceAnnotation`` of the same
+name for its duration, so the program's own spans sit on the device
+trace's clock beside the ``XLA Ops``; with no profile running an
+annotation is a cheap no-op. ``attach_process_hooks`` adds two
+process-wide sources while an enabled tracer is in use: interpreter
+garbage collections (``gc``) and XLA backend compiles (``compile``).
 
 Storage is a lock-free ring: events are plain tuples appended to a
 ``deque(maxlen=capacity)`` and counted with ``itertools.count`` — both
@@ -29,6 +41,7 @@ hot path pays ~nothing for the instrumentation points it carries.
 """
 from __future__ import annotations
 
+import gc
 import threading
 from collections import deque
 from itertools import count as _monotonic_count
@@ -38,6 +51,9 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 # batch flush reasons annotated on batch-formation events; the trace
 # validation pass (repro.check --passes trace) rejects anything else
 FLUSH_REASONS = ("size", "deadline", "max_wait", "drain", "shed")
+# why the dispatch thread waits (``sched_wait`` spans): no request is
+# queued, or the queued ones are not yet due for a flush
+WAIT_REASONS = ("empty", "fill")
 
 
 class TraceEvent(NamedTuple):
@@ -59,29 +75,35 @@ class TraceEvent(NamedTuple):
 
 
 class _Span:
-    """Context manager recording one thread span on exit."""
+    """Context manager recording one thread span on exit (and holding a
+    ``TraceAnnotation`` of the same name open while it runs)."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
-                 args: Optional[dict]):
+                 args: Optional[dict], t0_us: Optional[float] = None):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._t0 = t0_us
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._now()
+        self._ann = self._tracer._annotation(self._name)
+        self._ann.__enter__()
+        if self._t0 is None:
+            self._t0 = self._tracer._now()
         return self
 
     def __exit__(self, *exc) -> None:
         # inlined tracer.complete(): X spans fire per batch phase on
         # the scheduler thread, so every frame saved is throughput
         tr = self._tracer
+        t1 = tr._now()
+        self._ann.__exit__(None, None, None)
         next(tr._n)
         tr._buf.append(("X", self._name, self._cat, self._t0,
-                        tr._now() - self._t0, get_ident(), None,
-                        self._args))
+                        t1 - self._t0, get_ident(), None, self._args))
 
 
 class _NullSpan:
@@ -117,6 +139,11 @@ class SpanTracer:
         self.clock = clock
         self.enabled = enabled
         self._cap = capacity
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self._local = threading.local()     # the thread's current batch
+        self._hooks = 0                     # attach_process_hooks depth
+        self._gc_open: Optional[Tuple[float, object]] = None
         # the hot path is lock-free: deque.append with a maxlen and
         # next() on an itertools.count are both single C calls, atomic
         # under the GIL, so 64 submitter threads recording concurrently
@@ -145,6 +172,15 @@ class SpanTracer:
     def n_dropped(self) -> int:
         return max(0, self.n_recorded - self._cap)
 
+    @property
+    def batch(self) -> Optional[int]:
+        """The id of the batch the calling thread is executing (set by
+        the scheduler around its executor call), or None."""
+        return getattr(self._local, "batch", None)
+
+    def set_batch(self, batch_id: Optional[int]) -> None:
+        self._local.batch = batch_id
+
     # -- recording ---------------------------------------------------------
     def complete(self, name: str, t0_us: float, t1_us: float,
                  cat: str = "sched", args: Optional[dict] = None) -> None:
@@ -157,12 +193,13 @@ class SpanTracer:
                           get_ident(), None, args))
 
     def span(self, name: str, cat: str = "sched",
-             args: Optional[dict] = None):
+             args: Optional[dict] = None, t0_us: Optional[float] = None):
         """``with tracer.span("exec"): ...`` — times the block on the
-        current thread."""
+        current thread; ``t0_us`` starts it at a time stamped earlier
+        instead of at entry."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, cat, args)
+        return _Span(self, name, cat, args, t0_us)
 
     def instant(self, name: str, cat: str = "sched",
                 args: Optional[dict] = None) -> None:
@@ -216,6 +253,58 @@ class SpanTracer:
                           self._now() if ts_us is None else ts_us, 0.0,
                           get_ident(), scope_id, args))
 
+    # -- process-wide sources ----------------------------------------------
+    def attach_process_hooks(self) -> None:
+        """Record garbage collections (``gc``, on the collecting thread,
+        ``args.generation``) and XLA backend compiles (``compile``, from
+        a ``jax.monitoring`` duration listener) as thread spans until
+        the matching ``detach_process_hooks``. Counted, so schedulers
+        sharing one tracer may each attach; a disabled tracer installs
+        nothing."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._hooks += 1
+            if self._hooks > 1:
+                return
+            import jax.monitoring
+            gc.callbacks.append(self._on_gc)
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+
+    def detach_process_hooks(self) -> None:
+        with self._lock:
+            if self._hooks == 0:
+                return
+            self._hooks -= 1
+            if self._hooks:
+                return
+            import jax.monitoring
+            gc.callbacks.remove(self._on_gc)
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_duration)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections never nest, and start and stop run on the same
+        # thread, so one open slot suffices
+        if phase == "start":
+            ann = self._annotation("gc")
+            ann.__enter__()
+            self._gc_open = (self._now(), ann)
+        elif self._gc_open is not None:
+            t0, ann = self._gc_open
+            self._gc_open = None
+            t1 = self._now()
+            ann.__exit__(None, None, None)
+            self.complete("gc", t0, t1, cat="process",
+                          args={"generation": info["generation"]})
+
+    def _on_duration(self, event: str, secs: float, **kw) -> None:
+        if event.endswith("backend_compile_duration"):
+            t1 = self._now()
+            self.complete("compile", t1 - secs * 1e6, t1, cat="process",
+                          args={"fun_name": kw.get("fun_name")})
+
     # -- reading -----------------------------------------------------------
     def events(self) -> List[TraceEvent]:
         """Snapshot of the retained events in recording order."""
@@ -251,10 +340,15 @@ class NullTracer:
     def n_dropped(self) -> int:
         return 0
 
+    batch = None
+
+    def set_batch(self, batch_id) -> None:
+        pass
+
     def complete(self, name, t0_us, t1_us, cat="sched", args=None) -> None:
         pass
 
-    def span(self, name, cat="sched", args=None):
+    def span(self, name, cat="sched", args=None, t0_us=None):
         return _NULL_SPAN
 
     def instant(self, name, cat="sched", args=None) -> None:
@@ -273,6 +367,12 @@ class NullTracer:
 
     def aend(self, name, scope_id, cat="request", args=None,
              ts_us=None) -> None:
+        pass
+
+    def attach_process_hooks(self) -> None:
+        pass
+
+    def detach_process_hooks(self) -> None:
         pass
 
     def events(self) -> List[TraceEvent]:

@@ -51,10 +51,6 @@ class BitplaneAggregator:
         self.lanes_per_word = WORD_BITS
         self.pad_rows = pad_rows
         self.tracer = NULL_TRACER
-        # online-profiling hook: called with (measured device µs, rows)
-        # after each netlist evaluation when set (see
-        # repro.obs.online.OnlineProfiler.observe)
-        self.on_device_us: Optional[callable] = None
         self.n_features = bitnet.net.n_inputs   # admission width check
         self.n_evals = 0            # lane-words carrying >= 1 real request
         self.n_rows = 0             # request rows served
@@ -74,16 +70,19 @@ class BitplaneAggregator:
         flush size.
         """
         bn = self.bitnet
+        tr = self.tracer
         if self.pad_rows and x.shape[0] < self.pad_rows:
             x = np.concatenate(
                 [x, np.zeros((self.pad_rows - x.shape[0], x.shape[1]),
                              x.dtype)])
-        codes = bn.quantize_codes(x).astype(np.int64)
-        planes = np.empty((codes.shape[1] * bn.in_bits, codes.shape[0]),
-                          np.uint8)
-        for b in range(bn.in_bits):     # wire i*in_bits+b = bit b of code i
-            planes[b::bn.in_bits] = ((codes >> b) & 1).T
-        return pack_bits(planes)
+        with tr.span("quantize", cat="pack"):
+            codes = bn.quantize_codes(x).astype(np.int64)
+        with tr.span("bitpack", cat="pack"):
+            planes = np.empty((codes.shape[1] * bn.in_bits, codes.shape[0]),
+                              np.uint8)
+            for b in range(bn.in_bits):  # wire i*in_bits+b = bit b of code i
+                planes[b::bn.in_bits] = ((codes >> b) & 1).T
+            return pack_bits(planes)
 
     def __call__(self, x: np.ndarray,
                  deadline_us: Optional[float] = None) -> np.ndarray:
@@ -95,28 +94,21 @@ class BitplaneAggregator:
         how often the pack went out with idle lanes as a result."""
         x = np.asarray(x)
         true_rows = x.shape[0]
-        with self.tracer.span("aggregate_pack", cat="pack", args={
-                "rows": true_rows,
-                "lane_words": -(-true_rows // self.lanes_per_word)}):
+        tr = self.tracer
+        pack_args = exec_args = None
+        if tr.enabled:
+            pack_args = {"rows": true_rows, "batch": tr.batch,
+                         "lane_words": -(-true_rows // self.lanes_per_word)}
+            exec_args = {"rows": true_rows, "batch": tr.batch,
+                         "engine": self.bitnet.engine}
+        with tr.span("aggregate_pack", cat="pack", args=pack_args):
             pi_words = self.pack_requests(x)
         # engine dispatch happens inside classify_packed: the pallas
         # engine ships the words to the device and returns only the
         # scattered per-request argmax; numpy is the host fold + decode.
-        if self.on_device_us is not None:
-            # timed with wall perf_counter, not the tracer clock: the
-            # profiler wants real device µs even under a FakeClock
-            import time
-            t0 = time.perf_counter()
-            with self.tracer.span("device_exec", cat="exec", args={
-                    "rows": true_rows, "engine": self.bitnet.engine}):
-                labels = self.bitnet.classify_packed(pi_words, true_rows,
-                                                     self.n_classes)
-            self.on_device_us((time.perf_counter() - t0) * 1e6, true_rows)
-        else:
-            with self.tracer.span("device_exec", cat="exec", args={
-                    "rows": true_rows, "engine": self.bitnet.engine}):
-                labels = self.bitnet.classify_packed(pi_words, true_rows,
-                                                     self.n_classes)
+        with tr.span("device_exec", cat="exec", args=exec_args):
+            labels = self.bitnet.classify_packed(pi_words, true_rows,
+                                                 self.n_classes)
         # occupancy is accounted against *real* request rows: lane-words
         # that exist only because of pad_rows shape-stability padding
         # are tracked separately, not counted as served capacity.
@@ -129,9 +121,10 @@ class BitplaneAggregator:
         return labels
 
     def set_tracer(self, tracer) -> None:
-        """Adopt ``tracer`` (propagated to the underlying network so
-        device spans nest inside ``device_exec``); the scheduler calls
-        this automatically when constructed with one."""
+        """Adopt ``tracer`` (propagated through the underlying network
+        to its engine, so the ``h2d``/``fetch`` spans nest inside
+        ``device_exec``); the scheduler calls this automatically when
+        constructed with one."""
         self.tracer = tracer
         self.bitnet.tracer = tracer
 

@@ -101,15 +101,6 @@ class ReplicaSet:
             r.inflight += 1
             return r
 
-    def reseed_exec_estimate(self, us: float) -> None:
-        """Re-seed every replica's execution-time EWMA with a fresher
-        calibration (the online profiler's blended live estimate);
-        per-replica measurements keep blending in on top."""
-        with self._lock:
-            for r in self.replicas:
-                r.ewma_us = float(us)
-                r.ewma_seeded = True
-
     def mark_down(self, rid: int) -> None:
         with self._lock:
             self.replicas[rid].healthy = False

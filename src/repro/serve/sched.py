@@ -341,6 +341,7 @@ class MicroBatchScheduler:
                                           self.cfg.n_priorities)
         self._cond = threading.Condition()
         self._thread: Optional[threading.Thread] = None
+        self._hooked = False        # process hooks attached by start()
         self._stopping = False
         self._shutdown = False
         # smoothed batch execution time; a calibrated kernelprof
@@ -463,14 +464,6 @@ class MicroBatchScheduler:
             self._cond.notify_all()
         return fut
 
-    def update_exec_estimate(self, us: float) -> None:
-        """Re-seed the batch-execution estimate with a fresher
-        calibration (``repro.obs.online.OnlineProfiler`` pushes the
-        blended live-device estimate here). Subsequent measured batches
-        keep blending into it through the normal EWMA."""
-        self._exec_ewma_us = float(us)
-        self._ewma_seeded = True
-
     # -- event engine ------------------------------------------------------
     def next_deadline_us(self) -> Optional[float]:
         """Earliest instant a flush is owed: the tightest queued SLO
@@ -544,29 +537,40 @@ class MicroBatchScheduler:
         tracer = self.tracer
         rows = sum(r.rows for r in batch)
         t_form = self.clock.now_us()
+        bid = form_args = None
         if tracer.enabled:
-            for r in batch:
-                if r.trace_id is not None:
-                    # open both spans at the enqueue ts, close the
-                    # queue phase at exactly t_form; the flush reason
-                    # lives on the batch_form span
-                    self._trace_begin(tracer, r)
-                    tracer.aend("queue_wait", r.trace_id, ts_us=t_form)
-        xs = [r.x if r.x.ndim > 1 else r.x[None] for r in batch]
-        tightest = min(r.deadline_us for r in batch)
-        xcat = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
-        if tracer.enabled:
-            # explicit endpoints so batch formation covers everything
-            # from t_form (queue_wait ends) through the concat — the
-            # per-request close loop and payload staging included;
-            # otherwise that work is an unattributed reconciliation gap
-            tracer.complete("batch_form", t_form, self.clock.now_us(),
-                            cat="batch",
-                            args={"flush_reason": reason, "rows": rows,
-                                  "n_requests": len(batch)})
+            # one id joins the batch's spans (here, and the pack and
+            # device call below through tracer.batch) and its requests'
+            # queue_wait ends; the tracer's counter keeps it unique
+            # when several schedulers share one tracer
+            bid = tracer.new_id()
+            tracer.set_batch(bid)
+            form_args = {"flush_reason": reason, "rows": rows,
+                         "n_requests": len(batch), "batch": bid}
+        # batch formation starts at t_form (where queue_wait ends) and
+        # covers the per-request close loop and the payload concat;
+        # otherwise that work is an unattributed reconciliation gap
+        with tracer.span("batch_form", cat="batch", args=form_args,
+                         t0_us=t_form):
+            if tracer.enabled:
+                # one args dict for the batch: the ring keeps every
+                # event, and each live dict lengthens full collections
+                wait_args = {"batch": bid}
+                for r in batch:
+                    if r.trace_id is not None:
+                        # open both spans at the enqueue ts, close the
+                        # queue phase at exactly t_form; the flush
+                        # reason lives on the batch_form span
+                        self._trace_begin(tracer, r)
+                        tracer.aend("queue_wait", r.trace_id, ts_us=t_form,
+                                    args=wait_args)
+            xs = [r.x if r.x.ndim > 1 else r.x[None] for r in batch]
+            tightest = min(r.deadline_us for r in batch)
+            xcat = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
         t0 = self.clock.now_us()
         try:
-            with tracer.span("exec", cat="exec", args={"rows": rows}):
+            with tracer.span("exec", cat="exec",
+                             args={"rows": rows, "batch": bid}):
                 if self._pass_deadline:
                     res = self.executor(xcat, deadline_us=tightest)
                 else:
@@ -582,6 +586,9 @@ class MicroBatchScheduler:
                                       "error": type(e).__name__})
                 r.future.set_exception(e)
             return
+        finally:
+            if bid is not None:
+                tracer.set_batch(None)
         now = self.clock.now_us()
         self.metrics.record_batch(rows, now - t0, now_us=now)
         dt = now - t0
@@ -593,7 +600,7 @@ class MicroBatchScheduler:
         assert res.shape[0] == rows, (
             f"executor returned {res.shape[0]} rows for a {rows}-row batch")
         with tracer.span("scatter", cat="sched",
-                         args={"n_requests": len(batch)}):
+                         args={"n_requests": len(batch), "batch": bid}):
             off = 0
             for r in batch:
                 out = res[off: off + r.rows]
@@ -640,17 +647,41 @@ class MicroBatchScheduler:
         assert self._thread is None, "scheduler already started"
         with self._cond:
             self._stopping = False
+        if self.tracer.enabled and not self._hooked:
+            # gc pauses and compiles while serving; stop() takes them off
+            self.tracer.attach_process_hooks()
+            self._hooked = True
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="microbatch-sched")
         self._thread.start()
         return self
 
+    def _wait_span(self, wait, reason: str):
+        """The dispatch thread's open ``sched_wait`` span as
+        ``(span, reason)``: kept while ``reason`` holds, else the open
+        one is closed and one for ``reason`` opened. ``empty`` waits for
+        arrivals, ``fill`` for the flush deadline of a queued batch."""
+        if wait is not None:
+            if wait[1] == reason:
+                return wait
+            wait[0].__exit__(None, None, None)
+        span = self.tracer.span("sched_wait", cat="sched",
+                                args={"reason": reason})
+        span.__enter__()
+        return span, reason
+
     def _loop(self) -> None:
+        traced = self.tracer.enabled
+        wait = None                 # open sched_wait span (traced only)
         while True:
             with self._cond:
                 while (not self._stopping and len(self.queue) == 0):
+                    if traced:
+                        wait = self._wait_span(wait, "empty")
                     self._cond.wait(timeout=0.05)
                 if self._stopping and len(self.queue) == 0:
+                    if wait is not None:
+                        wait[0].__exit__(None, None, None)
                     return
                 now = self.clock.now_us()
                 full = self.queue.rows >= self.cfg.max_batch
@@ -659,9 +690,14 @@ class MicroBatchScheduler:
                 wait_us = (0.0 if full or flush_at is None or self._stopping
                            else flush_at - now)
                 if wait_us > 0:
+                    if traced:
+                        wait = self._wait_span(wait, "fill")
                     self._cond.wait(timeout=wait_us * 1e-6)
                     continue
                 stopping = self._stopping   # snapshot under the lock
+            if wait is not None:
+                wait[0].__exit__(None, None, None)
+                wait = None
             self.poll(force=stopping)
 
     def stop(self, drain: bool = True) -> None:
@@ -699,3 +735,6 @@ class MicroBatchScheduler:
                                  args={"outcome": "shutdown"})
             r.future.set_exception(RequestRejected(
                 RejectReason.SHUTDOWN, "scheduler stopped before dispatch"))
+        if self._hooked:
+            self.tracer.detach_process_hooks()
+            self._hooked = False

@@ -437,7 +437,9 @@ class _DeviceExecutor:
         import jax
         import jax.numpy as jnp
         from repro.kernels.spec import DEFAULT_SPEC
+        from repro.obs.trace import NULL_TRACER
 
+        self.tracer = NULL_TRACER       # set through BitplaneNetwork.tracer
         self._jnp = jnp
         self.spec = DEFAULT_SPEC if spec is None else spec
         self.interpret = self.spec.resolve_interpret(interpret)
@@ -515,18 +517,30 @@ class _DeviceExecutor:
         return np.asarray(self._argmax_codes(
             self._put(np.asarray(codes, np.int32)), n_classes=n_classes))
 
+    def _put_words(self, pi_words: np.ndarray):
+        """Packed PI words -> int32 words on the device (the ``h2d``
+        span: ``device_put`` returns once the host has handed the
+        buffer over, so the rest of the copy lands in ``fetch``)."""
+        with self.tracer.span("h2d", cat="exec"):
+            return self._put(
+                np.ascontiguousarray(pi_words, np.uint32).view(np.int32))
+
     def device_labels(self, pi_words: np.ndarray, n_classes: int):
         """Packed PI words -> per-lane argmax labels, left on the
         device (a jax array of ``W * 32`` labels, not yet awaited)."""
-        words = self._put(
-            np.ascontiguousarray(pi_words, np.uint32).view(np.int32))
-        return self._argmax_words(words, n_classes=n_classes)
+        return self._argmax_words(self._put_words(pi_words),
+                                  n_classes=n_classes)
 
     def classify_words(self, pi_words: np.ndarray, n_rows: int,
                        n_classes: int) -> np.ndarray:
         """Packed PI words straight to the device; only the per-request
-        argmax labels come back (the serve aggregation hot path)."""
-        return np.asarray(self.device_labels(pi_words, n_classes))[:n_rows]
+        argmax labels come back (the serve aggregation hot path). The
+        ``fetch`` span covers dispatch, kernel, argmax and the copy
+        back, up to the labels on the host."""
+        words = self._put_words(pi_words)
+        with self.tracer.span("fetch", cat="exec"):
+            return np.asarray(
+                self._argmax_words(words, n_classes=n_classes))[:n_rows]
 
     def classify_packed(self, pi_words: np.ndarray, n_rows: int,
                         n_classes: int) -> np.ndarray:
@@ -661,9 +675,9 @@ class BitplaneNetwork:
         # (via aggregate), while repro.obs pulls repro.serve.metrics —
         # a module-level import here would close an import cycle
         from repro.obs.trace import NULL_TRACER
+        self._exec = None
         self.tracer = NULL_TRACER
         self._plan = _compile_plan(mapped)
-        self._exec = None
         self.in_bits = net.in_spec.code_bits
         last = net.layers[-1]
         self.out_bits = last.out_spec.code_bits
@@ -687,11 +701,26 @@ class BitplaneNetwork:
         return bn
 
     @property
+    def tracer(self):
+        """The span tracer, handed down to the engine (now, or when it
+        is built): engines with a ``tracer`` attribute record their
+        spans on it."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self._tracer = tracer
+        if self._exec is not None and hasattr(self._exec, "tracer"):
+            self._exec.tracer = tracer
+
+    @property
     def executor(self):
         """This network's engine instance (built lazily on first use)."""
         if self._exec is None:
             self._exec = self._factory(self, interpret=self.interpret,
                                        spec=self.spec)
+            if hasattr(self._exec, "tracer"):
+                self._exec.tracer = self._tracer
         return self._exec
 
     def quantize_codes(self, x) -> np.ndarray:
@@ -720,11 +749,7 @@ class BitplaneNetwork:
         The serve-aggregation entry point: on device engines the words
         go straight to the kernel and only the scattered argmax
         returns; on numpy it is the host fold + decode."""
-        with self.tracer.span("lut_eval", cat="kernel", args={
-                "rows": n_rows, "engine": self.engine,
-                "n_levels": len(self._plan.levels)}):
-            return self.executor.classify_packed(pi_words, n_rows,
-                                                 n_classes)
+        return self.executor.classify_packed(pi_words, n_rows, n_classes)
 
 
 # ---------------------------------------------------------------------------

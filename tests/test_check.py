@@ -492,12 +492,11 @@ def test_real_serve_stack_is_clean():
 
 
 def test_obs_classes_are_linted():
-    """The lint covers repro.obs: the shared-mutable window/burn-rate/
-    profiler classes must carry (and satisfy) lock annotations."""
+    """The lint covers repro.obs: the shared-mutable window/burn-rate
+    classes must carry (and satisfy) lock annotations."""
     rep = check_concurrency()
     assert rep.ok, rep.format()
-    for cls in ("WindowedMetrics", "BurnRateMonitor", "OnlineProfiler",
-                "BucketRing"):
+    for cls in ("WindowedMetrics", "BurnRateMonitor", "BucketRing"):
         assert cls in rep.info["guarded_classes"], cls
 
 

@@ -46,6 +46,7 @@ def test_full_lifecycle_spans_size_flush():
     futs = [s.submit(np.full((1, 3), i, np.float32)) for i in range(4)]
     assert s.poll() == 4
     evs = tracer.events()
+    form = _by(evs, ph="X", name="batch_form")[0]
 
     # every request opened + closed both async spans, outcome ok
     ids = {f.trace_id for f in futs}
@@ -56,9 +57,10 @@ def test_full_lifecycle_spans_size_flush():
         assert [e.name for e in begins] == ["request", "queue_wait"]
         assert [e.name for e in ends] == ["queue_wait", "request"]
         qw, req = ends
-        # the dispatch-path queue_wait end carries no args (the flush
-        # reason lives on the batch_form span; wait is ts delta)
-        assert qw.args is None
+        # the dispatch-path queue_wait end carries only its batch's id
+        # (the flush reason lives on the batch_form span; wait is ts
+        # delta)
+        assert qw.args == {"batch": form.args["batch"]}
         assert req.args["outcome"] == "ok"
         assert req.args["latency_us"] >= 0.0
 
@@ -66,7 +68,6 @@ def test_full_lifecycle_spans_size_flush():
     assert _by(evs, ph="X", name="batch_form", cat="batch")
     assert _by(evs, ph="X", name="exec", cat="exec")
     assert _by(evs, ph="X", name="scatter", cat="sched")
-    form = _by(evs, ph="X", name="batch_form")[0]
     assert form.args["flush_reason"] == "size" and form.args["rows"] == 4
 
 

@@ -1,7 +1,6 @@
 """Online telemetry: trace analytics (per-phase attribution +
 reconciliation), streaming windowed metrics, the SLO burn-rate monitor
-and its scheduler degradation hook, online continuous profiling,
-latency-table hardening, the Prometheus pull endpoint, and the
+and its scheduler degradation hook, latency-table hardening, the Prometheus pull endpoint, and the
 perf-trajectory ledger."""
 import json
 import urllib.request
@@ -13,14 +12,14 @@ from repro.check.tracecheck import (check_phase_reconciliation,
                                     synthetic_trace_events)
 from repro.obs import (BucketRing, BurnRateMonitor, EmptyLatencyTable,
                        LatencyTable, LatencyTableError, MetricsRegistry,
-                       MetricsServer, OnlineProfiler, SpanTracer,
+                       MetricsServer, SpanTracer,
                        TraceEvent, WindowedMetrics, analyze_events,
                        analyze_trace, to_prometheus_text,
                        write_chrome_trace)
 from repro.obs.analyze import (diff_reports, format_diff, format_report,
                                main as analyze_main)
 from repro.serve import (FakeClock, MicroBatchScheduler, RejectReason,
-                         ReplicaSet, RequestRejected, SchedConfig)
+                         RequestRejected, SchedConfig)
 
 
 def _ev(ph, name, ts, dur=0.0, tid=1, sid=None, args=None, cat="request"):
@@ -335,7 +334,7 @@ def test_degraded_check_rate_limited():
 
 
 # ---------------------------------------------------------------------------
-# Online continuous profiling
+# LatencyTable hardening
 # ---------------------------------------------------------------------------
 
 def _grid_table(scale=1.0):
@@ -344,59 +343,6 @@ def _grid_table(scale=1.0):
             for w in (4, 16) for f in (2, 4)]
     return LatencyTable(rows=rows, meta={}, scale=scale)
 
-
-class _FakeSched:
-    def __init__(self):
-        self.pushed = []
-
-    def update_exec_estimate(self, us):
-        self.pushed.append(us)
-
-
-def test_online_profiler_blends_and_pushes():
-    t = _grid_table()
-    sched = _FakeSched()
-    rs = ReplicaSet([lambda x: x], clock=FakeClock(), exec_seed_us=100.0)
-    prof = OnlineProfiler(t, predicted_us=100.0, sample_every=2,
-                          alpha=0.5).attach(scheduler=sched, replicas=rs)
-    prof.observe(200.0, rows=32)         # off-sample: counted, not blended
-    assert prof.n_sampled == 0 and t.scale == 1.0
-    prof.observe(200.0, rows=32)         # sampled: ratio 2.0 blends in
-    assert prof.n_sampled == 1
-    assert t.scale == pytest.approx(1.5)
-    assert sched.pushed[-1] == pytest.approx(150.0)
-    assert rs.stats()[0]["ewma_us"] == pytest.approx(150.0)
-    # repeated identical measurements converge on the true ratio
-    # instead of compounding (the denominator is scale-normalized)
-    for _ in range(40):
-        prof.observe(200.0, rows=32)
-    assert t.scale == pytest.approx(2.0, rel=1e-3)
-    assert prof.estimate_us == pytest.approx(200.0, rel=1e-3)
-    st = prof.stats()
-    assert st["n_observed"] == 42 and st["last_measured_us"] == 200.0
-    reg = MetricsRegistry()
-    prof.publish(reg)
-    assert reg.snapshot()["online_profile"]["n_sampled"] == st["n_sampled"]
-
-
-def test_online_profiler_guards():
-    with pytest.raises(ValueError):
-        OnlineProfiler(_grid_table(), predicted_us=0.0)
-    prof = OnlineProfiler(_grid_table(), predicted_us=100.0,
-                          sample_every=1, min_rows=8)
-    prof.observe(200.0, rows=2)          # under min_rows: ignored
-    prof.observe(-5.0, rows=32)          # nonsense measurement: ignored
-    assert prof.n_sampled == 0 and prof.table.scale == 1.0
-    # scale-normalized construction: a table already blended to 2x and a
-    # prediction made at that scale give the same base
-    t2 = _grid_table(scale=2.0)
-    p2 = OnlineProfiler(t2, predicted_us=200.0, sample_every=1)
-    assert p2.estimate_us == pytest.approx(200.0)
-
-
-# ---------------------------------------------------------------------------
-# LatencyTable hardening
-# ---------------------------------------------------------------------------
 
 def test_latency_table_empty_and_bad_queries():
     empty = LatencyTable(rows=[], meta={})
