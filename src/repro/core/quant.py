@@ -17,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
@@ -238,6 +239,44 @@ def encode_levels(spec: ActQuantSpec, x: Array, alpha: Array) -> Array:
         half = (1 << (spec.bits - 1)) - 1
         return jnp.clip(jnp.round(xf * half / alpha) + half, 0, 2 * half).astype(jnp.int32)
     raise ValueError(spec.kind)
+
+
+def encode_inputs_host(spec: ActQuantSpec, x, alpha) -> np.ndarray:
+    """Numpy twin of ``encode_levels(spec, apply_act_quant(spec, x, alpha),
+    alpha)``: real inputs -> int32 level codes, on the host.
+
+    Every intermediate is float32 and in the jax path's order (clip,
+    ``scale = n / max(alpha, 1e-8)``, round half to even, divide, then
+    ``round(q * n / alpha)`` shifted and clipped to the codes), so the
+    codes are bit-identical to it. Two places follow XLA where numpy
+    differs: XLA reads subnormals as zero, so the sign test is
+    ``x > -tiny`` (a negative subnormal is ``>= 0`` there), and XLA
+    converts a NaN to code 0, so the last clip drops NaNs
+    (``fmax``/``fmin``).
+    """
+    x = np.asarray(x, np.float32)
+    a = np.float32(alpha)
+    if spec.kind == "sign" or (spec.kind == "signed" and spec.bits == 1):
+        nonneg = x > -np.finfo(np.float32).tiny
+        q = np.where(nonneg, np.float32(1), np.float32(-1)) * a
+        return (q > 0).astype(np.int32)
+    if spec.kind == "binary":
+        q = (x >= np.float32(0.5)).astype(np.float32) * a
+        return (q > a * np.float32(0.5)).astype(np.int32)
+    if spec.kind == "pact":
+        n = (1 << spec.bits) - 1
+        lo, shift, top = np.float32(0), 0, n
+    elif spec.kind == "signed":
+        n = (1 << (spec.bits - 1)) - 1
+        lo, shift, top = -a, n, 2 * n
+    else:
+        raise ValueError(spec.kind)
+    nf = np.float32(n)
+    scale = nf / np.maximum(a, np.float32(1e-8))
+    q = np.rint(np.clip(x, lo, a) * scale) / scale
+    codes = np.rint(q * nf / a) + np.float32(shift)
+    return np.fmin(np.fmax(codes, np.float32(0)),
+                   np.float32(top)).astype(np.int32)
 
 
 def decode_levels(spec: ActQuantSpec, codes: Array, alpha: float) -> Array:

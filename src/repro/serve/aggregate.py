@@ -56,7 +56,7 @@ class BitplaneAggregator:
         self.n_rows = 0             # request rows served
         self.n_pad_rows = 0         # shape-stability padding rows added
         self.n_partial_packs = 0    # flushes whose last lane-word is partial
-        if pad_rows:                # warm the single quantizer shape
+        if pad_rows:                # warm the single device-program shape
             self(np.zeros((1, bitnet.net.n_inputs), np.float32))
             self.n_evals = self.n_rows = 0
             self.n_pad_rows = self.n_partial_packs = 0
@@ -64,10 +64,11 @@ class BitplaneAggregator:
     def pack_requests(self, x: np.ndarray) -> np.ndarray:
         """(B, n_features) real inputs -> (n_pi_wires, ceil(B/32)) words.
 
-        With ``pad_rows`` set, short batches are zero-padded to that row
-        count first: the input quantizer is (eager) jax, and a fixed
-        batch shape keeps it compiled once instead of once per distinct
-        flush size.
+        The input quantizer runs on the host (float32 numpy), so the
+        pack makes no device transfer. With ``pad_rows`` set, short
+        batches are zero-padded to that row count first: one word count
+        for the device program, so its kernel and argmax compile once
+        instead of once per distinct flush size.
         """
         bn = self.bitnet
         tr = self.tracer
@@ -76,7 +77,7 @@ class BitplaneAggregator:
                 [x, np.zeros((self.pad_rows - x.shape[0], x.shape[1]),
                              x.dtype)])
         with tr.span("quantize", cat="pack"):
-            codes = bn.quantize_codes(x).astype(np.int64)
+            codes = bn.quantize_codes(x)
         with tr.span("bitpack", cat="pack"):
             planes = np.empty((codes.shape[1] * bn.in_bits, codes.shape[0]),
                               np.uint8)

@@ -71,7 +71,7 @@ class LogicEngine:
             self.bitnet = compile_logic_network(
                 self.net, effort=self.synth_effort, engine=self.engine,
                 device=self.device)
-            # padded aggregator: one quantizer shape for every flush size
+            # padded aggregator: one device-program shape for every flush size
             self._fn = BitplaneAggregator(self.bitnet, self.n_classes,
                                           pad_rows=self.max_batch)
             return

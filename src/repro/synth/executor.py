@@ -42,6 +42,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.core.quant import encode_inputs_host
+
 from .aig import lit_compl, lit_var, tt_expand
 from .lutmap import MappedNetwork
 from .simulate import WORD_BITS, pack_bits, unpack_bits
@@ -656,9 +658,9 @@ class BitplaneNetwork:
     ``UnknownEngineError`` listing the registered engines. All engines
     are bit-identical on every reachable input.
 
-    ``device`` (a ``jax.Device``) pins the device engines' plan tensors,
-    jitted calls and input quantization to that device; ``None`` leaves
-    them on JAX's default device.
+    ``device`` (a ``jax.Device``) pins the device engines' plan tensors
+    and jitted calls to that device; ``None`` leaves them on JAX's
+    default device. Input quantization runs on the host.
     """
 
     def __init__(self, net, mapped: MappedNetwork, engine: str = "numpy",
@@ -724,11 +726,10 @@ class BitplaneNetwork:
         return self._exec
 
     def quantize_codes(self, x) -> np.ndarray:
-        """Real inputs -> (B, n_inputs) input codes, quantized on this
-        network's device."""
-        import jax
-        with jax.default_device(self.device):
-            return np.asarray(self.net.quantize_inputs(x))
+        """Real inputs -> (B, n_inputs) int32 input codes, quantized on
+        the host (float32 numpy, bit-identical to
+        ``LogicNetwork.quantize_inputs``)."""
+        return encode_inputs_host(self.net.in_spec, x, self.net.in_alpha)
 
     def apply_codes(self, codes: np.ndarray) -> np.ndarray:
         """(B, n_inputs) input codes -> (B, n_out_neurons) output codes."""

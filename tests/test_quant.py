@@ -66,6 +66,48 @@ def test_encode_decode_roundtrip(kind, bits, alpha):
                                rtol=1e-5, atol=1e-5)
 
 
+def _host_twin_inputs(spec, alpha):
+    """Random normals, every level boundary k*step/2 and its +-1-ulp
+    neighbours, +-alpha, values far out of range, and NaN (float32)."""
+    a = np.float32(alpha)
+    if spec.kind in ("sign", "binary") or spec.bits == 1:
+        n = 1
+    elif spec.kind == "pact":
+        n = (1 << spec.bits) - 1
+    else:
+        n = (1 << (spec.bits - 1)) - 1
+    step = a / np.float32(n)
+    k = np.arange(-2 * n - 2, 2 * n + 3, dtype=np.float32)
+    edges = np.concatenate([k * step / np.float32(2), [a, -a, a / 2, 0.5]])
+    edges = edges.astype(np.float32)
+    far = np.array([4 * a, -4 * a, 1e6, -1e6, 3e38, -3e38, np.inf, -np.inf,
+                    np.nan, -0.0], np.float32)
+    rng = np.random.default_rng(spec.bits)
+    return np.concatenate([
+        rng.normal(0, 2 * alpha, 4096).astype(np.float32), edges,
+        np.nextafter(edges, np.float32(np.inf)),
+        np.nextafter(edges, np.float32(-np.inf)), far])
+
+
+@pytest.mark.parametrize("alpha", [0.75, 1.0, 1.001, 1.2345])
+@pytest.mark.parametrize("kind", ["sign", "binary", "pact", "signed"])
+@pytest.mark.parametrize("bits", [1, 2, 3, 4])
+def test_encode_inputs_host_matches_jax(kind, bits, alpha):
+    """The numpy input quantizer gives the jax path's codes bit for bit."""
+    spec = Q.ActQuantSpec(kind, bits)
+    x = _host_twin_inputs(spec, alpha)
+    q = Q.apply_act_quant(spec, jnp.asarray(x), jnp.asarray(alpha, jnp.float32))
+    want = np.asarray(Q.encode_levels(spec, q, alpha))
+    got = Q.encode_inputs_host(spec, x, alpha)
+    assert got.dtype == np.int32 and got.shape == x.shape
+    np.testing.assert_array_equal(got, want)
+
+
+def test_encode_inputs_host_refuses_unquantized():
+    with pytest.raises(ValueError):
+        Q.encode_inputs_host(Q.ActQuantSpec("none", 1), np.zeros(4), 1.0)
+
+
 def test_dorefa_weights():
     w = jnp.asarray(np.random.default_rng(1).normal(size=(8, 8)),
                     jnp.float32)

@@ -500,6 +500,27 @@ def test_aggregator_occupancy_counts_real_rows_under_pad_rows(jsc_small):
     assert agg.n_features == net.n_inputs
 
 
+@pytest.mark.parametrize("pad_rows", [None, 64])
+def test_aggregator_pack_makes_no_device_transfer(jsc_small, pad_rows):
+    import jax
+    from repro.serving.engine import LogicEngine
+    from repro.synth.simulate import pack_bits
+    net, xte = jsc_small
+    eng = LogicEngine(net, 5, max_batch=64, backend="bitplane")
+    agg = BitplaneAggregator(eng.bitnet, 5, pad_rows=pad_rows)
+    rows = np.concatenate([xte[:32], 3 * xte[32:40]]).astype(np.float32)
+    padded = rows if pad_rows is None else np.concatenate(
+        [rows, np.zeros((pad_rows - len(rows), rows.shape[1]), np.float32)])
+    # the jax quantizer's codes, bit b of code i on wire i*in_bits + b
+    codes = np.asarray(net.quantize_inputs(padded))
+    bits = eng.bitnet.in_bits
+    planes = (codes[:, :, None] >> np.arange(bits)) & 1
+    want = pack_bits(planes.reshape(len(padded), -1).T)
+    with jax.transfer_guard("disallow"):
+        got = agg.pack_requests(rows)
+    np.testing.assert_array_equal(got, want)
+
+
 def test_serve_queue_wrapper_reports_true_latency(jsc_small):
     from repro.serving.engine import LogicEngine
     net, xte = jsc_small
