@@ -10,6 +10,12 @@ The exact LogicNets (fanin, bits) pairs are approximated where the papers
 leave them implicit; the reproduction target is the relative claim
 structure (accuracy >= LogicNets at multiple-x fewer LUTs) on identical
 synthetic data — see DESIGN.md §7.
+
+These presets are cuts, sized for training and tests. ``JSC_M`` here is
+64-32-32-5 with 2-bit codes; the published LogicNets JSC-M
+(16-64-32-32-32-5, fan-in 4, 3-bit codes in every layer) is
+``bench/configs/jsc-m.json``, which the benchmark serves from a netlist
+synthesized ahead of time (``repro.synth.store``).
 """
 from repro.models.mlp import MLPConfig
 

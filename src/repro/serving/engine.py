@@ -21,12 +21,16 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.logic_infer import LogicNetwork
+from repro.core.lutcost import LUT_K
 from repro.models import lm
 
 
 # ---------------------------------------------------------------------------
 # Logic-network serving (the paper's inference product)
 # ---------------------------------------------------------------------------
+
+BITPLANE_BACKENDS = ("bitplane", "bitplane-prebuilt")
+
 
 @dataclasses.dataclass
 class LogicEngine:
@@ -37,7 +41,11 @@ class LogicEngine:
       * ``"pallas"``   — same tables through the lut_layer Pallas kernel;
       * ``"bitplane"`` — the ``repro.synth`` mapped 6-LUT netlist run as
         packed bitplane ops (32 samples per uint32 lane) — no per-neuron
-        gathers at all. Argmax outputs are identical across backends.
+        gathers at all; synthesized at construction;
+      * ``"bitplane-prebuilt"`` — the same, from the netlist synthesized
+        ahead of time into ``repro.synth.store`` (a store miss raises
+        ``NetlistNotFound``; it never synthesizes).
+    Argmax outputs are identical across backends.
 
     For the bitplane backend, ``engine`` names a netlist executor in
     the ``repro.synth.executors`` registry: ``"numpy"`` folds levels on
@@ -65,12 +73,14 @@ class LogicEngine:
     def __post_init__(self):
         if self.use_pallas and self.backend == "gather":
             self.backend = "pallas"
-        if self.backend == "bitplane":
+        if self.backend in BITPLANE_BACKENDS:
             from repro.serve.aggregate import BitplaneAggregator
-            from repro.synth import compile_logic_network
-            self.bitnet = compile_logic_network(
-                self.net, effort=self.synth_effort, engine=self.engine,
-                device=self.device)
+            from repro.synth import BitplaneNetwork
+            build = (BitplaneNetwork.from_logic_network
+                     if self.backend == "bitplane"
+                     else BitplaneNetwork.from_store)
+            self.bitnet = build(self.net, effort=self.synth_effort, k=LUT_K,
+                                engine=self.engine, device=self.device)
             # padded aggregator: one device-program shape for every flush size
             self._fn = BitplaneAggregator(self.bitnet, self.n_classes,
                                           pad_rows=self.max_batch)
@@ -96,7 +106,7 @@ class LogicEngine:
         x = np.asarray(x)
         n = x.shape[0]
         assert n <= self.max_batch, (n, self.max_batch)
-        if self.backend == "bitplane":
+        if self.backend in BITPLANE_BACKENDS:
             return np.asarray(self._fn(x))
         pad = self.max_batch - n
         if pad:
@@ -123,7 +133,7 @@ class LogicEngine:
         payloads at admission (typed ``BAD_SHAPE``) instead of letting
         one malformed request poison a whole batch.
         """
-        if self.backend == "bitplane":
+        if self.backend in BITPLANE_BACKENDS:
             return self._fn             # BitplaneAggregator: has n_features
 
         def ex(x: np.ndarray) -> np.ndarray:

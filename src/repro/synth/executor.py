@@ -253,6 +253,15 @@ def compile_tile_plan(plan: _Plan, n_pis: int, k: int,
                     row_of_wire, n_pis, n_rows, T, gather_cap, k)
 
 
+def staged_rows(tp: TilePlan) -> int:
+    """Unique leaf rows the streamed kernel stages per call, summed over
+    tiles (``gather_rows`` without its padding)."""
+    if tp.n_tiles == 0:
+        return 0
+    s = np.sort(tp.leaf_tiles.reshape(tp.n_tiles, -1), axis=1)
+    return int(tp.n_tiles + (np.diff(s, axis=1) != 0).sum())
+
+
 # ---------------------------------------------------------------------------
 # Device plan: level-stacked, width-padded tensors for the lut_eval kernel
 # ---------------------------------------------------------------------------
@@ -442,6 +451,7 @@ class _DeviceExecutor:
         from repro.obs.trace import NULL_TRACER
 
         self.tracer = NULL_TRACER       # set through BitplaneNetwork.tracer
+        self.fetch_args = None          # plan counts on each fetch span
         self._jnp = jnp
         self.spec = DEFAULT_SPEC if spec is None else spec
         self.interpret = self.spec.resolve_interpret(interpret)
@@ -538,9 +548,10 @@ class _DeviceExecutor:
         """Packed PI words straight to the device; only the per-request
         argmax labels come back (the serve aggregation hot path). The
         ``fetch`` span covers dispatch, kernel, argmax and the copy
-        back, up to the labels on the host."""
+        back, up to the labels on the host; its args are the engine's
+        ``fetch_args``."""
         words = self._put_words(pi_words)
-        with self.tracer.span("fetch", cat="exec"):
+        with self.tracer.span("fetch", cat="exec", args=self.fetch_args):
             return np.asarray(
                 self._argmax_words(words, n_classes=n_classes))[:n_rows]
 
@@ -621,6 +632,10 @@ class _StreamedExecutor(_DeviceExecutor):
         self._meta = self._put(pack_tile_meta(tp))
         self._out_idx = self._put(tp.out_idx.astype(np.int32))
         self._neg = self._put(np.where(tp.out_neg, -1, 0).astype(np.int32))
+        # one dict for every fetch span of this engine
+        self.fetch_args = {"luts": bitnet.mapped.n_luts,
+                           "tiles": tp.n_tiles,
+                           "staged_rows": staged_rows(tp)}
 
     def _eval_words(self, words):
         from repro.kernels.lut_eval.lut_eval import lut_eval_streamed_pallas
@@ -648,7 +663,8 @@ class BitplaneNetwork:
     """A compiled ``LogicNetwork`` executed through the mapped netlist.
 
     ``from_logic_network`` runs the full synthesis pipeline
-    (SOP -> AIG -> balance/rewrite -> k-LUT map); ``__call__`` matches
+    (SOP -> AIG -> balance/rewrite -> k-LUT map); ``from_store`` loads
+    the netlist synthesized ahead of time; ``__call__`` matches
     ``LogicNetwork.__call__`` bit-exactly on every reachable input.
 
     ``engine`` names an executor in the ``repro.synth.executors``
@@ -691,8 +707,11 @@ class BitplaneNetwork:
                            interpret: Optional[bool] = None,
                            verify: bool = False,
                            device=None) -> "BitplaneNetwork":
+        """Synthesize ``net`` and wrap the mapped netlist."""
         from . import synthesize        # lazy: package init imports us
+        from .executors import get as _get_engine
         from .from_sop import network_to_aig
+        _get_engine(engine)             # a bad name fails before synthesis
         bn = cls(net, synthesize(network_to_aig(net), effort=effort, k=k,
                                  verify=verify),
                  engine=engine, interpret=interpret, device=device)
@@ -701,6 +720,20 @@ class BitplaneNetwork:
             from repro.check.report import require_ok
             require_ok(preflight(bn))
         return bn
+
+    @classmethod
+    def from_store(cls, net, effort: int = 1, k: int = 6,
+                   engine: str = "numpy",
+                   interpret: Optional[bool] = None,
+                   device=None) -> "BitplaneNetwork":
+        """Wrap the netlist of ``net`` synthesized ahead of time
+        (``repro.synth.store``); never synthesizes: a store miss raises
+        ``NetlistNotFound``."""
+        from . import store
+        from .executors import get as _get_engine
+        _get_engine(engine)             # a bad name fails before the load
+        return cls(net, store.load(net, effort, k), engine=engine,
+                   interpret=interpret, device=device)
 
     @property
     def tracer(self):
