@@ -93,14 +93,19 @@ def numpy_labels(bitnet, xs, n_classes: int):
 
 
 def require_compiled(bitnet, n_classes: int):
-    """The engine ran its Mosaic kernel: not interpreted, staged-DMA
-    gather where the engine has one, and a ``tpu_custom_call`` in the
+    """The engine ran its Mosaic kernel: not interpreted, the gather
+    mode the plan's size gives where the engine has one (never the
+    interpreter-only ``fancy``), and a ``tpu_custom_call`` in the
     lowered classify program."""
     ex = bitnet.executor
     if ex.interpret is not False:
         raise AssertionError(f"{bitnet.engine}: interpret={ex.interpret}")
-    if getattr(ex, "gather", "dma") != "dma":
-        raise AssertionError(f"{bitnet.engine}: gather={ex.gather}")
+    if hasattr(ex, "gather"):
+        from repro.kernels.lut_eval.lut_eval import default_gather
+        want = default_gather(ex.tp, False, ex.spec.tile.block_w)
+        if ex.gather != want or ex.gather == "fancy":
+            raise AssertionError(f"{bitnet.engine}: gather={ex.gather}, "
+                                 f"the plan's size gives {want}")
     words = ex._put(np.zeros((bitnet.mapped.n_pis, MAX_BATCH // 32),
                              np.int32))
     hlo = ex._argmax_words.lower(words, n_classes=n_classes).as_text()

@@ -37,8 +37,14 @@ PASS = "plan"
 
 # mirrors kernels/lut_eval DEFAULT_BW without importing jax here
 _DEFAULT_BLOCK_W = 128
-# one TPU core's VMEM; the kernel wants the whole wire plane resident
+# the monolithic kernel's budget (a v5e core's default scoped VMEM
+# limit); it wants the whole wire plane resident
 DEFAULT_VMEM_BUDGET = 16 << 20
+# a TPU v5e core's VMEM (``pltpu.get_tpu_info().vmem_capacity_bytes``)
+V5E_VMEM_BYTES = 128 << 20
+# the streamed kernel keeps its plane in VMEM while the plane takes at
+# most this share of the core's VMEM, leaving the rest to the compiler
+_RESIDENT_SHARE = 0.5
 
 _FULL = np.uint32(0xFFFFFFFF)
 
@@ -85,6 +91,25 @@ def estimate_tile_vmem_bytes(tplan, block_w: int = _DEFAULT_BLOCK_W) -> int:
     fold = t * n_tt * block_w * 4 + t * k * block_w * 4   # state + gathers
     band = t * block_w * 4                         # contiguous out band
     return pis + bufs + stage + fold + band
+
+
+def resident_plane_bytes(tplan, block_w: int = _DEFAULT_BLOCK_W) -> int:
+    """VMEM the streamed kernel's resident plane takes: the plan's rows
+    padded to a multiple of 8 by ``block_w`` padded to 128 lanes, int32."""
+    rows = -(-tplan.n_rows // 8) * 8
+    lanes = -(-max(block_w, 1) // 128) * 128
+    return rows * lanes * 4
+
+
+def gather_mode(tplan, vmem_capacity_bytes: int,
+                block_w: int = _DEFAULT_BLOCK_W) -> str:
+    """The streamed kernel's leaf-gather mode for a plan: ``"vmem"``
+    (plane resident in VMEM, leaves read by vector loads) when the
+    padded plane fits half the core's VMEM, else ``"dma"`` (plane in
+    HBM, leaf rows staged by DMA)."""
+    budget = int(vmem_capacity_bytes * _RESIDENT_SHARE)
+    return ("vmem" if resident_plane_bytes(tplan, block_w) <= budget
+            else "dma")
 
 
 def validate_device_plan(dplan: DevicePlan,

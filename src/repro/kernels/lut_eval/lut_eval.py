@@ -11,31 +11,36 @@ against the full plane, and the plane must fit VMEM — both of which
 cap it far below the jnp scan oracle and below JSC-M/L-scale netlists.
 
 ``lut_eval_streamed_pallas`` — the streamed, tiled, double-buffered
-rebuild. The wire plane lives in HBM (``memory_space=ANY``) with rows
-renumbered level-major (``repro.synth.executor.compile_tile_plan``) so
-every tile of ``T`` slots writes one contiguous row band. Each tile's
-scalars (band base, INIT bits, leaf indices) travel as one packed
-``(R, 128)`` record (``pack_tile_meta``) that streams HBM→SMEM through a
-two-slot buffer: tile ``t+1``'s record DMA starts before tile ``t``'s
-fold, so the plan fetch hides behind compute. The band is stored with
-one contiguous DMA.
+rebuild. Rows are renumbered level-major
+(``repro.synth.executor.compile_tile_plan``) so every tile of ``T``
+slots writes one contiguous row band. Each tile's scalars (band base,
+INIT bits, leaf indices) travel as one packed ``(R, 128)`` record
+(``pack_tile_meta``) that streams HBM→SMEM through a two-slot buffer:
+tile ``t+1``'s record DMA starts before tile ``t``'s fold, so the plan
+fetch hides behind compute.
 
-Mosaic slices HBM and VMEM refs only at whole (8, 128) tiles, and only
-DMAs may touch an ``ANY`` ref. So the plane is 3-D, ``(rows, 1, W)``,
-with the word axis padded to 128 lanes: one wire row is then a
-leading-dim slice that a DMA may move, and the const-0 and PI rows are
-written by DMA too.
+Where the wire plane lives, and so how leaves are read, is the one
+mode-dependent step (``gather=``):
 
-Leaf gathering is the one mode-dependent step (``gather=``):
-
-  * ``"dma"`` — the default on every backend, and the only mode that
-    compiles for the chip: each tile's unique leaf rows are staged
-    HBM→VMEM by per-row async copies into a two-slot stage buffer, and
-    slots fold from stage-local indices read as SMEM scalars.
-  * ``"fancy"`` — one vector gather ``plane[leaf_rows]`` per tile.
-    Interpreter-only: Mosaic has no arbitrary-row vector gather and no
-    vector access to HBM. Bit-identical to ``"dma"`` (the test suite
-    runs both).
+  * ``"vmem"`` — the whole plane of one 128-lane word block is a 2-D
+    VMEM scratch: the head rows are vector stores, every leaf is a
+    one-row vector load at the plane row its record names, every
+    slot's output a one-row vector store, and the finished plane goes
+    to HBM in one DMA per grid step. Chosen whenever the padded plane
+    fits the core's VMEM budget (``repro.check.plan_check.gather_mode``).
+  * ``"dma"`` — the plane stays in HBM (``memory_space=ANY``), for
+    nets too large for VMEM: each tile's unique leaf rows are staged
+    HBM→VMEM by per-row async copies into a two-slot stage buffer,
+    slots fold from stage-local indices read as SMEM scalars, and the
+    band is stored with one contiguous DMA. Mosaic slices HBM refs only
+    at whole (8, 128) tiles and only DMAs may touch an ``ANY`` ref, so
+    this plane is 3-D, ``(rows, 1, W)``, with the word axis padded to
+    128 lanes: one wire row is then a leading-dim slice that a DMA may
+    move, and the const-0 and PI rows are written by DMA too.
+  * ``"fancy"`` — the HBM plane read by one vector gather
+    ``plane[leaf_rows]`` per tile. Interpreter-only: Mosaic has no
+    arbitrary-row vector gather and no vector access to HBM.
+    Bit-identical to the other two (the test suite runs all three).
 
 Levelization guarantees every leaf lives on a strictly earlier level,
 so tile-order execution is a topological order; padded slots inside a
@@ -54,13 +59,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BW = 128   # word (packed-sample) tile, lane-aligned
 
-GATHER_MODES = ("fancy", "dma")
+GATHER_MODES = ("fancy", "dma", "vmem")
+
+# VMEM the resident plane's kernel may take beyond the plane itself
+# (the PI block, fold state and the compiler's own scratch)
+VMEM_MARGIN = 16 << 20
 
 
-def default_gather() -> str:
-    """``"dma"`` on every backend: the CPU suite's default path is the
-    one the chip runs (``"fancy"`` is interpreter-only)."""
-    return "dma"
+def vmem_capacity_bytes(interpret: bool) -> int:
+    """One core's VMEM: the attached chip's when compiling for it, a
+    v5e's when interpreting, so the CPU suite takes the chip's
+    decisions."""
+    from repro.check.plan_check import V5E_VMEM_BYTES
+    if interpret:
+        return V5E_VMEM_BYTES
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def default_gather(tplan, interpret: bool, block_w: int = DEFAULT_BW) -> str:
+    """The gather mode the plan's size gives (``gather_mode``):
+    ``"vmem"`` where its padded plane fits the core's VMEM budget,
+    ``"dma"`` otherwise; never the interpreter-only ``"fancy"``."""
+    from repro.check.plan_check import gather_mode
+    return gather_mode(tplan, vmem_capacity_bytes(interpret), block_w)
 
 
 # ---------------------------------------------------------------------------
@@ -131,8 +152,8 @@ LANES = 128   # Mosaic slices HBM/VMEM refs only at whole 128-lane tiles
 
 def _meta_layout(T: int, G: int, k: int):
     """Offsets of one tile's scalar record in the ``meta`` operand:
-    out_base, then ``leaf_loc`` (T*k), ``gather_rows`` (G) and the INIT
-    bits packed as ``n_words`` int32 words per slot."""
+    out_base, then the slots' leaves (T*k), ``gather_rows`` (G) and the
+    INIT bits packed as ``n_words`` int32 words per slot."""
     if k > 6:
         raise ValueError(f"k={k}: a slot's INIT bits must fit two words")
     n_words = -(-(1 << k) // 32)
@@ -143,17 +164,28 @@ def _meta_layout(T: int, G: int, k: int):
     return loc, grow, init, n_words, -(-size // LANES)
 
 
-def pack_tile_meta(tplan) -> np.ndarray:
+def record_gather_cap(gather: str, gather_cap: int) -> int:
+    """The ``gather_rows`` a tile's record holds in a gather mode: none
+    under ``"vmem"``, whose record names each leaf's plane row itself."""
+    return 0 if gather == "vmem" else gather_cap
+
+
+def pack_tile_meta(tplan, gather: str = "dma") -> np.ndarray:
     """A ``TilePlan``'s per-tile scalars as the streamed kernel's
-    ``meta`` operand: (n_tiles, R, 128) int32, one record per tile laid
-    out by ``_meta_layout``. A 128-lane minor dim is what lets one DMA
-    per tile move the whole record into SMEM on the chip."""
+    ``meta`` operand for a gather mode: (n_tiles, R, 128) int32, one
+    record per tile laid out by ``_meta_layout``. Under ``"vmem"`` the
+    leaves are plane rows (``leaf_tiles``), read in one SMEM read each;
+    under the staged modes they index the tile's ``gather_rows``
+    (``leaf_loc``). A 128-lane minor dim is what lets one DMA per tile
+    move the whole record into SMEM on the chip."""
     n_tiles, T, k = tplan.n_tiles, tplan.tile_rows, tplan.k
-    loc, grow, init, n_words, rows = _meta_layout(T, tplan.gather_cap, k)
+    G = record_gather_cap(gather, tplan.gather_cap)
+    loc, grow, init, n_words, rows = _meta_layout(T, G, k)
     flat = np.zeros((n_tiles, rows * LANES), np.int64)
     flat[:, 0] = tplan.out_base
-    flat[:, loc:grow] = tplan.leaf_loc.reshape(n_tiles, T * k)
-    flat[:, grow:init] = tplan.gather_rows
+    leaves = tplan.leaf_tiles if G == 0 else tplan.leaf_loc
+    flat[:, loc:grow] = leaves.reshape(n_tiles, T * k)
+    flat[:, grow:init] = tplan.gather_rows[:, :G]
     bits = (np.asarray(tplan.tt_tiles) & 1).astype(np.int64)  # (n, T, 2^k)
     bits = np.pad(bits, ((0, 0), (0, 0), (0, n_words * 32 - bits.shape[2])))
     words = (bits.reshape(n_tiles, T, n_words, 32)
@@ -183,12 +215,104 @@ def _tile_fold(tt_tile, ins, *, T: int, n_tt: int, k: int, bw: int):
     return state[:, 0, :]
 
 
+def _slot_fold(words, leaf, sh, k: int):
+    """Shannon fold of one slot: ``words`` its packed INIT words (SMEM
+    scalars), ``leaf(j)`` its j-th leaf row (1, bw), ``sh`` the shift
+    iota ``31 - row`` of shape (min(2^k, 32), bw) -> the slot's output
+    row (1, bw). Row i's INIT mask is bit i of its word, moved to the
+    sign bit and spread to 0 / -1 by an arithmetic shift; with two
+    words (k = 6) the first fold step selects between them directly."""
+    masks = [(w << sh) >> 31 for w in words]
+    if len(masks) == 2:
+        sel = leaf(k - 1)
+        state = (masks[0] & ~sel) | (masks[1] & sel)
+        size, top = 32, k - 2
+    else:
+        state, size, top = masks[0], 1 << k, k - 1
+    for j in range(top, -1, -1):
+        half = size // 2
+        sel = leaf(j)
+        state = (state[:half] & ~sel) | (state[half:size] & sel)
+        size = half
+    return state
+
+
+def _fold_tile(out, words, leaf, sh, *, T: int, k: int):
+    """Fold a tile's ``T`` slots into ``out[s]`` (1, bw) rows: slot
+    ``s`` folds ``words(s)`` over ``leaf(s, j)``. The slots are unrolled
+    with static ``s``, so every record offset is a constant, and the
+    slots of one tile are independent (their leaves lie on earlier
+    levels), so the scheduler interleaves their reads and folds."""
+    for s in range(T):
+        out[s] = _slot_fold(words(s), functools.partial(leaf, s), sh, k)
+
+
 def _streamed_kernel(pi_ref, meta_hbm, plane_ref, *, n_pis: int,
                      n_tiles: int, T: int, G: int, k: int, bw: int,
                      gather: str):
     n_tt = 1 << k
-    loc_at, grow_at, init_at, n_words, _ = _meta_layout(T, G, k)
+    loc_at, grow_at, init_at, n_words, _ = _meta_layout(
+        T, record_gather_cap(gather, G), k)
     cols = pl.ds(pl.program_id(0) * bw, bw)
+
+    def scalar(metabuf, slot, i):
+        return metabuf[slot, i // LANES, i % LANES]
+
+    def init_words(metabuf, slot, s):
+        return [scalar(metabuf, slot, init_at + s * n_words + i)
+                for i in range(n_words)]
+
+    sh = 31 - jax.lax.broadcasted_iota(jnp.int32, (min(n_tt, 32), bw), 0)
+
+    if gather == "vmem":
+        # The plane of this word block is a VMEM scratch: head rows,
+        # leaves and slot outputs are one-row vector accesses, and the
+        # finished plane leaves in one DMA.
+        def resident(plane, outbuf, metabuf, meta_sem, out_sem):
+            def meta_dma(slot, t):
+                return pltpu.make_async_copy(meta_hbm.at[t], metabuf.at[slot],
+                                             meta_sem.at[slot])
+
+            meta_dma(0, 0).start()
+            plane[pl.ds(0, 1), :] = jnp.zeros((1, bw), jnp.int32)
+            plane[pl.ds(1, n_pis), :] = pi_ref[...]
+
+            def tile_step(t, carry):
+                slot = jax.lax.rem(t, 2)
+
+                @pl.when(t + 1 < n_tiles)
+                def _():
+                    meta_dma(1 - slot, t + 1).start()
+
+                meta_dma(slot, t).wait()
+
+                def leaf(s, j):
+                    row = scalar(metabuf, slot, loc_at + s * k + j)
+                    return plane[pl.ds(row, 1), :]
+
+                # the fold writes a separate buffer, so no slot's store
+                # orders another slot's leaf loads; then the band lands
+                _fold_tile(outbuf, functools.partial(init_words, metabuf,
+                                                     slot), leaf, sh,
+                           T=T, k=k)
+                base = scalar(metabuf, slot, 0)
+                for s in range(T):
+                    plane[pl.ds(base + s, 1), :] = outbuf[s]
+                return carry
+
+            jax.lax.fori_loop(0, n_tiles, tile_step, 0)
+            out = pltpu.make_async_copy(plane, plane_ref.at[:, cols], out_sem)
+            out.start()
+            out.wait()
+
+        pl.run_scoped(resident,
+                      plane=pltpu.VMEM((plane_ref.shape[0], bw), jnp.int32),
+                      outbuf=pltpu.VMEM((T, 1, bw), jnp.int32),
+                      metabuf=pltpu.SMEM((2,) + meta_hbm.shape[1:],
+                                         jnp.int32),
+                      meta_sem=pltpu.SemaphoreType.DMA((2,)),
+                      out_sem=pltpu.SemaphoreType.DMA)
+        return
 
     # The plane lives in HBM (ANY), which only DMAs may touch: write the
     # const-0 row from a zeroed VMEM row and the PI rows straight from
@@ -249,15 +373,12 @@ def _streamed_kernel(pi_ref, meta_hbm, plane_ref, *, n_pis: int,
     # gather == "dma": stage each tile's unique leaf rows HBM->VMEM by
     # per-row async copies; slots fold from stage-local SMEM indices.
     def body(metabuf, stage, outbuf, meta_sem, stage_sem, st_sem):
-        def scalar(slot, i):
-            return metabuf[slot, i // LANES, i % LANES]
-
         def meta_dma(slot, t):
             return pltpu.make_async_copy(meta_hbm.at[t], metabuf.at[slot],
                                          meta_sem.at[slot])
 
         def stage_row_dma(slot, g):
-            row = scalar(slot, grow_at + g)
+            row = scalar(metabuf, slot, grow_at + g)
             return pltpu.make_async_copy(
                 plane_ref.at[pl.ds(row, 1), :, cols],
                 stage.at[slot, pl.ds(g, 1)], stage_sem.at[slot])
@@ -279,7 +400,6 @@ def _streamed_kernel(pi_ref, meta_hbm, plane_ref, *, n_pis: int,
         meta_dma(0, 0).start()
         meta_dma(0, 0).wait()
         issue_stage(0)
-        r = jax.lax.broadcasted_iota(jnp.int32, (n_tt, bw), 0)
 
         def tile_step(t, carry):
             slot = jax.lax.rem(t, 2)
@@ -291,23 +411,14 @@ def _streamed_kernel(pi_ref, meta_hbm, plane_ref, *, n_pis: int,
 
             wait_stage(slot)
 
-            def slot_step(s, carry):
-                state = _init_masks(
-                    [scalar(slot, init_at + s * n_words + i)
-                     for i in range(n_words)], r)             # (2^k, bw)
-                size = n_tt
-                for j in range(k - 1, -1, -1):
-                    half = size // 2
-                    sel = stage[slot, scalar(slot, loc_at + s * k + j)]
-                    state = ((state[:half] & ~sel)
-                             | (state[half:size] & sel))
-                    size = half
-                outbuf[s] = state
-                return carry
+            def leaf(s, j):
+                return stage[slot, scalar(metabuf, slot, loc_at + s * k + j)]
 
-            jax.lax.fori_loop(0, T, slot_step, 0)
+            _fold_tile(outbuf, functools.partial(init_words, metabuf, slot),
+                       leaf, sh, T=T, k=k)
             st = pltpu.make_async_copy(
-                outbuf, plane_ref.at[pl.ds(scalar(slot, 0), T), :, cols],
+                outbuf,
+                plane_ref.at[pl.ds(scalar(metabuf, slot, 0), T), :, cols],
                 st_sem)
             st.start()
             st.wait()     # band landed: tile t+1 may stage-read any row
@@ -343,33 +454,54 @@ def lut_eval_streamed_pallas(pi_words: jax.Array, meta: jax.Array,
     ``repro.synth.executor.compile_tile_plan`` for the plan itself).
 
     pi_words: (n_pis, W) int32; meta: (n_tiles, R, 128) int32 per-tile
-    records from ``pack_tile_meta``. Returns the renumbered wire plane
-    (n_rows, W) int32 — row 0 const-0, rows 1..n_pis the inputs, then
-    one band of ``T`` rows per tile (pad rows hold 0).
+    records from ``pack_tile_meta`` for the same ``gather``. Returns the
+    renumbered wire plane (n_rows, W) int32 — row 0 const-0, rows
+    1..n_pis the inputs, then one band of ``T`` rows per tile (pad rows
+    hold 0).
 
-    Inside, the word axis is padded to whole 128-lane tiles and each
-    wire row is its own ``(1, words)`` slab of a 3-D plane, so every
-    DMA the kernel makes slices only whole tiles. A row still costs one
-    vreg row at any W <= 128, so the padding adds DMA bytes, not folds.
+    Inside, the word axis is padded to whole 128-lane tiles. Under
+    ``"vmem"`` the plane is 2-D with its rows padded to a multiple of 8
+    (the copy-out DMA moves whole (8, 128) tiles), and the kernel may
+    take that plane plus ``VMEM_MARGIN`` of VMEM. Under the HBM modes
+    each wire row is its own ``(1, words)`` slab of a 3-D plane, so
+    every DMA the kernel makes slices only whole tiles. A row still
+    costs one vreg row at any W <= 128, so the padding adds DMA bytes,
+    not folds.
     """
     if gather not in GATHER_MODES:
         raise ValueError(f"unknown gather mode {gather!r} "
                          f"(expected one of {GATHER_MODES})")
+    rows = _meta_layout(tile_rows, record_gather_cap(gather, gather_cap),
+                        k)[-1]
+    if meta.shape[1:] != (rows, LANES):
+        raise ValueError(f"meta records {meta.shape[1:]} are not the "
+                         f"{gather!r} layout ({rows}, {LANES}): pack them "
+                         f"with pack_tile_meta(tplan, {gather!r})")
     _, w = pi_words.shape
     bw = -(-max(block_w, 1) // LANES) * LANES
     wp = -(-w // bw) * bw
-    words = jnp.pad(pi_words, ((0, 0), (0, wp - w))).reshape(n_pis, 1, wp)
+    words = jnp.pad(pi_words, ((0, 0), (0, wp - w)))
+    if gather == "vmem":
+        plane_rows = -(-n_rows // 8) * 8
+        pi_spec = pl.BlockSpec((n_pis, bw), lambda i: (0, i))
+        plane_shape = (plane_rows, wp)
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=plane_rows * bw * 4 + VMEM_MARGIN)
+    else:
+        words = words.reshape(n_pis, 1, wp)
+        pi_spec = pl.BlockSpec((n_pis, 1, bw), lambda i: (0, 0, i))
+        plane_shape = (n_rows, 1, wp)
+        params = None
     plane = pl.pallas_call(
         functools.partial(_streamed_kernel, n_pis=n_pis, n_tiles=n_tiles,
                           T=tile_rows, G=gather_cap, k=k, bw=bw,
                           gather=gather),
         grid=(wp // bw,),
-        in_specs=[
-            pl.BlockSpec((n_pis, 1, bw), lambda i: (0, 0, i)),   # pi block
-            pl.BlockSpec(memory_space=pl.ANY),                   # meta
-        ],
+        in_specs=[pi_spec,                                   # pi block
+                  pl.BlockSpec(memory_space=pl.ANY)],        # meta
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((n_rows, 1, wp), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(plane_shape, jnp.int32),
+        compiler_params=params,
         interpret=interpret,
     )(words, meta)
-    return plane[:, 0, :w]
+    return plane.reshape(plane_shape[0], wp)[:n_rows, :w]

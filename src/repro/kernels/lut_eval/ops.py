@@ -68,8 +68,8 @@ def lut_eval_streamed(pi_words: np.ndarray, tplan,
     kernel; returns the renumbered (tplan.n_rows, W) uint32 wire plane
     (use ``tplan.out_idx`` / ``tplan.row_of_wire`` to pull outputs).
 
-    pi_words: (n_pis, W) uint32. ``gather=None`` picks the staged-DMA
-    path the chip runs (``lut_eval.default_gather``);
+    pi_words: (n_pis, W) uint32. ``gather=None`` picks the mode the
+    plan's size gives (``lut_eval.default_gather``);
     ``spec.tile.block_w`` sets the word tile (``tile_rows`` geometry is
     baked into the plan itself).
     """
@@ -82,14 +82,14 @@ def lut_eval_streamed(pi_words: np.ndarray, tplan,
     w = pi_words.shape[1]
     interpret = spec.resolve_interpret(interpret)
     if gather is None:
-        gather = default_gather()
+        gather = default_gather(tplan, interpret, spec.tile.block_w)
     if tplan.n_tiles == 0 or tplan.n_pis == 0 or w == 0:
         vals = np.zeros((tplan.n_rows, w), np.uint32)
         vals[1: tplan.n_pis + 1] = pi_words
         return vals
     out = lut_eval_streamed_pallas(
         jnp.asarray(pi_words.view(np.int32)),
-        jnp.asarray(pack_tile_meta(tplan)),
+        jnp.asarray(pack_tile_meta(tplan, gather)),
         n_pis=tplan.n_pis, n_tiles=tplan.n_tiles,
         tile_rows=tplan.tile_rows, gather_cap=tplan.gather_cap,
         n_rows=tplan.n_rows, k=tplan.k,

@@ -223,12 +223,12 @@ def profile_tile_plan(tplan, w_words: int = 128, iters: int = 3,
     if interpret is None:
         interpret = default_interpret()
     if gather is None:
-        gather = default_gather()
+        gather = default_gather(tplan, interpret, min(128, w_words))
     rng = np.random.default_rng(seed)
     words = rng.integers(0, 1 << 31, (max(tplan.n_pis, 1), w_words),
                          dtype=np.int64)
     jwords = jnp.asarray(words.astype(np.int32))
-    meta = jnp.asarray(pack_tile_meta(tplan))
+    meta = jnp.asarray(pack_tile_meta(tplan, gather))
     fanins = tile_plan_fanins(tplan)
 
     prefix_us = []

@@ -157,9 +157,10 @@ class TilePlan:
     0 to their own (never-read) pad row — no per-slot validity branch
     and no dump row.
 
-    ``leaf_tiles`` holds plane-row leaf indices, the reference that
-    ``repro.check`` holds the staged remap to; ``gather_rows``/
-    ``leaf_loc`` are that remap, which the kernel reads
+    ``leaf_tiles`` holds plane-row leaf indices, which the kernel reads
+    with the plane in VMEM and the reference that ``repro.check`` holds
+    the staged remap to; ``gather_rows``/``leaf_loc`` are that remap,
+    which the kernel reads with the plane in HBM
     (``pack_tile_meta``): ``gather_rows[t]`` lists the tile's unique
     leaf rows (padded by re-reading row 0) and
     ``leaf_loc[t, s, j]`` is slot ``s``'s position of leaf ``j`` inside
@@ -600,8 +601,11 @@ class _PallasExecutor(_DeviceExecutor):
 
 
 class _StreamedExecutor(_DeviceExecutor):
-    """The streamed/tiled on-device pipeline over a ``TilePlan``: HBM
-    wire plane, double-buffered plan-tensor DMA, whole-tile folds.
+    """The streamed/tiled on-device pipeline over a ``TilePlan``:
+    double-buffered plan-tensor DMA, whole-tile folds, and the wire
+    plane in VMEM where it fits the core's budget, else in HBM
+    (``gather`` is ``"vmem"`` or ``"dma"``, from the plan's size: see
+    ``repro.check.plan_check.gather_mode``).
 
     Tile geometry comes from, in priority order: an explicit ``spec``,
     the persisted autotune cache (keyed by the plan's sha1 fingerprint,
@@ -628,14 +632,18 @@ class _StreamedExecutor(_DeviceExecutor):
         dp.tiles = tp
         self.dp = dp
         self.tp = tp
-        self.gather = default_gather() if gather is None else gather
-        self._meta = self._put(pack_tile_meta(tp))
+        self.gather = (default_gather(tp, self.interpret,
+                                      self.spec.tile.block_w)
+                       if gather is None else gather)
+        self._meta = self._put(pack_tile_meta(tp, self.gather))
         self._out_idx = self._put(tp.out_idx.astype(np.int32))
         self._neg = self._put(np.where(tp.out_neg, -1, 0).astype(np.int32))
         # one dict for every fetch span of this engine
         self.fetch_args = {"luts": bitnet.mapped.n_luts,
                            "tiles": tp.n_tiles,
-                           "staged_rows": staged_rows(tp)}
+                           "gather": self.gather,
+                           "staged_rows": (staged_rows(tp)
+                                           if self.gather == "dma" else 0)}
 
     def _eval_words(self, words):
         from repro.kernels.lut_eval.lut_eval import lut_eval_streamed_pallas

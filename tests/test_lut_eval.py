@@ -1,8 +1,12 @@
 """kernels/lut_eval: on-device mapped-netlist execution vs the numpy
 fold, the jnp scan oracle, and the per-sample gather oracle (Pallas in
 interpret mode on CPU, same pattern as kernels/aig_sim)."""
+import importlib
+import types
+
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hyp_compat import given, settings, st
 
 from repro.kernels.lut_eval import (lut_eval, lut_eval_gather_ref,
@@ -163,29 +167,38 @@ def test_streamed_multi_tile_levels():
 
 def test_pack_tile_meta_round_trips_the_tile_plan():
     """The streamed kernel's per-tile record holds exactly the plan's
-    band base, staged-gather remap and INIT bits, in 128-lane rows."""
+    band base, leaves and INIT bits, in 128-lane rows: the staged
+    remap (``leaf_loc`` into ``gather_rows``) with the plane in HBM,
+    the plane rows themselves (``leaf_tiles``) with it in VMEM."""
     from repro.kernels.lut_eval.lut_eval import (LANES, _meta_layout,
                                                  pack_tile_meta)
     from repro.synth import compile_tile_plan
     from repro.synth.executor import _compile_plan as cp
     mapped = _random_mapped(4, 10, 4)
     tp = compile_tile_plan(cp(mapped), mapped.n_pis, mapped.k, tile_rows=8)
-    T, G, k = tp.tile_rows, tp.gather_cap, tp.k
-    loc, grow, init, n_words, rows = _meta_layout(T, G, k)
-    meta = pack_tile_meta(tp)
-    assert meta.shape == (tp.n_tiles, rows, LANES)
-    assert meta.dtype == np.int32
-    flat = meta.reshape(tp.n_tiles, -1)
-    np.testing.assert_array_equal(flat[:, 0], tp.out_base)
-    np.testing.assert_array_equal(flat[:, loc:grow].reshape(tp.leaf_loc.shape),
-                                  tp.leaf_loc)
-    np.testing.assert_array_equal(flat[:, grow:init], tp.gather_rows)
-    words = flat[:, init:init + T * n_words].view(np.uint32).reshape(
-        tp.n_tiles, T, n_words)
-    r = np.arange(1 << k)
-    bits = (words[:, :, r // 32] >> (r % 32)) & 1
-    np.testing.assert_array_equal(bits, tp.tt_tiles & 1)
-    assert not flat[:, init + T * n_words:].any()
+    T, k = tp.tile_rows, tp.k
+    for gather in ("dma", "vmem"):
+        G = 0 if gather == "vmem" else tp.gather_cap
+        loc, grow, init, n_words, rows = _meta_layout(T, G, k)
+        meta = pack_tile_meta(tp, gather)
+        assert meta.shape == (tp.n_tiles, rows, LANES)
+        assert meta.dtype == np.int32
+        flat = meta.reshape(tp.n_tiles, -1)
+        np.testing.assert_array_equal(flat[:, 0], tp.out_base)
+        leaves = flat[:, loc:grow].reshape(tp.leaf_loc.shape)
+        if gather == "vmem":
+            assert grow == init
+            np.testing.assert_array_equal(leaves, tp.leaf_tiles)
+        else:
+            np.testing.assert_array_equal(leaves, tp.leaf_loc)
+            np.testing.assert_array_equal(flat[:, grow:init],
+                                          tp.gather_rows)
+        words = flat[:, init:init + T * n_words].view(np.uint32).reshape(
+            tp.n_tiles, T, n_words)
+        r = np.arange(1 << k)
+        bits = (words[:, :, r // 32] >> (r % 32)) & 1
+        np.testing.assert_array_equal(bits, tp.tt_tiles & 1)
+        assert not flat[:, init + T * n_words:].any()
 
 
 def test_tile_plan_structure():
@@ -212,11 +225,12 @@ def test_tile_plan_structure():
 
 @settings(max_examples=10, deadline=None)
 @given(n=st.integers(1, 5), n_outs=st.integers(1, 3),
-       tile_rows=st.sampled_from([1, 2, 8, 32]), data=st.data())
-def test_streamed_exhaustive_property(n, n_outs, tile_rows, data):
+       tile_rows=st.sampled_from([1, 2, 8, 32]),
+       gather=st.sampled_from(["fancy", "dma", "vmem"]), data=st.data())
+def test_streamed_exhaustive_property(n, n_outs, tile_rows, gather, data):
     """Random mapped netlists agree with the host fold on every input
-    pattern through the streamed kernel, at tile sizes from degenerate
-    (1 slot/tile) to larger-than-any-level."""
+    pattern through the streamed kernel, in every gather mode, at tile
+    sizes from degenerate (1 slot/tile) to larger-than-any-level."""
     from repro.synth import compile_tile_plan, execute_packed_streamed
     from repro.synth.executor import _compile_plan as cp
     aig = AIG(n)
@@ -233,7 +247,97 @@ def test_streamed_exhaustive_property(n, n_outs, tile_rows, data):
     pats = input_patterns(n)
     np.testing.assert_array_equal(
         execute_packed(mapped, pats),
-        execute_packed_streamed(mapped, pats, tplan=tp))
+        execute_packed_streamed(mapped, pats, tplan=tp, gather=gather))
+
+
+def _const_mapped():
+    aig = AIG(3)
+    aig.outputs = [1]           # const-1 literal
+    return synthesize(aig)
+
+
+@pytest.mark.parametrize("net,tile_rows,n_words", [
+    ("one-tile", 8, 8), ("one-tile", 32, 256),
+    ("multi-tile", 8, 8), ("multi-tile", 8, 33),
+    ("multi-tile", 32, 33), ("multi-tile", 32, 256),
+    ("constant", 8, 33)])
+def test_streamed_vmem_gather_matches_dma_and_numpy(net, tile_rows, n_words):
+    """The resident-plane mode writes the same wire plane as the
+    staged-DMA mode, and its outputs equal the host fold: one tile and
+    several, at 8 and 32 slots a tile, a padded word count (33) and two
+    128-lane blocks (256, the VMEM scratch reused across grid steps),
+    and the constant network. Every kernel compile here unrolls a whole
+    tile, so the cases cover each value rather than every product."""
+    from repro.kernels.lut_eval import lut_eval_streamed
+    from repro.synth import compile_tile_plan
+    from repro.synth.executor import _compile_plan as cp
+    mapped = {"one-tile": lambda: _random_mapped(0, 3, 2),
+              "multi-tile": lambda: _random_mapped(0, 9, 3),
+              "constant": _const_mapped}[net]()
+    tp = compile_tile_plan(cp(mapped), mapped.n_pis, mapped.k,
+                           tile_rows=tile_rows)
+    n_tiles = {"one-tile": tp.n_tiles == 1, "multi-tile": tp.n_tiles > 1,
+               "constant": tp.n_tiles == 0}
+    assert n_tiles[net], tp.n_tiles
+    words = random_words(mapped.n_pis, n_words, seed=n_words)
+    vmem = lut_eval_streamed(words, tp, gather="vmem")
+    np.testing.assert_array_equal(
+        vmem, lut_eval_streamed(words, tp, gather="dma"))
+    out = vmem[tp.out_idx]
+    out[tp.out_neg] = ~out[tp.out_neg]
+    np.testing.assert_array_equal(out, execute_packed(mapped, words))
+
+
+@pytest.mark.parametrize("block_w,lanes", [(8, 128), (128, 128),
+                                           (256, 256)])
+def test_gather_mode_at_the_vmem_budget(block_w, lanes):
+    """The plane stays in VMEM up to half the core's capacity, counted
+    with rows padded to 8 and words to whole 128-lane blocks; one more
+    row block of plane takes the HBM (``dma``) path."""
+    from repro.check.plan_check import gather_mode, resident_plane_bytes
+    cap = 1 << 20
+    rows_at_budget = cap // 2 // (lanes * 4)          # a multiple of 8
+    at = types.SimpleNamespace(n_rows=rows_at_budget - 7)
+    over = types.SimpleNamespace(n_rows=rows_at_budget + 1)
+    assert resident_plane_bytes(at, block_w) == cap // 2
+    assert resident_plane_bytes(over, block_w) == cap // 2 + 8 * lanes * 4
+    assert gather_mode(at, cap, block_w) == "vmem"
+    assert gather_mode(over, cap, block_w) == "dma"
+    assert gather_mode(at, cap - 1, block_w) == "dma"
+
+
+def _streamed_executor(mapped, **kw):
+    from repro.synth.executor import _compile_plan, _StreamedExecutor
+    # the executor reads only these attributes of its BitplaneNetwork
+    bitnet = types.SimpleNamespace(
+        mapped=mapped, _plan=_compile_plan(mapped), in_bits=1, out_bits=1,
+        out_levels=np.arange(2, dtype=np.float32), device=None)
+    return _StreamedExecutor(bitnet, interpret=True, use_cache=False, **kw)
+
+
+def test_streamed_executor_gather_counters(monkeypatch):
+    """The engine takes the mode the plan's size gives (a v5e's VMEM
+    when interpreting) and says so on its ``fetch`` spans:
+    ``staged_rows`` is 0 where nothing is staged."""
+    from repro.check.plan_check import (V5E_VMEM_BYTES, gather_mode,
+                                        resident_plane_bytes)
+    from repro.synth.executor import staged_rows
+    lut_eval = importlib.import_module("repro.kernels.lut_eval.lut_eval")
+    mapped = _random_mapped(0, 9, 3)
+    ex = _streamed_executor(mapped)
+    assert ex.gather == gather_mode(ex.tp, V5E_VMEM_BYTES) == "vmem"
+    assert ex.fetch_args == {"luts": mapped.n_luts, "tiles": ex.tp.n_tiles,
+                             "gather": "vmem", "staged_rows": 0}
+    # a core whose budget the plane overflows keeps the plane in HBM
+    monkeypatch.setattr(lut_eval, "vmem_capacity_bytes",
+                        lambda interpret: resident_plane_bytes(ex.tp))
+    small = _streamed_executor(mapped)
+    assert small.gather == "dma"
+    assert small.fetch_args["gather"] == "dma"
+    assert small.fetch_args["staged_rows"] == staged_rows(small.tp) > 0
+    # an explicit mode is kept as given
+    assert _streamed_executor(mapped, gather="fancy").fetch_args[
+        "staged_rows"] == 0
 
 
 def test_over_vmem_netlist_runs_streamed():

@@ -7,6 +7,7 @@ HBM refs, too much SMEM or VMEM. The topology is described inside a
 fixture, never at import, so that only the worker given this file loads
 the TPU compiler.
 """
+import importlib
 import os
 import types
 
@@ -17,7 +18,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.lut_eval.lut_eval import (_meta_layout, lut_eval_pallas,
-                                             lut_eval_streamed_pallas)
+                                             lut_eval_streamed_pallas,
+                                             record_gather_cap)
 
 K = 6
 N_CLASSES = 5
@@ -26,6 +28,9 @@ N_CLASSES = 5
 JSC_S = dict(n_pis=32, n_slots=96, n_tiles=4, gather_cap=96)
 # JSC-L-sized tile plan: 16 inputs x 3 bits = 48 PIs, ~12k LUT slots.
 JSC_L = dict(n_pis=48, n_tiles=380, gather_cap=192)
+# JSC-M as served (bench/configs/jsc-m.json): 48 PIs, 60,415 LUTs in
+# 1,902 tiles of 32 slots, up to 159 staged leaf rows per tile.
+JSC_M = dict(n_pis=48, n_tiles=1902, gather_cap=159)
 
 
 @pytest.fixture(scope="module")
@@ -78,34 +83,51 @@ def test_monolithic_kernel_compiles_jsc_s(one_chip, w):
     _custom_call_hlo(compiled)
 
 
-def _compile_streamed(sharding, n_pis, n_tiles, gather_cap, w,
+def _compile_streamed(sharding, n_pis, n_tiles, gather_cap, w, gather,
                       tile_rows=32):
     n_rows = 1 + n_pis + n_tiles * tile_rows
-    rows = _meta_layout(tile_rows, gather_cap, K)[-1]
+    rows = _meta_layout(tile_rows, record_gather_cap(gather, gather_cap),
+                        K)[-1]
 
     def run(words, meta):
         return lut_eval_streamed_pallas(
             words, meta, n_pis=n_pis, n_tiles=n_tiles, tile_rows=tile_rows,
             gather_cap=gather_cap, n_rows=n_rows, k=K, block_w=min(w, 128),
-            gather="dma", interpret=False)
+            gather=gather, interpret=False)
 
     return jax.jit(run).lower(_shape(sharding, n_pis, w),
                               _shape(sharding, n_tiles, rows, 128)).compile()
 
 
+@pytest.mark.parametrize("gather", ["dma", "vmem"])
 @pytest.mark.parametrize("w", [8, 128])
-def test_streamed_dma_kernel_compiles_jsc_s(one_chip, w):
-    """The serving shape (W = 8) and a lane-aligned one (W = 128)."""
+def test_streamed_dma_kernel_compiles_jsc_s(one_chip, w, gather):
+    """The serving shape (W = 8) and a lane-aligned one (W = 128), with
+    the plane in HBM (staged-DMA leaves) and resident in VMEM."""
     _custom_call_hlo(_compile_streamed(
-        one_chip, JSC_S["n_pis"], JSC_S["n_tiles"], JSC_S["gather_cap"], w))
+        one_chip, JSC_S["n_pis"], JSC_S["n_tiles"], JSC_S["gather_cap"], w,
+        gather))
 
 
+@pytest.mark.parametrize("gather", ["dma", "vmem"])
 @pytest.mark.parametrize("w", [8, 256])
-def test_streamed_dma_kernel_compiles_jsc_l(one_chip, w):
+def test_streamed_dma_kernel_compiles_jsc_l(one_chip, w, gather):
     """A JSC-L-sized plan: the monolithic kernel's SMEM leaf table does
     not fit at this size, so the streamed engine is its only path."""
     _custom_call_hlo(_compile_streamed(
-        one_chip, JSC_L["n_pis"], JSC_L["n_tiles"], JSC_L["gather_cap"], w))
+        one_chip, JSC_L["n_pis"], JSC_L["n_tiles"], JSC_L["gather_cap"], w,
+        gather))
+
+
+@pytest.mark.parametrize("gather", ["dma", "vmem"])
+@pytest.mark.parametrize("w", [8, 256])
+def test_streamed_kernel_compiles_jsc_m(one_chip, w, gather):
+    """JSC-M's served plan: its 60,913-row plane (31.2 MB at 128 lanes)
+    resident in VMEM, and the same plan with the plane in HBM; W = 256
+    runs two grid steps over one scratch."""
+    _custom_call_hlo(_compile_streamed(
+        one_chip, JSC_M["n_pis"], JSC_M["n_tiles"], JSC_M["gather_cap"], w,
+        gather))
 
 
 def _random_netlist(n_pis: int, n_ands: int, n_outs: int, seed: int = 0):
@@ -123,10 +145,15 @@ def _random_netlist(n_pis: int, n_ands: int, n_outs: int, seed: int = 0):
     return synthesize(aig)
 
 
-def test_streamed_executor_classify_compiles(one_chip):
+def test_streamed_executor_classify_compiles(one_chip, monkeypatch):
     """The fused classify jit the aggregator calls (pack -> streamed
-    kernel -> complement -> decode -> argmax) at the serving shape."""
+    kernel -> complement -> decode -> argmax) at the serving shape, in
+    the gather mode the plan's size gives on a v5e core, and with the
+    plane in HBM where a core's budget is smaller than the plane."""
+    from repro.check.plan_check import (V5E_VMEM_BYTES, gather_mode,
+                                        resident_plane_bytes)
     from repro.synth.executor import _compile_plan, _StreamedExecutor
+    lut_eval = importlib.import_module("repro.kernels.lut_eval.lut_eval")
 
     mapped = _random_netlist(JSC_S["n_pis"], 600, N_CLASSES * 3)
     assert mapped.n_luts > 32               # more than one tile
@@ -134,8 +161,17 @@ def test_streamed_executor_classify_compiles(one_chip):
     bitnet = types.SimpleNamespace(
         mapped=mapped, _plan=_compile_plan(mapped), in_bits=2, out_bits=3,
         out_levels=np.arange(8, dtype=np.float32), device=None)
+    # the described chip is a v5e; this process's default device is not
+    monkeypatch.setattr(lut_eval, "vmem_capacity_bytes",
+                        lambda interpret: V5E_VMEM_BYTES)
     ex = _StreamedExecutor(bitnet, interpret=False, use_cache=False)
-    assert ex.gather == "dma"
-    compiled = ex._argmax_words.lower(
-        _shape(one_chip, JSC_S["n_pis"], 8), n_classes=N_CLASSES).compile()
-    _custom_call_hlo(compiled)
+    assert ex.gather == gather_mode(ex.tp, V5E_VMEM_BYTES) == "vmem"
+    monkeypatch.setattr(lut_eval, "vmem_capacity_bytes",
+                        lambda interpret: resident_plane_bytes(ex.tp))
+    over = _StreamedExecutor(bitnet, interpret=False, use_cache=False)
+    assert over.gather == "dma"
+    for e in (ex, over):
+        compiled = e._argmax_words.lower(
+            _shape(one_chip, JSC_S["n_pis"], 8),
+            n_classes=N_CLASSES).compile()
+        _custom_call_hlo(compiled)
