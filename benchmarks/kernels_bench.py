@@ -60,7 +60,8 @@ def run() -> Dict:
     # lut_eval: whole mapped-netlist execution, 8k samples — the bitplane
     # Shannon fold (numpy host / jnp scan oracle / Pallas kernel) vs the
     # per-sample table-gather path on the same netlist
-    from repro.kernels.lut_eval import lut_eval, lut_eval_gather_ref, lut_eval_ref
+    from repro.kernels.lut_eval import (lut_eval_gather_ref, lut_eval_ref,
+                                        lut_eval_streamed)
     from repro.synth import compile_device_plan, synthesize
     from repro.synth.executor import _compile_plan, execute_packed
     from repro.synth.simulate import unpack_bits
@@ -84,21 +85,11 @@ def run() -> Dict:
         jax.jit(lambda w: lut_eval_ref(w, flat_leaf, flat_tt, flat_ow,
                                        dp.n_pis, dp.n_wires)),
         jnp.asarray(lwords.view(np.int32)))
-    out["lut_eval_pallas_us"] = _t(
-        lambda w: lut_eval(w, dp.leaf_idx, dp.tt_bits, dp.out_wires,
-                           n_pis=dp.n_pis, n_wires=dp.n_wires),
-        lwords, iters=3)
-    # streamed/tiled kernel: same netlist through the TilePlan route
-    # (HBM-resident wire plane, double-buffered per-tile plan tensors)
-    from repro.kernels.lut_eval import lut_eval_streamed
-    from repro.synth import compile_tile_plan
-    tp = compile_tile_plan(plan, dp.n_pis, dp.k)
+    # the served kernel over the same netlist's tile plan
     out["lut_eval_streamed_us"] = _t(
-        lambda w: lut_eval_streamed(w, tp), lwords, iters=5)
-    # dimensionless cross-kernel ratios (direction-aware CI gates; the
-    # *_us rows drift with host load, the ratios should not)
-    out["lut_eval_streamed_vs_pallas_x"] = (
-        out["lut_eval_streamed_us"] / out["lut_eval_pallas_us"])
+        lambda w: lut_eval_streamed(w, dp.tiles), lwords, iters=5)
+    # dimensionless cross-kernel ratio (direction-aware CI gate; the
+    # *_us rows drift with host load, the ratio should not)
     out["aig_sim_pallas_vs_ref_x"] = (
         out["aig_sim_pallas_us"] / out["aig_sim_ref_us"])
     lbits = jnp.asarray(unpack_bits(lwords, 256 * 32), jnp.int32)

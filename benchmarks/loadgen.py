@@ -1,7 +1,7 @@
 """Load-generator harness for the ``repro.serve`` scheduler.
 
 Drives the micro-batching scheduler end-to-end on JSC-S across the
-``LogicEngine`` backends (``bitplane-pallas`` = mapped netlist on the
+``LogicEngine`` backends (``bitplane-streamed`` = mapped netlist on the
 ``kernels/lut_eval`` device executor) and writes ``BENCH_serve.json``
 at the repo root:
 
@@ -37,18 +37,17 @@ import numpy as np
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-BACKENDS = ("gather", "pallas", "bitplane", "bitplane-pallas",
-            "bitplane-streamed")
+BACKENDS = ("gather", "pallas", "bitplane", "bitplane-streamed")
 
 
 def parse_backend(spec: str, engine: str = "numpy"):
     """Backend spec -> (LogicEngine backend, bitplane engine).
 
-    ``"bitplane-<engine>"`` pins the bitplane backend to that executor
-    from the ``repro.synth.executors`` registry regardless of
-    ``--engine`` (``bitplane-streamed`` is shorthand for the
-    ``pallas-streamed`` engine); plain ``"bitplane"`` uses ``engine``
-    (default numpy host fold)."""
+    ``"bitplane-<engine>"`` pins the bitplane backend to that engine
+    (one of ``repro.synth.ENGINES``) regardless of ``--engine``
+    (``bitplane-streamed`` is shorthand for the ``pallas-streamed``
+    engine); plain ``"bitplane"`` uses ``engine`` (default numpy host
+    fold)."""
     if spec == "bitplane-streamed":
         return "bitplane", "pallas-streamed"
     if spec.startswith("bitplane-"):
@@ -137,14 +136,13 @@ def _wire_sinks(sched, sinks) -> None:
 
 def run_open_loop(executor, xs: np.ndarray, qps: float, seed: int = 0,
                   max_batch: int = 256, max_wait_us: float = 200.0,
-                  tracer=None, exec_estimate_us: Optional[float] = None,
-                  sinks: Optional[Sequence] = None):
+                  tracer=None, sinks: Optional[Sequence] = None):
     """Real-time Poisson open loop into a threaded scheduler."""
     from repro.serve import MicroBatchScheduler, RequestRejected, SchedConfig
 
     n = xs.shape[0]
     cfg = SchedConfig(max_batch=max_batch, max_wait_us=max_wait_us,
-                      max_queue=2 * n, exec_estimate_us=exec_estimate_us)
+                      max_queue=2 * n)
     sched = MicroBatchScheduler(executor, cfg, tracer=tracer)
     _wire_sinks(sched, sinks)
     sched.start()
@@ -167,7 +165,6 @@ def run_slo_lanes(executor, xs: np.ndarray, qps: float,
                   slo_us: Sequence[float], seed: int = 0,
                   max_batch: int = 256, max_wait_us: float = 200.0,
                   tight_every: int = 4, tracer=None,
-                  exec_estimate_us: Optional[float] = None,
                   sinks: Optional[Sequence] = None):
     """Two-lane SLO open loop: every ``tight_every``-th request rides
     lane 0 (tight SLO), the rest lane 1 (loose SLO). Deadlines default
@@ -179,8 +176,7 @@ def run_slo_lanes(executor, xs: np.ndarray, qps: float,
     n = xs.shape[0]
     cfg = SchedConfig(max_batch=max_batch, max_wait_us=max_wait_us,
                       max_queue=2 * n, n_priorities=max(2, len(slo_us)),
-                      lane_slo_us=tuple(slo_us),
-                      exec_estimate_us=exec_estimate_us)
+                      lane_slo_us=tuple(slo_us))
     sched = MicroBatchScheduler(executor, cfg, tracer=tracer)
     _wire_sinks(sched, sinks)
     sched.start()
@@ -209,14 +205,13 @@ def run_slo_lanes(executor, xs: np.ndarray, qps: float,
 
 def run_closed_loop(executor, xs: np.ndarray, concurrency: int = 32,
                     max_batch: int = 256, max_wait_us: float = 200.0,
-                    tracer=None, exec_estimate_us: Optional[float] = None,
-                    sinks: Optional[Sequence] = None):
+                    tracer=None, sinks: Optional[Sequence] = None):
     """Fixed in-flight submit→wait workers (peak throughput probe)."""
     from repro.serve import MicroBatchScheduler, SchedConfig
 
     n = xs.shape[0]
     cfg = SchedConfig(max_batch=max_batch, max_wait_us=max_wait_us,
-                      max_queue=2 * n, exec_estimate_us=exec_estimate_us)
+                      max_queue=2 * n)
     sched = MicroBatchScheduler(executor, cfg, tracer=tracer)
     _wire_sinks(sched, sinks)
     sched.start()
@@ -340,11 +335,7 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
 
     ``trace=PATH`` records the full request lifecycle with
     ``repro.obs`` and writes a Perfetto-loadable Chrome trace there
-    (metrics-registry snapshot embedded as ``otherData``), plus a
-    measured per-level ``lut_eval`` latency table next to it
-    (``<PATH stem>.lut_table.json``) whose whole-netlist estimate seeds
-    the scheduler's flush margin and replica dispatch for the
-    bitplane-pallas backend.
+    (metrics-registry snapshot embedded as ``otherData``).
 
     ``registry`` lets a caller (``launch.serve --metrics-port``) hand
     in the ``MetricsRegistry`` behind a live pull endpoint: every
@@ -376,33 +367,14 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
                for b, (be, en) in resolved.items()}
     direct = {b: engines[b].classify(xs) for b in backends}
 
-    # observability: one tracer + registry across every loadgen phase,
-    # and a calibrated per-level lut_eval latency table for any backend
-    # running the device pipeline
+    # observability: one tracer + registry across every loadgen phase
     tracer = None
-    lut_table = None
-    exec_est_us: Dict[str, float] = {}
     if trace:
-        from repro.obs import MetricsRegistry, SpanTracer, build_latency_table
-        from repro.synth.executor import compile_device_plan
+        from repro.obs import MetricsRegistry, SpanTracer
 
         tracer = SpanTracer(capacity=1 << 18)
         if registry is None:
             registry = MetricsRegistry()
-        for b, (be, en) in resolved.items():
-            if be != "bitplane" or en not in ("pallas", "pallas-streamed"):
-                continue
-            bn = engines[b].bitnet
-            dplan = compile_device_plan(bn.mapped, bn._plan)
-            if lut_table is None:
-                lut_table = build_latency_table(dplan,
-                                                iters=2 if fast else 3)
-            exec_est_us[b] = lut_table.estimate_plan_us(dplan)
-            print(f"[loadgen] {b}: calibrated netlist estimate "
-                  f"{exec_est_us[b]:.1f}us/batch "
-                  f"({dplan.n_levels} levels)")
-        if lut_table is None:           # no device backend: grid only
-            lut_table = build_latency_table(iters=2 if fast else 3)
 
     # legacy sequential reference (gather = the seed's default backend)
     base_eng = engines.get("gather") or next(iter(engines.values()))
@@ -433,7 +405,6 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
                  "baseline_sequential": base, "backends": {}}
     for b in backends:
         be, en = resolved[b]
-        est = exec_est_us.get(b)
         executor = engines[b].scheduler_executor()
         sinks = None
         if registry is not None:
@@ -451,13 +422,12 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
             # least-loaded, so open/closed numbers stay comparable
             executor = build_logic_replicas(
                 net, JSC_S.n_classes, n_replicas=n_replicas, backend=be,
-                max_batch=max_batch, policy="least_slack", engine=en,
-                exec_seed_us=est)
+                max_batch=max_batch, policy="least_slack", engine=en)
         rec: Dict = {"engine": en} if be == "bitplane" else {}
         if loadgen in ("open", "both"):
             got, snap = run_open_loop(executor, xs, offered, seed=seed,
                                       max_batch=max_batch, tracer=tracer,
-                                      exec_estimate_us=est, sinks=sinks)
+                                      sinks=sinks)
             if registry is not None:
                 registry.register(f"{b}.open_loop",
                                   lambda snap=snap: snap)
@@ -469,9 +439,7 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
             # per-lane SLO attainment under moderate two-lane load
             got, lanes, snap = run_slo_lanes(executor, xs, slo_qps, slo_us,
                                              seed=seed, max_batch=max_batch,
-                                             tracer=tracer,
-                                             exec_estimate_us=est,
-                                             sinks=sinks)
+                                             tracer=tracer, sinks=sinks)
             if registry is not None:
                 registry.register(f"{b}.slo_lanes",
                                   lambda snap=snap: snap)
@@ -490,8 +458,7 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
             }
         if loadgen in ("closed", "both"):
             got, snap = run_closed_loop(executor, xs, max_batch=max_batch,
-                                        tracer=tracer,
-                                        exec_estimate_us=est, sinks=sinks)
+                                        tracer=tracer, sinks=sinks)
             if registry is not None:
                 registry.register(f"{b}.closed_loop",
                                   lambda snap=snap: snap)
@@ -521,18 +488,11 @@ def run(fast: bool = False, backends: Sequence[str] = BACKENDS,
 
     if trace:
         from repro.obs import write_chrome_trace
-        table_path = os.path.splitext(trace)[0] + ".lut_table.json"
-        lut_table.save(table_path)
         write_chrome_trace(trace, tracer, other_data=registry.snapshot())
-        out["trace"] = {
-            "path": trace, "events": tracer.n_recorded,
-            "dropped": tracer.n_dropped, "lut_table": table_path,
-            "exec_estimate_us": {k: round(v, 2)
-                                 for k, v in exec_est_us.items()},
-        }
+        out["trace"] = {"path": trace, "events": tracer.n_recorded,
+                        "dropped": tracer.n_dropped}
         print(f"[loadgen] trace: {tracer.n_recorded} events "
               f"({tracer.n_dropped} dropped) -> {trace}")
-        print(f"[loadgen] lut latency table -> {table_path}")
 
     if write_json:
         from benchmarks.meta import bench_meta
@@ -556,12 +516,10 @@ def main(argv=None):
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
-    from repro.synth.executors import names as engine_names
-    ap.add_argument("--engine", choices=list(engine_names()),
-                    default="numpy",
-                    help="bitplane netlist executor from the "
-                         "repro.synth.executors registry (host fold, "
-                         "monolithic device kernel, or pallas-streamed)")
+    from repro.synth import ENGINES
+    ap.add_argument("--engine", choices=ENGINES, default="numpy",
+                    help="bitplane netlist executor: the host fold "
+                         "(numpy) or the device engine (pallas-streamed)")
     ap.add_argument("--slo-us", default=None,
                     help="comma list of per-lane SLO deadline budgets in µs "
                          "(tight lane first, e.g. '5000,50000'; default: "
@@ -570,8 +528,7 @@ def main(argv=None):
                     help="record the request lifecycle with repro.obs: "
                          "writes a Chrome trace-event JSON (open in "
                          "ui.perfetto.dev) with the metrics-registry "
-                         "snapshot as otherData, plus a measured per-level "
-                         "lut_eval latency table (<stem>.lut_table.json)")
+                         "snapshot as otherData")
     args = ap.parse_args(argv)
     slo_us = (tuple(float(v) for v in args.slo_us.split(","))
               if args.slo_us else None)

@@ -5,11 +5,11 @@ One process, through the entry points a user calls: train JSC-S with
 the serving launcher's defaults, compile it to fixed-function logic,
 synthesize the mapped 6-LUT netlist, and serve seeded test rows through
 ``MicroBatchScheduler`` -> ``BitplaneAggregator`` -> the compiled
-``kernels/lut_eval`` engines. Every label must equal the numpy host
-fold's on the same netlist, and each device engine must have run as a
-compiled Mosaic kernel, not in the Pallas interpreter.
+``kernels/lut_eval`` engine (``pallas-streamed``). Every label must
+equal the numpy host fold's on the same netlist, and the engine must
+have run as a compiled Mosaic kernel, not in the Pallas interpreter.
 
-    python chip_smoke.py             # one chip: pallas, pallas-streamed
+    python chip_smoke.py             # one chip, one replica
     python chip_smoke.py --chips 4   # four one-chip replicas vs one
 
 Exits non-zero unless JAX's first device is a TPU. The last line of
@@ -32,6 +32,7 @@ N_REQUESTS = 4096
 MAX_BATCH = 256          # loadgen's serving batch: pad_rows -> W = 8 words
 TRAIN_STEPS = 400        # repro.launch.serve --train-steps default
 SEED = 0
+ENGINE = "pallas-streamed"
 
 
 def check_device(n_chips: int):
@@ -94,18 +95,16 @@ def numpy_labels(bitnet, xs, n_classes: int):
 
 def require_compiled(bitnet, n_classes: int):
     """The engine ran its Mosaic kernel: not interpreted, the gather
-    mode the plan's size gives where the engine has one (never the
-    interpreter-only ``fancy``), and a ``tpu_custom_call`` in the
+    mode the plan's size gives, and a ``tpu_custom_call`` in the
     lowered classify program."""
     ex = bitnet.executor
     if ex.interpret is not False:
         raise AssertionError(f"{bitnet.engine}: interpret={ex.interpret}")
-    if hasattr(ex, "gather"):
-        from repro.kernels.lut_eval.lut_eval import default_gather
-        want = default_gather(ex.tp, False, ex.spec.tile.block_w)
-        if ex.gather != want or ex.gather == "fancy":
-            raise AssertionError(f"{bitnet.engine}: gather={ex.gather}, "
-                                 f"the plan's size gives {want}")
+    from repro.kernels.lut_eval.lut_eval import default_gather
+    want = default_gather(ex.tp, False, ex.spec.tile.block_w)
+    if ex.gather != want:
+        raise AssertionError(f"{bitnet.engine}: gather={ex.gather}, "
+                             f"the plan's size gives {want}")
     words = ex._put(np.zeros((bitnet.mapped.n_pis, MAX_BATCH // 32),
                              np.int32))
     hlo = ex._argmax_words.lower(words, n_classes=n_classes).as_text()
@@ -129,19 +128,17 @@ def one_chip():
     from repro.serving.engine import LogicEngine
 
     cfg, net, xs, ys = train_logic_net()
-    ref = None
-    for engine in ("pallas", "pallas-streamed"):
-        t0 = time.perf_counter()
-        eng = LogicEngine(net, cfg.n_classes, max_batch=MAX_BATCH,
-                          backend="bitplane", engine=engine)
-        print(f"[smoke] {engine}: {eng.bitnet.mapped.n_luts} LUTs, depth "
-              f"{eng.bitnet.mapped.depth}, synthesized and warmed in "
-              f"{time.perf_counter() - t0:.1f}s", flush=True)
-        require_compiled(eng.bitnet, cfg.n_classes)
-        if ref is None:
-            ref = numpy_labels(eng.bitnet, xs, cfg.n_classes)
-        labels, snap = serve(eng.scheduler_executor(), xs)
-        report(f"engine={engine}", labels, ref, ys, snap)
+    t0 = time.perf_counter()
+    eng = LogicEngine(net, cfg.n_classes, max_batch=MAX_BATCH,
+                      backend="bitplane", engine=ENGINE)
+    print(f"[smoke] {ENGINE}: {eng.bitnet.mapped.n_luts} LUTs, depth "
+          f"{eng.bitnet.mapped.depth}, gather {eng.bitnet.executor.gather}, "
+          f"synthesized and warmed in {time.perf_counter() - t0:.1f}s",
+          flush=True)
+    require_compiled(eng.bitnet, cfg.n_classes)
+    ref = numpy_labels(eng.bitnet, xs, cfg.n_classes)
+    labels, snap = serve(eng.scheduler_executor(), xs)
+    report(f"engine={ENGINE}", labels, ref, ys, snap)
 
 
 def four_chips():
@@ -154,7 +151,7 @@ def four_chips():
     for n in (1, 4):
         rs = build_logic_replicas(net, cfg.n_classes, n_replicas=n,
                                   backend="bitplane", max_batch=MAX_BATCH,
-                                  engine="pallas")
+                                  engine=ENGINE)
         nets = [r.fn.bitnet for r in rs.replicas]
         for bn in nets:
             require_compiled(bn, cfg.n_classes)
@@ -194,9 +191,6 @@ def main(argv=None) -> int:
                     help="4 runs only the four-replica path and the "
                          "one-replica run it is compared with")
     args = ap.parse_args(argv)
-    # pin tile geometry to the spec defaults: no autotune file under
-    # ~/.cache may change what gets compiled
-    os.environ["REPRO_AUTOTUNE_CACHE"] = ""
 
     import jax
 

@@ -41,8 +41,7 @@ from .netlist_lint import lint_aig, lint_mapped
 from .pipeline import (check_sop_stage, check_static, check_synth_pipeline,
                        preflight, verify_plan, verify_synthesis)
 from .plan_check import (DEFAULT_VMEM_BUDGET, estimate_tile_vmem_bytes,
-                         estimate_vmem_bytes, plan_fingerprint,
-                         validate_device_plan)
+                         plan_fingerprint, validate_device_plan)
 from .report import (Counterexample, CheckFailure, CheckReport, Issue,
                      require_ok)
 from .srclint import check_duplicate_definitions
@@ -62,7 +61,7 @@ __all__ = [
     "prove_mapped_plan", "prove_network_mapped",
     "equiv_aig_mapped", "equiv_aigs", "equiv_cover_aig",
     "equiv_mapped_plan", "equiv_network_mapped", "execute_plan_host",
-    "estimate_tile_vmem_bytes", "estimate_vmem_bytes", "lint_aig",
+    "estimate_tile_vmem_bytes", "lint_aig",
     "lint_mapped", "miter",
     "plan_fingerprint", "preflight", "require_ok",
     "synthetic_trace_events",

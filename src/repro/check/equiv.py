@@ -302,8 +302,8 @@ def equiv_aig_mapped(aig: AIG, mapped: MappedNetwork,
 
 def execute_plan_host(dplan: DevicePlan, pi_words: np.ndarray) -> np.ndarray:
     """Slot-by-slot host interpreter of the DevicePlan tensors — the
-    reference semantics of the ``lut_eval`` kernel, sharing no code with
-    ``execute_packed``'s level-vectorized fold."""
+    reference semantics of a slot walk over the netlist, sharing no code
+    with ``execute_packed``'s level-vectorized fold."""
     pi_words = np.asarray(pi_words, np.uint32)
     w = pi_words.shape[1]
     wires = np.zeros((dplan.n_wires + 1, w), np.uint32)   # +1 = dump row

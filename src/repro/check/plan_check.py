@@ -1,8 +1,8 @@
 """Pass 3 — static validation of ``DevicePlan`` tensors before launch.
 
-``compile_device_plan`` output is what the ``lut_eval`` Pallas kernel
-trusts blindly: wire indices become unchecked VMEM loads/stores, INIT
-masks become the Shannon fold, and the dump-row convention turns padded
+``compile_device_plan`` output is what the device engine trusts
+blindly: wire indices become unchecked VMEM loads/stores, INIT masks
+become the Shannon fold, and the dump-row convention turns padded
 slots into silent no-ops. A malformed plan therefore fails *on device*
 (or worse, silently corrupts the wire plane), so every contract is
 checked here on the host first:
@@ -15,8 +15,9 @@ checked here on the host first:
     dump-row output;
   * INIT masks restricted to the {0, 0xFFFFFFFF} bitplane encoding;
   * output gather indices/complements in range;
-  * estimated VMEM footprint (wire plane + plan tensors at the kernel's
-    word tile) against a configurable budget.
+  * the attached tile schedule: its staged-gather remap and tile order;
+  * estimated VMEM working set of one tile step at the kernel's word
+    tile against a configurable budget.
 
 Results are cached by a content hash of the plan so the serving hot
 path (which validates on every ``--check`` preflight) pays the cost
@@ -37,8 +38,8 @@ PASS = "plan"
 
 # mirrors kernels/lut_eval DEFAULT_BW without importing jax here
 _DEFAULT_BLOCK_W = 128
-# the monolithic kernel's budget (a v5e core's default scoped VMEM
-# limit); it wants the whole wire plane resident
+# a v5e core's default scoped VMEM limit, against which one tile step's
+# working set is checked
 DEFAULT_VMEM_BUDGET = 16 << 20
 # a TPU v5e core's VMEM (``pltpu.get_tpu_info().vmem_capacity_bytes``)
 V5E_VMEM_BYTES = 128 << 20
@@ -64,21 +65,10 @@ def plan_fingerprint(dplan: DevicePlan) -> str:
     return h.hexdigest()
 
 
-def estimate_vmem_bytes(dplan: DevicePlan,
-                        block_w: int = _DEFAULT_BLOCK_W) -> int:
-    """Working-set estimate for one *monolithic* lut_eval grid step:
-    the (n_wires+1, block_w) wire plane plus the full plan tensors
-    (leaf indices / INIT masks / output wires live on-chip for the
-    whole slot walk)."""
-    plane = (dplan.n_wires + 1) * block_w * 4
-    plan = (dplan.leaf_idx.size * 4 + dplan.tt_bits.size * 4
-            + dplan.out_wires.size * 4)
-    return plane + plan
-
-
 def estimate_tile_vmem_bytes(tplan, block_w: int = _DEFAULT_BLOCK_W) -> int:
-    """Working-set estimate for one *streamed* tile step. The wire
-    plane stays in HBM; on-chip the kernel holds the PI block, the
+    """Working-set estimate for one tile step with the wire plane in
+    HBM (``"dma"``; a resident plane is sized by ``gather_mode``
+    instead). On-chip the kernel holds the PI block, the
     double-buffered plan tensors for two tiles, the staged leaf rows
     (DMA-gather mode), the gathered-input/fold state of one tile, and
     the output band — so the budget scales with (tile_rows, gather_cap,
@@ -119,12 +109,11 @@ def validate_device_plan(dplan: DevicePlan,
                          use_cache: bool = True,
                          name: str = "device-plan") -> CheckReport:
     """Static checks on a compiled ``DevicePlan``; cached by plan hash."""
-    tp = getattr(dplan, "tiles", None)
     key = None
     if use_cache:
-        tile_key = (tp.tile_rows, tp.gather_cap) if tp is not None else None
+        tp = dplan.tiles
         key = (plan_fingerprint(dplan), vmem_budget_bytes, block_w,
-               tile_key)
+               (tp.tile_rows, tp.gather_cap))
         hit = _CACHE.get(key)
         if hit is not None:
             return hit
@@ -288,61 +277,44 @@ def _validate(dplan: DevicePlan, vmem_budget_bytes: Optional[int],
                   f"out_idx[{i}] = {oi[i]} outside [0, {nw})",
                   where=f"output {i}")
 
-    # ---- tile schedule consistency (streamed kernel) ----
-    tp = getattr(dplan, "tiles", None)
-    if tp is not None:
-        rep.checked += 1
-        staged = tp.gather_rows[
-            np.arange(tp.n_tiles)[:, None, None], tp.leaf_loc]
-        if not np.array_equal(staged, tp.leaf_tiles):
-            t, s, j = (int(x[0]) for x in
-                       np.nonzero(staged != tp.leaf_tiles))
-            rep.error(PASS, "tile-gather",
-                      f"gather_rows[{t}][leaf_loc[{t},{s},{j}]] = "
-                      f"{staged[t, s, j]} != leaf_tiles[{t},{s},{j}] = "
-                      f"{tp.leaf_tiles[t, s, j]} — the staged-DMA remap "
-                      f"disagrees with the direct leaf rows",
-                      where=f"tile {t} slot {s}")
-        rep.checked += 1
-        bad = tp.leaf_tiles >= tp.out_base[:, None, None]
-        if bad.any():
-            t, s, j = (int(x[0]) for x in np.nonzero(bad))
-            rep.error(PASS, "tile-order",
-                      f"tile {t} (band starts at row {tp.out_base[t]}) "
-                      f"reads row {tp.leaf_tiles[t, s, j]} from its own "
-                      f"or a later band — streamed tile order would "
-                      f"read unwritten rows", where=f"tile {t} slot {s}")
+    # ---- tile schedule consistency ----
+    tp = dplan.tiles
+    rep.checked += 1
+    staged = tp.gather_rows[
+        np.arange(tp.n_tiles)[:, None, None], tp.leaf_loc]
+    if not np.array_equal(staged, tp.leaf_tiles):
+        t, s, j = (int(x[0]) for x in
+                   np.nonzero(staged != tp.leaf_tiles))
+        rep.error(PASS, "tile-gather",
+                  f"gather_rows[{t}][leaf_loc[{t},{s},{j}]] = "
+                  f"{staged[t, s, j]} != leaf_tiles[{t},{s},{j}] = "
+                  f"{tp.leaf_tiles[t, s, j]} — the staged-DMA remap "
+                  f"disagrees with the direct leaf rows",
+                  where=f"tile {t} slot {s}")
+    rep.checked += 1
+    bad = tp.leaf_tiles >= tp.out_base[:, None, None]
+    if bad.any():
+        t, s, j = (int(x[0]) for x in np.nonzero(bad))
+        rep.error(PASS, "tile-order",
+                  f"tile {t} (band starts at row {tp.out_base[t]}) "
+                  f"reads row {tp.leaf_tiles[t, s, j]} from its own "
+                  f"or a later band — streamed tile order would "
+                  f"read unwritten rows", where=f"tile {t} slot {s}")
 
-    # ---- VMEM footprint ----
-    # With a tile schedule attached the streamed kernel keeps the wire
-    # plane in HBM, so the budget applies per tile step; otherwise the
-    # monolithic kernel needs the whole plane resident.
+    # ---- VMEM working set of one tile step ----
     rep.info["n_levels"] = n_levels
     rep.info["level_width"] = lw
     rep.checked += 1
-    if tp is not None:
-        est = estimate_tile_vmem_bytes(tp, block_w)
-        rep.info["vmem_bytes"] = est
-        rep.info["tile_rows"] = tp.tile_rows
-        rep.info["n_tiles"] = tp.n_tiles
-        if vmem_budget_bytes is not None and est > vmem_budget_bytes:
-            rep.error(PASS, "vmem-budget",
-                      f"estimated per-tile VMEM working set "
-                      f"{est / 2**20:.1f} MiB (tile_rows "
-                      f"{tp.tile_rows} x {block_w} words, gather_cap "
-                      f"{tp.gather_cap}) exceeds the "
-                      f"{vmem_budget_bytes / 2**20:.1f} MiB budget — "
-                      f"shrink tile_rows or block_w")
-    else:
-        est = estimate_vmem_bytes(dplan, block_w)
-        rep.info["vmem_bytes"] = est
-        if vmem_budget_bytes is not None and est > vmem_budget_bytes:
-            rep.error(PASS, "vmem-budget",
-                      f"estimated VMEM working set {est / 2**20:.1f} MiB "
-                      f"(wire plane {nw + 1} x {block_w} words + plan "
-                      f"tensors) exceeds the "
-                      f"{vmem_budget_bytes / 2**20:.1f} MiB budget — use "
-                      f"the streamed engine (engine=\"pallas-streamed\" "
-                      f"/ compile_device_plan(tile_rows=...)) or a "
-                      f"smaller block_w")
+    est = estimate_tile_vmem_bytes(tp, block_w)
+    rep.info["vmem_bytes"] = est
+    rep.info["tile_rows"] = tp.tile_rows
+    rep.info["n_tiles"] = tp.n_tiles
+    if vmem_budget_bytes is not None and est > vmem_budget_bytes:
+        rep.error(PASS, "vmem-budget",
+                  f"estimated per-tile VMEM working set "
+                  f"{est / 2**20:.1f} MiB (tile_rows "
+                  f"{tp.tile_rows} x {block_w} words, gather_cap "
+                  f"{tp.gather_cap}) exceeds the "
+                  f"{vmem_budget_bytes / 2**20:.1f} MiB budget — "
+                  f"shrink tile_rows or block_w")
     return rep

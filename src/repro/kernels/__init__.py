@@ -7,10 +7,11 @@
   fanin_matmul  — fanin-K gather-matmul for FCP-sparse linear layers.
   aig_sim       — bit-parallel AIG simulation: the node walk of the
                   synthesis-time equivalence checker run on-chip.
-  lut_eval      — whole mapped-netlist execution: the levelized,
-                  width-padded k-LUT plan evaluated as Shannon-cofactor
-                  folds over a VMEM-resident wire plane (the serving
-                  path of ``BitplaneNetwork(engine="pallas")``).
+  lut_eval      — whole mapped-netlist execution: the level-major tile
+                  plan evaluated as Shannon-cofactor folds over a wire
+                  plane in VMEM, or in HBM where it does not fit (the
+                  serving path of
+                  ``BitplaneNetwork(engine="pallas-streamed")``).
   flash_attention — online-softmax attention (VMEM-tiled), the LM-side
                   hot-spot at 32k+ contexts (GQA via grouped heads).
 

@@ -1,33 +1,25 @@
-"""Pallas kernels: whole-netlist evaluation of a mapped k-LUT network.
+"""Pallas kernel: whole-netlist evaluation of a mapped k-LUT network.
 
-Two kernels share the Shannon-cofactor fold (slot i gathers its k leaf
-planes from the wire buffer and folds its 2^k-entry INIT vector over
-them — k select steps, each one AND/ANDN/OR over the whole word tile):
-
-``lut_eval_pallas`` — the original monolithic walk: the whole wire
-plane is the kernel's VMEM output block and a ``fori_loop`` evaluates
-one slot per step. Simple, but every slot pays a dynamic row store
-against the full plane, and the plane must fit VMEM — both of which
-cap it far below the jnp scan oracle and below JSC-M/L-scale netlists.
-
-``lut_eval_streamed_pallas`` — the streamed, tiled, double-buffered
-rebuild. Rows are renumbered level-major
-(``repro.synth.executor.compile_tile_plan``) so every tile of ``T``
-slots writes one contiguous row band. Each tile's scalars (band base,
-INIT bits, leaf indices) travel as one packed ``(R, 128)`` record
-(``pack_tile_meta``) that streams HBM→SMEM through a two-slot buffer:
-tile ``t+1``'s record DMA starts before tile ``t``'s fold, so the plan
-fetch hides behind compute.
+``lut_eval_streamed_pallas`` walks a level-major tile plan
+(``repro.synth.executor.compile_tile_plan``): every tile of ``T`` slots
+writes one contiguous row band, and slot i gathers its k leaf planes
+and folds its 2^k-entry INIT vector over them (a Shannon-cofactor fold:
+k select steps, each one AND/ANDN/OR over the whole word tile). Each
+tile's scalars (band base, INIT bits, leaf indices) travel as one
+packed ``(R, 128)`` record (``pack_tile_meta``) that streams HBM→SMEM
+through a two-slot buffer: tile ``t+1``'s record DMA starts before tile
+``t``'s fold, so the plan fetch hides behind compute.
 
 Where the wire plane lives, and so how leaves are read, is the one
-mode-dependent step (``gather=``):
+mode-dependent step (``gather=``, one of ``GATHER_MODES``), chosen from
+the plan's size (``repro.check.plan_check.gather_mode``):
 
   * ``"vmem"`` — the whole plane of one 128-lane word block is a 2-D
     VMEM scratch: the head rows are vector stores, every leaf is a
     one-row vector load at the plane row its record names, every
     slot's output a one-row vector store, and the finished plane goes
     to HBM in one DMA per grid step. Chosen whenever the padded plane
-    fits the core's VMEM budget (``repro.check.plan_check.gather_mode``).
+    fits the core's VMEM budget.
   * ``"dma"`` — the plane stays in HBM (``memory_space=ANY``), for
     nets too large for VMEM: each tile's unique leaf rows are staged
     HBM→VMEM by per-row async copies into a two-slot stage buffer,
@@ -37,11 +29,8 @@ mode-dependent step (``gather=``):
     this plane is 3-D, ``(rows, 1, W)``, with the word axis padded to
     128 lanes: one wire row is then a leading-dim slice that a DMA may
     move, and the const-0 and PI rows are written by DMA too.
-  * ``"fancy"`` — the HBM plane read by one vector gather
-    ``plane[leaf_rows]`` per tile. Interpreter-only: Mosaic has no
-    arbitrary-row vector gather and no vector access to HBM.
-    Bit-identical to the other two (the test suite runs all three).
 
+Both modes share the fold and write the same plane bit for bit.
 Levelization guarantees every leaf lives on a strictly earlier level,
 so tile-order execution is a topological order; padded slots inside a
 band read the constant-0 row with all-zero INIT masks and write 0 to
@@ -59,7 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BW = 128   # word (packed-sample) tile, lane-aligned
 
-GATHER_MODES = ("fancy", "dma", "vmem")
+GATHER_MODES = ("dma", "vmem")
 
 # VMEM the resident plane's kernel may take beyond the plane itself
 # (the PI block, fold state and the compiler's own scratch)
@@ -76,76 +65,20 @@ def vmem_capacity_bytes(interpret: bool) -> int:
     return pltpu.get_tpu_info().vmem_capacity_bytes
 
 
+def check_gather(gather: str) -> None:
+    """Refuse a gather mode the kernel does not have."""
+    if gather not in GATHER_MODES:
+        raise ValueError(f"unknown gather mode {gather!r} "
+                         f"(expected one of {GATHER_MODES})")
+
+
 def default_gather(tplan, interpret: bool, block_w: int = DEFAULT_BW) -> str:
     """The gather mode the plan's size gives (``gather_mode``):
     ``"vmem"`` where its padded plane fits the core's VMEM budget,
-    ``"dma"`` otherwise; never the interpreter-only ``"fancy"``."""
+    ``"dma"`` otherwise."""
     from repro.check.plan_check import gather_mode
     return gather_mode(tplan, vmem_capacity_bytes(interpret), block_w)
 
-
-# ---------------------------------------------------------------------------
-# Legacy monolithic kernel (VMEM-resident wire plane, one slot per step)
-# ---------------------------------------------------------------------------
-
-def _kernel(leaf_ref, ow_ref, tt_ref, pis_ref, out_ref, *,
-            n_pis: int, n_slots: int, k: int):
-    bw = pis_ref.shape[1]
-    n_tt = tt_ref.shape[1]
-    out_ref[0, :] = jnp.zeros((bw,), jnp.int32)          # const-0 row
-    out_ref[1: n_pis + 1, :] = pis_ref[...]
-
-    def body(i, carry):
-        # INIT masks for slot i, broadcast over the word tile
-        tt = tt_ref[pl.ds(i, 1), :]                               # (1, n_tt)
-        state = jnp.broadcast_to(tt.reshape(n_tt, 1), (n_tt, bw))
-        size = n_tt
-        for j in range(k - 1, -1, -1):   # static unroll: Shannon fold
-            half = size // 2
-            sel = out_ref[pl.ds(leaf_ref[i, j], 1), :]            # (1, bw)
-            state = (state[:half] & ~sel) | (state[half:size] & sel)
-            size = half
-        out_ref[pl.ds(ow_ref[i], 1), :] = state
-        return carry
-
-    jax.lax.fori_loop(0, n_slots, body, 0)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_pis", "n_slots", "n_wires", "k", "block_w",
-                     "interpret"))
-def lut_eval_pallas(pi_words: jax.Array, leaf_idx: jax.Array,
-                    tt_bits: jax.Array, out_wires: jax.Array,
-                    n_pis: int, n_slots: int, n_wires: int, k: int,
-                    block_w: int = DEFAULT_BW,
-                    interpret: bool = True) -> jax.Array:
-    """pi_words: (n_pis, W) int32 packed samples; leaf_idx: (n_slots, k)
-    int32 wire indices; tt_bits: (n_slots, 2^k) int32 INIT masks;
-    out_wires: (n_slots,) int32 wire written per slot. Returns the full
-    wire plane (n_wires + 1, W) int32 — row 0 is const-0, rows
-    1..n_pis echo the inputs, row n_wires is the padded slots' dump."""
-    _, w = pi_words.shape
-    assert w % block_w == 0, (w, block_w)
-    grid = (w // block_w,)
-    return pl.pallas_call(
-        functools.partial(_kernel, n_pis=n_pis, n_slots=n_slots, k=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # leaf_idx
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # out_wires
-            pl.BlockSpec((n_slots, 1 << k), lambda i: (0, 0)),   # tt masks
-            pl.BlockSpec((n_pis, block_w), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((n_wires + 1, block_w), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n_wires + 1, w), jnp.int32),
-        interpret=interpret,
-    )(leaf_idx, out_wires, tt_bits, pi_words)
-
-
-# ---------------------------------------------------------------------------
-# Streamed, tiled, double-buffered kernel (HBM wire plane, T slots/step)
-# ---------------------------------------------------------------------------
 
 LANES = 128   # Mosaic slices HBM/VMEM refs only at whole 128-lane tiles
 
@@ -175,7 +108,7 @@ def pack_tile_meta(tplan, gather: str = "dma") -> np.ndarray:
     ``meta`` operand for a gather mode: (n_tiles, R, 128) int32, one
     record per tile laid out by ``_meta_layout``. Under ``"vmem"`` the
     leaves are plane rows (``leaf_tiles``), read in one SMEM read each;
-    under the staged modes they index the tile's ``gather_rows``
+    under ``"dma"`` they index the tile's ``gather_rows``
     (``leaf_loc``). A 128-lane minor dim is what lets one DMA per tile
     move the whole record into SMEM on the chip."""
     n_tiles, T, k = tplan.n_tiles, tplan.tile_rows, tplan.k
@@ -192,27 +125,6 @@ def pack_tile_meta(tplan, gather: str = "dma") -> np.ndarray:
              << np.arange(32, dtype=np.int64)).sum(axis=3)
     flat[:, init:init + T * n_words] = words.reshape(n_tiles, -1)
     return flat.astype(np.uint32).view(np.int32).reshape(n_tiles, rows, LANES)
-
-
-def _init_masks(words, r):
-    """INIT masks of one LUT: ``words`` its packed INIT bits (scalars or
-    a trailing axis), ``r`` the row index of each mask -> 0 / -1 int32
-    per row (the Shannon fold's starting state)."""
-    word = words[0] if len(words) == 1 else jnp.where(r < 32, *words)
-    return -((word >> (r & 31)) & 1)
-
-
-def _tile_fold(tt_tile, ins, *, T: int, n_tt: int, k: int, bw: int):
-    """Batched Shannon fold of one tile: tt_tile (T, 2^k) INIT masks,
-    ins (T, k, bw) gathered leaf planes -> (T, bw) output planes."""
-    state = jnp.broadcast_to(tt_tile[:, :, None], (T, n_tt, bw))
-    size = n_tt
-    for j in range(k - 1, -1, -1):
-        half = size // 2
-        sel = ins[:, j:j + 1, :]
-        state = (state[:, :half] & ~sel) | (state[:, half:size] & sel)
-        size = half
-    return state[:, 0, :]
 
 
 def _slot_fold(words, leaf, sh, k: int):
@@ -331,45 +243,6 @@ def _streamed_kernel(pi_ref, meta_hbm, plane_ref, *, n_pis: int,
     pl.run_scoped(write_head, zero=pltpu.VMEM((1, 1, bw), jnp.int32),
                   sems=pltpu.SemaphoreType.DMA((2,)))
 
-    if gather == "fancy":
-        def body(metabuf, sem):
-            def meta_dma(slot, t):
-                return pltpu.make_async_copy(meta_hbm.at[t], metabuf.at[slot],
-                                             sem.at[slot])
-
-            meta_dma(0, 0).start()
-
-            def tile_step(t, carry):
-                slot = jax.lax.rem(t, 2)
-
-                # double buffering: tile t+1's record streams in while
-                # tile t folds
-                @pl.when(t + 1 < n_tiles)
-                def _():
-                    meta_dma(1 - slot, t + 1).start()
-
-                meta_dma(slot, t).wait()
-                rec = metabuf[slot].reshape(-1)
-                leaves = rec[grow_at:grow_at + G][
-                    rec[loc_at:grow_at].reshape(T, k)]        # (T, k) rows
-                words = rec[init_at:init_at + T * n_words].reshape(
-                    T, n_words)
-                r = jnp.arange(n_tt, dtype=jnp.int32)
-                masks = _init_masks([words[:, i:i + 1]
-                                     for i in range(n_words)], r)
-                ins = plane_ref[leaves, 0, cols]             # (T, k, bw)
-                out = _tile_fold(masks, ins, T=T, n_tt=n_tt, k=k, bw=bw)
-                plane_ref[pl.ds(rec[0], T), 0, cols] = out
-                return carry
-
-            jax.lax.fori_loop(0, n_tiles, tile_step, 0)
-
-        pl.run_scoped(body,
-                      metabuf=pltpu.VMEM((2,) + meta_hbm.shape[1:],
-                                         jnp.int32),
-                      sem=pltpu.SemaphoreType.DMA((2,)))
-        return
-
     # gather == "dma": stage each tile's unique leaf rows HBM->VMEM by
     # per-row async copies; slots fold from stage-local SMEM indices.
     def body(metabuf, stage, outbuf, meta_sem, stage_sem, st_sem):
@@ -462,15 +335,13 @@ def lut_eval_streamed_pallas(pi_words: jax.Array, meta: jax.Array,
     Inside, the word axis is padded to whole 128-lane tiles. Under
     ``"vmem"`` the plane is 2-D with its rows padded to a multiple of 8
     (the copy-out DMA moves whole (8, 128) tiles), and the kernel may
-    take that plane plus ``VMEM_MARGIN`` of VMEM. Under the HBM modes
+    take that plane plus ``VMEM_MARGIN`` of VMEM. Under ``"dma"``
     each wire row is its own ``(1, words)`` slab of a 3-D plane, so
     every DMA the kernel makes slices only whole tiles. A row still
     costs one vreg row at any W <= 128, so the padding adds DMA bytes,
     not folds.
     """
-    if gather not in GATHER_MODES:
-        raise ValueError(f"unknown gather mode {gather!r} "
-                         f"(expected one of {GATHER_MODES})")
+    check_gather(gather)
     rows = _meta_layout(tile_rows, record_gather_cap(gather, gather_cap),
                         k)[-1]
     if meta.shape[1:] != (rows, LANES):

@@ -1,10 +1,9 @@
 """One launch-configuration surface for every kernel in ``repro.kernels``.
 
 Each kernel directory used to grow its own ad-hoc launch kwargs
-(``block_w=...``, ``interpret=...``, per-kernel VMEM assumptions),
-which meant ``benchmarks/kernels_bench``, ``repro.obs.kernelprof`` and
-any autotuner had to know six different call conventions. ``KernelSpec``
-is the single object they sweep instead:
+(``block_w=...``, ``interpret=...``, per-kernel VMEM assumptions), so
+every caller had to know six different call conventions. ``KernelSpec``
+is the single object they pass instead:
 
   * ``TileConfig`` — the geometry knobs: word/lane tile (``block_w``),
     row tile for blocked kernels (``block_rows``), slot tile for the
@@ -62,7 +61,7 @@ class TileConfig:
 
 @dataclasses.dataclass(frozen=True)
 class KernelSpec:
-    """A named, sweepable launch configuration for one kernel."""
+    """A named launch configuration for one kernel."""
 
     name: str = ""
     interpret: Optional[bool] = None     # None = auto (not on a TPU)
@@ -75,11 +74,6 @@ class KernelSpec:
         if self.interpret is not None:
             return self.interpret
         return default_interpret()
-
-    def with_tile(self, **kw) -> "KernelSpec":
-        """Copy with tile-geometry fields replaced (sweep helper)."""
-        return dataclasses.replace(
-            self, tile=dataclasses.replace(self.tile, **kw))
 
 
 DEFAULT_SPEC = KernelSpec()

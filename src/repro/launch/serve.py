@@ -7,7 +7,7 @@
   # mapped netlist executed on-device via the kernels/lut_eval kernel
   PYTHONPATH=src python -m repro.launch.serve --mode logic --sched \
       --replicas 2 --loadgen open --qps 20000 --backend bitplane \
-      --engine pallas
+      --engine pallas-streamed
 
   # continuous-batching LM decode on a smoke config
   PYTHONPATH=src python -m repro.launch.serve --mode lm --arch glm4-9b \
@@ -226,13 +226,11 @@ def main(argv=None):
     ap.add_argument("--backend", choices=["gather", "pallas", "bitplane"],
                     default="gather",
                     help="logic inference path (bitplane = mapped netlist)")
-    from repro.synth.executors import names as engine_names
-    ap.add_argument("--engine", choices=list(engine_names()),
-                    default="numpy",
-                    help="bitplane netlist executor from the "
-                         "repro.synth.executors registry (host fold, "
-                         "monolithic kernels/lut_eval, or the streamed/"
-                         "tiled pallas-streamed pipeline)")
+    from repro.synth import ENGINES
+    ap.add_argument("--engine", choices=ENGINES, default="numpy",
+                    help="bitplane netlist executor: the host fold "
+                         "(numpy) or the kernels/lut_eval device engine "
+                         "(pallas-streamed)")
     ap.add_argument("--sched", action="store_true",
                     help="serve through the repro.serve micro-batch "
                          "scheduler instead of the blocking loop")

@@ -19,11 +19,6 @@ NullaNet Tiny's whole pitch is latency, so latency has to be visible
                ``ServeMetrics``, ``ReplicaSet`` and
                ``BitplaneAggregator`` publish into, with a single
                ``snapshot()`` surface;
-  kernelprof — per-level ``lut_eval`` device timing fitted into a
-               measured ``(level_width, k, fanin) -> µs`` table, written
-               as an artifact so ``least_slack`` dispatch and mapping
-               search consume calibrated estimates instead of
-               cold-start EWMA;
   analyze    — trace artifacts back into per-request phase breakdowns
                ("where did the time go"), reconciliation against the
                scheduler-stamped latency, and trace-vs-trace diffing
@@ -47,9 +42,6 @@ from .trace import (FLUSH_REASONS, NULL_TRACER, NullTracer, SpanTracer,
 from .export import (load_trace_events, to_chrome_trace, to_jsonl,
                      write_chrome_trace, write_jsonl)
 from .registry import Counter, Gauge, MetricsRegistry
-from .kernelprof import (EmptyLatencyTable, LatencyTable,
-                         LatencyTableError, measure_level_grid,
-                         profile_plan, build_latency_table)
 from .analyze import TraceReport, analyze_events, analyze_trace
 from .window import BucketRing, WindowedMetrics
 from .slo import BurnAlert, BurnRateMonitor
@@ -61,8 +53,6 @@ __all__ = [
     "load_trace_events", "to_chrome_trace", "to_jsonl",
     "write_chrome_trace", "write_jsonl",
     "Counter", "Gauge", "MetricsRegistry",
-    "EmptyLatencyTable", "LatencyTable", "LatencyTableError",
-    "measure_level_grid", "profile_plan", "build_latency_table",
     "TraceReport", "analyze_events", "analyze_trace",
     "BucketRing", "WindowedMetrics",
     "BurnAlert", "BurnRateMonitor",

@@ -12,11 +12,11 @@ back out of the output planes, bit-identical to ``classify`` on the
 gather and Pallas paths.
 
 The netlist executor is whatever engine the ``BitplaneNetwork`` was
-built with (``repro.synth.executors`` registry): under the device
-engines (``"pallas"``, ``"pallas-streamed"``) the packed words are
-handed straight to the kernel and only the scattered argmax labels come
-back — pack → all levels → complement → argmax is one fused jit, so
-between enqueue and verdict nothing touches the host. The numpy engine
+built with (``repro.synth.ENGINES``): under the device engine
+(``"pallas-streamed"``) the packed words are handed straight to the
+kernel and only the scattered argmax labels come back — pack → all
+levels → complement → argmax is one fused jit, so between enqueue and
+verdict nothing touches the host. The numpy engine
 keeps the host fold (``execute_packed``) + decode. Aggregation itself
 is engine-agnostic; ``classify_packed`` dispatches.
 """
@@ -104,7 +104,7 @@ class BitplaneAggregator:
                          "engine": self.bitnet.engine}
         with tr.span("aggregate_pack", cat="pack", args=pack_args):
             pi_words = self.pack_requests(x)
-        # engine dispatch happens inside classify_packed: the pallas
+        # engine dispatch happens inside classify_packed: the device
         # engine ships the words to the device and returns only the
         # scattered per-request argmax; numpy is the host fold + decode.
         with tr.span("device_exec", cat="exec", args=exec_args):

@@ -65,9 +65,9 @@ class ReplicaSet:
         assert len(fns) >= 1
         self.replicas = [Replica(fn=f, rid=i) for i, f in enumerate(fns)]
         if exec_seed_us is not None:
-            # calibrated per-batch execution estimate (kernelprof
-            # LatencyTable) — least_slack starts informed instead of
-            # treating every replica as free until its first batch
+            # a known per-batch execution time: least_slack starts
+            # informed instead of treating every replica as free until
+            # its first batch
             for r in self.replicas:
                 r.ewma_us = float(exec_seed_us)
                 r.ewma_seeded = True
@@ -141,7 +141,7 @@ class ReplicaSet:
                     r.inflight -= 1
                     r.served += 1
                     # first real measurement replaces a cold 0.0 but
-                    # only blends into a calibrated kernelprof seed
+                    # only blends into a given seed
                     r.ewma_us = (dt if r.served == 1 and not r.ewma_seeded
                                  else 0.8 * r.ewma_us + 0.2 * dt)
                 return out
@@ -222,8 +222,8 @@ def build_logic_replicas(net, n_classes: int, n_replicas: int = 1,
     the ``repro.dist`` sharding rules on their way in instead.
     ``engine`` selects the bitplane backend's netlist executor (numpy
     fold or the ``kernels.lut_eval`` device pipeline). ``exec_seed_us``
-    seeds every replica's execution-time EWMA with a calibrated
-    kernelprof estimate.
+    seeds every replica's execution-time EWMA with a known per-batch
+    time.
     """
     import jax
 

@@ -266,10 +266,9 @@ class SchedConfig:
     # lane 1 in 1 ms. None (or a missing lane entry) = no deadline;
     # an explicit ``submit(..., deadline_us=...)`` always wins.
     lane_slo_us: Optional[Tuple[float, ...]] = None
-    # Calibrated batch-execution estimate (µs) seeding the flush-margin
-    # EWMA, e.g. ``LatencyTable.estimate_plan_us`` from
-    # ``repro.obs.kernelprof`` — without it the first deadline-margin
-    # flush decisions run on a cold 0 µs estimate.
+    # Known batch-execution time (µs) seeding the flush-margin EWMA —
+    # without it the first deadline-margin flush decisions run on a
+    # cold 0 µs estimate.
     exec_estimate_us: Optional[float] = None
 
     def slo_for_lane(self, lane: int) -> float:
@@ -344,8 +343,8 @@ class MicroBatchScheduler:
         self._hooked = False        # process hooks attached by start()
         self._stopping = False
         self._shutdown = False
-        # smoothed batch execution time; a calibrated kernelprof
-        # estimate seeds it so the first flush margins aren't cold
+        # smoothed batch execution time; a given estimate seeds it so
+        # the first flush margins aren't cold
         self._exec_ewma_us = float(self.cfg.exec_estimate_us or 0.0)
         self._ewma_seeded = self.cfg.exec_estimate_us is not None
         self._n_execs = 0

@@ -47,13 +47,12 @@ class LogicEngine:
         ``NetlistNotFound``; it never synthesizes).
     Argmax outputs are identical across backends.
 
-    For the bitplane backend, ``engine`` names a netlist executor in
-    the ``repro.synth.executors`` registry: ``"numpy"`` folds levels on
-    the host; ``"pallas"`` runs the whole levelized netlist through the
-    monolithic ``kernels.lut_eval`` device pipeline;
-    ``"pallas-streamed"`` through the streamed/tiled kernel (pack →
-    levels → complement → argmax in one jit either way). Custom engines
-    registered via ``executors.register`` work here unchanged.
+    For the bitplane backends, ``engine`` is one of
+    ``repro.synth.ENGINES``: ``"numpy"`` folds levels on the host;
+    ``"pallas-streamed"`` runs the netlist through the
+    ``kernels.lut_eval`` device kernel (pack → levels → complement →
+    argmax in one jit). Any other name raises ``UnknownEngineError``
+    before synthesis or the netlist load.
 
     ``device`` (a ``jax.Device``) pins this engine's arrays and jitted
     calls to one device, so replicas can each own a chip; ``None`` uses

@@ -11,14 +11,15 @@ The offline replacement for the Vivado step of NullaNet Tiny's flow:
                                                kernels.aig_sim)
 
 ``compile_logic_network(net)`` is the one-call pipeline from a compiled
-``LogicNetwork`` to its executable mapped netlist.
+``LogicNetwork`` to its executable mapped netlist, a ``BitplaneNetwork``
+that runs on one of ``ENGINES``: the numpy host fold (the reference) or
+the device engine, ``"pallas-streamed"``.
 """
 from .aig import AIG, CONST0, CONST1, lit, lit_compl, lit_not, lit_var
 from .cuts import Cut, enumerate_cuts
-from . import executors
-from .executor import (BitplaneNetwork, DevicePlan, TilePlan,
-                       compile_device_plan, compile_tile_plan,
-                       emit_verilog, execute_packed, execute_packed_pallas,
+from .executor import (ENGINES, BitplaneNetwork, DevicePlan, TilePlan,
+                       UnknownEngineError, compile_device_plan,
+                       compile_tile_plan, emit_verilog, execute_packed,
                        execute_packed_streamed)
 from .from_sop import cover_to_aig, layer_to_aig, network_to_aig, table_to_aig
 from .lutmap import MappedLUT, MappedNetwork, map_aig
@@ -57,14 +58,12 @@ def compile_logic_network(net, effort: int = 1, k: int = 6,
                           device=None) -> BitplaneNetwork:
     """LogicNetwork -> optimized mapped netlist, ready to execute.
 
-    ``engine`` names an executor in the ``repro.synth.executors``
-    registry: ``"pallas"`` runs the netlist through the fused
-    ``kernels.lut_eval`` device pipeline instead of the host fold, and
-    ``"pallas-streamed"`` through the streamed/tiled kernel (fastest,
-    and the only engine whose wire plane may exceed VMEM).
-    ``verify=True`` additionally runs the ``repro.check`` lint +
-    equivalence passes over every synthesis stage (CheckFailure on the
-    first counterexample). ``device`` pins the device engines to one
+    ``engine`` is one of ``ENGINES``: ``"pallas-streamed"`` runs the
+    netlist through the fused ``kernels.lut_eval`` device pipeline
+    instead of the host fold (``"numpy"``). ``verify=True``
+    additionally runs the ``repro.check`` lint + equivalence passes
+    over every synthesis stage (CheckFailure on the first
+    counterexample). ``device`` pins the device engine to one
     ``jax.Device``."""
     return BitplaneNetwork.from_logic_network(net, effort=effort, k=k,
                                               engine=engine,

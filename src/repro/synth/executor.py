@@ -8,26 +8,21 @@ over its six input planes (6 select steps, each one AND/ANDN/OR over the
 whole level). Per 32 samples, a LUT costs ~18 word ops regardless of
 batch size — the TPU/CPU analogue of the FPGA's spatial LUT fabric.
 
-Execution engines are pluggable: ``BitplaneNetwork(engine=...)`` looks
-the name up in the ``repro.synth.executors`` registry (unknown names
-raise ``UnknownEngineError`` listing what is registered; third-party
-engines join via ``executors.register``). Built-ins:
+``BitplaneNetwork(engine=...)`` takes one of two engines (``ENGINES``;
+any other name raises ``UnknownEngineError``):
 
   * ``engine="numpy"``          — the host fold below
-    (``execute_packed``), level-by-level vectorized bitwise ops;
-  * ``engine="pallas"``         — ``compile_device_plan`` stacks the
-    levelized netlist into device-resident plan tensors and the
-    monolithic ``repro.kernels.lut_eval`` kernel evaluates every level
-    with the whole wire plane resident in VMEM;
-  * ``engine="pallas-streamed"`` — ``compile_tile_plan`` renumbers the
-    wire plane level-major and tiles the slot walk; the streamed kernel
-    keeps the plane in HBM, double-buffers the per-tile plan tensors
-    HBM→VMEM, and folds a whole tile of LUTs per step — faster than
-    both of the above and the only engine whose netlists may exceed
-    VMEM.
+    (``execute_packed``), level-by-level vectorized bitwise ops: the
+    reference;
+  * ``engine="pallas-streamed"`` — the device engine:
+    ``compile_tile_plan`` renumbers the wire plane level-major and
+    tiles the slot walk, and the ``repro.kernels.lut_eval`` kernel
+    folds a whole tile of LUTs per step, with the plane in VMEM where
+    it fits and in HBM where it does not (``gather`` ``"vmem"`` or
+    ``"dma"``, from the plan's size).
 
-All engines are bit-identical on every reachable input; the device
-engines fuse bitplane pack, all levels, the output complement and the
+Both are bit-identical on every reachable input; the device engine
+fuses bitplane pack, all levels, the output complement and the
 per-request argmax into one jit, so nothing touches the host between
 enqueue and verdict.
 
@@ -48,9 +43,7 @@ from .aig import lit_compl, lit_var, tt_expand
 from .lutmap import MappedNetwork
 from .simulate import WORD_BITS, pack_bits, unpack_bits
 
-# Back-compat alias: the authoritative list is the executors registry
-# (``repro.synth.executors.names()``), which third parties can extend.
-ENGINES = ("numpy", "pallas", "pallas-streamed")
+ENGINES = ("numpy", "pallas-streamed")
 
 # wire numbering for execution/emission:
 #   wire 0            = constant 0
@@ -264,7 +257,7 @@ def staged_rows(tp: TilePlan) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Device plan: level-stacked, width-padded tensors for the lut_eval kernel
+# Device plan: level-stacked, width-padded tensors (what repro.check reads)
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
@@ -277,10 +270,9 @@ class DevicePlan:
     ``n_wires`` — one past the last real wire — so the kernel's slot
     walk needs no per-slot validity branch.
 
-    ``tiles`` (attached by ``compile_device_plan(..., tile_rows=...)``)
-    is the same netlist as a streamed tile schedule (``TilePlan``) for
-    the tiled kernel; it is derived data and deliberately excluded from
-    ``repro.check.plan_check.plan_fingerprint``.
+    ``tiles`` is the same netlist as the tile schedule the device
+    engine runs (``TilePlan``); it is derived data and deliberately
+    excluded from ``repro.check.plan_check.plan_fingerprint``.
     """
 
     leaf_idx: np.ndarray     # (n_levels, Lw, k) int32 wire indices
@@ -291,7 +283,7 @@ class DevicePlan:
     n_pis: int
     n_wires: int             # 1 + n_pis + n_luts (dump row index)
     k: int
-    tiles: Optional[TilePlan] = None
+    tiles: TilePlan
 
     @property
     def n_levels(self) -> int:
@@ -305,15 +297,13 @@ class DevicePlan:
 def compile_device_plan(mapped: MappedNetwork,
                         plan: Optional[_Plan] = None,
                         verify: bool = False,
-                        tile_rows: Optional[int] = None) -> DevicePlan:
+                        tile_rows: int = _DEFAULT_TILE_ROWS) -> DevicePlan:
     """Stack the per-level arrays of ``_compile_plan`` into uniform-width
-    tensors ready to ship to the device.
-
-    ``tile_rows`` additionally attaches the streamed tile schedule
-    (``DevicePlan.tiles``) with that slot-tile size. ``verify=True``
-    runs ``repro.check``'s plan validator plus a mapped<->plan miter on
-    the result and raises ``CheckFailure`` with the first counterexample
-    on any disagreement."""
+    tensors, and attach the tile schedule at ``tile_rows`` slots a tile
+    (``DevicePlan.tiles``). ``verify=True`` runs ``repro.check``'s plan
+    validator plus a mapped<->plan miter on the result and raises
+    ``CheckFailure`` with the first counterexample on any
+    disagreement."""
     if plan is None:
         plan = _compile_plan(mapped)
     k = mapped.k
@@ -330,32 +320,12 @@ def compile_device_plan(mapped: MappedNetwork,
         out_wires[i, :n] = la.out_wires
     dplan = DevicePlan(leaf_idx, tt_bits, out_wires,
                        plan.out_idx.copy(), plan.out_neg.copy(),
-                       mapped.n_pis, n_wires, k)
-    if tile_rows is not None:
-        dplan.tiles = compile_tile_plan(plan, mapped.n_pis, k, tile_rows)
+                       mapped.n_pis, n_wires, k,
+                       compile_tile_plan(plan, mapped.n_pis, k, tile_rows))
     if verify:
         from repro.check.pipeline import verify_plan
         verify_plan(mapped, dplan, formal=(verify == "formal"))
     return dplan
-
-
-def execute_packed_pallas(mapped: MappedNetwork, pi_words: np.ndarray,
-                          dplan: Optional[DevicePlan] = None,
-                          interpret: Optional[bool] = None) -> np.ndarray:
-    """``execute_packed`` through the lut_eval kernel: pi_words
-    (n_pis, W) uint32 -> output words (n_outputs, W) uint32."""
-    from repro.kernels.lut_eval import lut_eval
-
-    pi_words = np.asarray(pi_words, np.uint32)
-    assert pi_words.shape[0] == mapped.n_pis
-    if dplan is None:
-        dplan = compile_device_plan(mapped)
-    plane = lut_eval(pi_words, dplan.leaf_idx, dplan.tt_bits,
-                     dplan.out_wires, n_pis=dplan.n_pis,
-                     n_wires=dplan.n_wires, interpret=interpret)
-    out = plane[dplan.out_idx]
-    out[dplan.out_neg] = ~out[dplan.out_neg]
-    return out
 
 
 def execute_packed_streamed(mapped: MappedNetwork, pi_words: np.ndarray,
@@ -380,14 +350,12 @@ def execute_packed_streamed(mapped: MappedNetwork, pi_words: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Executors (the engine implementations behind repro.synth.executors)
+# Executors (the engines behind ``BitplaneNetwork(engine=...)``)
 # ---------------------------------------------------------------------------
 
 class _NumpyExecutor:
     """Host-fold engine: ``execute_packed`` level by level, then the
     bitplane decode — no jax anywhere on the path."""
-
-    name = "numpy"
 
     def __init__(self, bitnet: "BitplaneNetwork",
                  interpret: Optional[bool] = None, spec=None):
@@ -428,31 +396,34 @@ class _NumpyExecutor:
 
 
 class _DeviceExecutor:
-    """Shared machinery of the fused on-device engines.
+    """The device engine (``"pallas-streamed"``) over a ``TilePlan``.
 
     Every public entry point is one jit: bitplane pack (32 samples per
-    int32 lane), the netlist kernel (subclass ``_eval_words``), the
-    output complement, code decode, and — for the classify paths — the
-    ``out_levels`` gather and per-request argmax. Distinct batch shapes
-    retrace; serving callers pin the shape (``pad_rows``) so the hot
-    path compiles once.
+    int32 lane), the streamed ``lut_eval`` kernel (double-buffered
+    per-tile records, whole-tile folds, the wire plane in VMEM where it
+    fits the core's budget, else in HBM: ``gather`` is ``"vmem"`` or
+    ``"dma"``, from the plan's size, see
+    ``repro.check.plan_check.gather_mode``), the output complement,
+    code decode, and — for the classify paths — the ``out_levels``
+    gather and per-request argmax. Distinct batch shapes retrace;
+    serving callers pin the shape (``pad_rows``) so the hot path
+    compiles once.
 
     Plan tensors and every input are committed to the network's
     ``device`` when it has one, so each jit runs there (one replica per
     chip); with ``device=None`` they land on JAX's default device.
     """
 
-    name = "device"
-
     def __init__(self, bitnet: "BitplaneNetwork",
                  interpret: Optional[bool] = None, spec=None):
         import jax
         import jax.numpy as jnp
+        from repro.kernels.lut_eval.lut_eval import (default_gather,
+                                                     pack_tile_meta)
         from repro.kernels.spec import DEFAULT_SPEC
         from repro.obs.trace import NULL_TRACER
 
         self.tracer = NULL_TRACER       # set through BitplaneNetwork.tracer
-        self.fetch_args = None          # plan counts on each fetch span
         self._jnp = jnp
         self.spec = DEFAULT_SPEC if spec is None else spec
         self.interpret = self.spec.resolve_interpret(interpret)
@@ -465,6 +436,20 @@ class _DeviceExecutor:
                                      static_argnames=("n_classes",))
         self._argmax_words = jax.jit(self._argmax_from_words,
                                      static_argnames=("n_classes",))
+        tp = compile_tile_plan(bitnet._plan, bitnet.mapped.n_pis,
+                               bitnet.mapped.k, self.spec.tile.tile_rows)
+        self.tp = tp
+        self.gather = default_gather(tp, self.interpret,
+                                     self.spec.tile.block_w)
+        self._meta = self._put(pack_tile_meta(tp, self.gather))
+        self._out_idx = self._put(tp.out_idx.astype(np.int32))
+        self._neg = self._put(np.where(tp.out_neg, -1, 0).astype(np.int32))
+        # plan counts, one dict for every fetch span of this engine
+        self.fetch_args = {"luts": bitnet.mapped.n_luts,
+                           "tiles": tp.n_tiles,
+                           "gather": self.gather,
+                           "staged_rows": (staged_rows(tp)
+                                           if self.gather == "dma" else 0)}
 
     def _put(self, a: np.ndarray):
         """Host array -> a jax array on this executor's device."""
@@ -475,7 +460,21 @@ class _DeviceExecutor:
 
     def _eval_words(self, words):
         """(n_pis, W) int32 -> complemented output words (n_outputs, W)."""
-        raise NotImplementedError
+        from repro.kernels.lut_eval.lut_eval import lut_eval_streamed_pallas
+        jnp = self._jnp
+        tp = self.tp
+        w = words.shape[1]
+        if tp.n_tiles == 0 or tp.n_pis == 0:     # constant network
+            plane = jnp.zeros((tp.n_rows, w), jnp.int32)
+            plane = plane.at[1: tp.n_pis + 1].set(words)
+        else:
+            plane = lut_eval_streamed_pallas(
+                words, self._meta, n_pis=tp.n_pis, n_tiles=tp.n_tiles,
+                tile_rows=tp.tile_rows, gather_cap=tp.gather_cap,
+                n_rows=tp.n_rows, k=tp.k,
+                block_w=self.spec.tile.clamp_block_w(w),
+                gather=self.gather, interpret=self.interpret)
+        return plane[self._out_idx] ^ self._neg[:, None]
 
     def _pack(self, codes):
         """(B, n_inputs) int32 codes -> (n_pi_wires, ceil(B/32)) int32
@@ -561,106 +560,28 @@ class _DeviceExecutor:
         return self.classify_words(pi_words, n_rows, n_classes)
 
 
-class _PallasExecutor(_DeviceExecutor):
-    """The monolithic on-device pipeline over a ``DevicePlan`` (whole
-    wire plane resident in VMEM, one LUT slot per kernel step)."""
-
-    name = "pallas"
-
-    def __init__(self, bitnet: "BitplaneNetwork",
-                 interpret: Optional[bool] = None, spec=None):
-        super().__init__(bitnet, interpret=interpret, spec=spec)
-        dp = compile_device_plan(bitnet.mapped, bitnet._plan)
-        self.dp = dp
-        self.n_slots = dp.n_levels * dp.level_width
-        self._leaf = self._put(dp.leaf_idx.reshape(-1, dp.k))
-        self._tt = self._put(np.ascontiguousarray(
-            dp.tt_bits.reshape(-1, 1 << dp.k)).view(np.int32))
-        self._ow = self._put(dp.out_wires.reshape(-1).astype(np.int32))
-        self._out_idx = self._put(dp.out_idx.astype(np.int32))
-        self._neg = self._put(np.where(dp.out_neg, -1, 0).astype(np.int32))
-
-    def _eval_words(self, words):
-        from repro.kernels.lut_eval.lut_eval import lut_eval_pallas
-        jnp = self._jnp
-        dp = self.dp
-        w = words.shape[1]
-        bw = self.spec.tile.clamp_block_w(w)
-        pad = (-w) % bw
-        if pad:
-            words = jnp.pad(words, ((0, 0), (0, pad)))
-        if self.n_slots == 0:        # constant network: PIs + const only
-            plane = jnp.zeros((dp.n_wires + 1, words.shape[1]), jnp.int32)
-            plane = plane.at[1: dp.n_pis + 1].set(words)
-        else:
-            plane = lut_eval_pallas(
-                words, self._leaf, self._tt, self._ow, n_pis=dp.n_pis,
-                n_slots=self.n_slots, n_wires=dp.n_wires, k=dp.k,
-                block_w=bw, interpret=self.interpret)
-        return (plane[self._out_idx] ^ self._neg[:, None])[:, :w]
+_EXECUTORS = {"numpy": _NumpyExecutor, "pallas-streamed": _DeviceExecutor}
 
 
-class _StreamedExecutor(_DeviceExecutor):
-    """The streamed/tiled on-device pipeline over a ``TilePlan``:
-    double-buffered plan-tensor DMA, whole-tile folds, and the wire
-    plane in VMEM where it fits the core's budget, else in HBM
-    (``gather`` is ``"vmem"`` or ``"dma"``, from the plan's size: see
-    ``repro.check.plan_check.gather_mode``).
+class UnknownEngineError(KeyError):
+    """An ``engine=`` name that is not one of ``ENGINES``."""
 
-    Tile geometry comes from, in priority order: an explicit ``spec``,
-    the persisted autotune cache (keyed by the plan's sha1 fingerprint,
-    see ``repro.kernels.lut_eval.autotune``), or the spec defaults.
-    """
+    def __init__(self, name: str):
+        self.name = name
+        super().__init__(f"unknown bitplane engine {name!r} (engines: "
+                         f"{', '.join(ENGINES)})")
 
-    name = "pallas-streamed"
+    def __str__(self) -> str:   # KeyError str() would quote the message
+        return self.args[0]
 
-    def __init__(self, bitnet: "BitplaneNetwork",
-                 interpret: Optional[bool] = None, spec=None,
-                 gather: Optional[str] = None, use_cache: bool = True):
-        super().__init__(bitnet, interpret=interpret, spec=spec)
-        from repro.kernels.lut_eval.lut_eval import (default_gather,
-                                                     pack_tile_meta)
-        dp = compile_device_plan(bitnet.mapped, bitnet._plan)
-        if use_cache and spec is None:
-            from repro.kernels.lut_eval import autotune
-            tuned = autotune.cached_tile(dp, interpret=self.interpret)
-            if tuned is not None:
-                self.spec = self.spec.with_tile(tile_rows=tuned[0],
-                                                block_w=tuned[1])
-        tp = compile_tile_plan(bitnet._plan, dp.n_pis, dp.k,
-                               self.spec.tile.tile_rows)
-        dp.tiles = tp
-        self.dp = dp
-        self.tp = tp
-        self.gather = (default_gather(tp, self.interpret,
-                                      self.spec.tile.block_w)
-                       if gather is None else gather)
-        self._meta = self._put(pack_tile_meta(tp, self.gather))
-        self._out_idx = self._put(tp.out_idx.astype(np.int32))
-        self._neg = self._put(np.where(tp.out_neg, -1, 0).astype(np.int32))
-        # one dict for every fetch span of this engine
-        self.fetch_args = {"luts": bitnet.mapped.n_luts,
-                           "tiles": tp.n_tiles,
-                           "gather": self.gather,
-                           "staged_rows": (staged_rows(tp)
-                                           if self.gather == "dma" else 0)}
 
-    def _eval_words(self, words):
-        from repro.kernels.lut_eval.lut_eval import lut_eval_streamed_pallas
-        jnp = self._jnp
-        tp = self.tp
-        w = words.shape[1]
-        if tp.n_tiles == 0 or tp.n_pis == 0:     # constant network
-            plane = jnp.zeros((tp.n_rows, w), jnp.int32)
-            plane = plane.at[1: tp.n_pis + 1].set(words)
-        else:
-            plane = lut_eval_streamed_pallas(
-                words, self._meta, n_pis=tp.n_pis, n_tiles=tp.n_tiles,
-                tile_rows=tp.tile_rows, gather_cap=tp.gather_cap,
-                n_rows=tp.n_rows, k=tp.k,
-                block_w=self.spec.tile.clamp_block_w(w),
-                gather=self.gather, interpret=self.interpret)
-        return plane[self._out_idx] ^ self._neg[:, None]
+def _executor_class(engine: str):
+    """The executor class of an engine name; ``UnknownEngineError`` for
+    any name not in ``ENGINES``."""
+    try:
+        return _EXECUTORS[engine]
+    except KeyError:
+        raise UnknownEngineError(engine) from None
 
 
 # ---------------------------------------------------------------------------
@@ -675,22 +596,19 @@ class BitplaneNetwork:
     the netlist synthesized ahead of time; ``__call__`` matches
     ``LogicNetwork.__call__`` bit-exactly on every reachable input.
 
-    ``engine`` names an executor in the ``repro.synth.executors``
-    registry (built-ins: ``"numpy"``, ``"pallas"``,
-    ``"pallas-streamed"`` — see the module docstring; register your own
-    with ``executors.register``). Unknown names raise
-    ``UnknownEngineError`` listing the registered engines. All engines
-    are bit-identical on every reachable input.
+    ``engine`` is one of ``ENGINES``: ``"numpy"`` (the host fold) or
+    ``"pallas-streamed"`` (the device engine); see the module
+    docstring. Any other name raises ``UnknownEngineError``. Both
+    engines are bit-identical on every reachable input.
 
-    ``device`` (a ``jax.Device``) pins the device engines' plan tensors
+    ``device`` (a ``jax.Device``) pins the device engine's plan tensors
     and jitted calls to that device; ``None`` leaves them on JAX's
     default device. Input quantization runs on the host.
     """
 
     def __init__(self, net, mapped: MappedNetwork, engine: str = "numpy",
                  interpret: Optional[bool] = None, spec=None, device=None):
-        from .executors import get as _get_engine
-        self._factory = _get_engine(engine)    # typed error on bad name
+        self._factory = _executor_class(engine)   # typed error on bad name
         self.net = net
         self.mapped = mapped
         self.engine = engine
@@ -717,9 +635,8 @@ class BitplaneNetwork:
                            device=None) -> "BitplaneNetwork":
         """Synthesize ``net`` and wrap the mapped netlist."""
         from . import synthesize        # lazy: package init imports us
-        from .executors import get as _get_engine
         from .from_sop import network_to_aig
-        _get_engine(engine)             # a bad name fails before synthesis
+        _executor_class(engine)         # a bad name fails before synthesis
         bn = cls(net, synthesize(network_to_aig(net), effort=effort, k=k,
                                  verify=verify),
                  engine=engine, interpret=interpret, device=device)
@@ -738,8 +655,7 @@ class BitplaneNetwork:
         (``repro.synth.store``); never synthesizes: a store miss raises
         ``NetlistNotFound``."""
         from . import store
-        from .executors import get as _get_engine
-        _get_engine(engine)             # a bad name fails before the load
+        _executor_class(engine)         # a bad name fails before the load
         return cls(net, store.load(net, effort, k), engine=engine,
                    interpret=interpret, device=device)
 
@@ -788,8 +704,8 @@ class BitplaneNetwork:
                         n_classes: int) -> np.ndarray:
         """Packed PI bitplanes -> per-lane argmax labels, (n_rows,) int32.
 
-        The serve-aggregation entry point: on device engines the words
-        go straight to the kernel and only the scattered argmax
+        The serve-aggregation entry point: on the device engine the
+        words go straight to the kernel and only the scattered argmax
         returns; on numpy it is the host fold + decode."""
         return self.executor.classify_packed(pi_words, n_rows, n_classes)
 

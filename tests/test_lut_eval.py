@@ -9,11 +9,10 @@ import numpy as np
 import pytest
 from hyp_compat import given, settings, st
 
-from repro.kernels.lut_eval import (lut_eval, lut_eval_gather_ref,
-                                    lut_eval_ref)
+from repro.kernels.lut_eval import lut_eval_gather_ref, lut_eval_ref
 from repro.synth import (AIG, compile_device_plan, execute_packed,
-                         execute_packed_pallas, input_patterns, random_words,
-                         synthesize, unpack_bits)
+                         input_patterns, random_words, synthesize,
+                         unpack_bits)
 from repro.synth.executor import _compile_plan
 from repro.synth.from_sop import table_to_aig
 
@@ -26,18 +25,6 @@ def _random_mapped(seed: int, n_vars: int, n_outs: int, density=0.5):
                      [2 * (i + 1) for i in range(n_vars)])
         for _ in range(n_outs)]
     return synthesize(aig)
-
-
-def test_pallas_matches_numpy_fold_ragged():
-    """Ragged word counts (not a multiple of the kernel block) pad
-    transparently and match the host fold bit-exactly."""
-    mapped = _random_mapped(0, 9, 3)
-    assert mapped.n_luts > 1
-    for n_words in (1, 7, 130):
-        words = random_words(mapped.n_pis, n_words, seed=n_words)
-        np.testing.assert_array_equal(
-            execute_packed(mapped, words),
-            execute_packed_pallas(mapped, words))
 
 
 def test_device_plan_shape_and_padding():
@@ -86,54 +73,22 @@ def test_scan_and_gather_oracles_match():
     np.testing.assert_array_equal(gout, unpack_bits(want, n_samples))
 
 
-def test_trivial_constant_network():
-    """A constant function maps to zero LUTs; the wrapper's no-slot path
-    still produces the complemented constant plane."""
-    aig = AIG(3)
-    aig.outputs = [1]           # const-1 literal
-    mapped = synthesize(aig)
-    assert mapped.n_luts == 0
-    words = random_words(3, 4, seed=0)
-    np.testing.assert_array_equal(
-        execute_packed(mapped, words),
-        execute_packed_pallas(mapped, words))
-
-
-@settings(max_examples=15, deadline=None)
-@given(n=st.integers(1, 5), n_outs=st.integers(1, 3), data=st.data())
-def test_lut_eval_exhaustive_property(n, n_outs, data):
-    """Random mapped netlists agree with the host fold on every input
-    pattern through the Pallas kernel (exhaustive packed simulation)."""
-    aig = AIG(n)
-    aig.outputs = [
-        table_to_aig(
-            aig,
-            np.array([bool((tt >> r) & 1) for r in range(1 << n)]),
-            None, [2 * (i + 1) for i in range(n)])
-        for tt in (data.draw(st.integers(0, (1 << (1 << n)) - 1))
-                   for _ in range(n_outs))]
-    mapped = synthesize(aig)
-    pats = input_patterns(n)
-    np.testing.assert_array_equal(
-        execute_packed(mapped, pats),
-        execute_packed_pallas(mapped, pats))
-
-
 # ---------------------------------------------------------------------------
-# Streamed/tiled kernel (TilePlan route) and the executor-engine registry
+# Streamed/tiled kernel (TilePlan route) and the engine names
 # ---------------------------------------------------------------------------
 
-def test_streamed_matches_numpy_fold_ragged():
+@pytest.mark.parametrize("gather", ["dma", "vmem"])
+@pytest.mark.parametrize("n_words", [1, 7, 130])
+def test_streamed_matches_numpy_fold_ragged(n_words, gather):
     """Both gather modes of the streamed kernel match the host fold
-    bit-exactly on ragged word counts."""
+    bit-exactly on ragged word counts, 130 taking two 128-lane
+    blocks."""
     from repro.synth import execute_packed_streamed
     mapped = _random_mapped(0, 9, 3)
-    for n_words in (1, 7, 130):
-        words = random_words(mapped.n_pis, n_words, seed=n_words)
-        want = execute_packed(mapped, words)
-        for gather in ("fancy", "dma"):
-            np.testing.assert_array_equal(
-                want, execute_packed_streamed(mapped, words, gather=gather))
+    words = random_words(mapped.n_pis, n_words, seed=n_words)
+    np.testing.assert_array_equal(
+        execute_packed(mapped, words),
+        execute_packed_streamed(mapped, words, gather=gather))
 
 
 def test_streamed_constant_network():
@@ -148,7 +103,8 @@ def test_streamed_constant_network():
         execute_packed_streamed(mapped, words))
 
 
-def test_streamed_multi_tile_levels():
+@pytest.mark.parametrize("gather", ["dma", "vmem"])
+def test_streamed_multi_tile_levels(gather):
     """tile_rows smaller than every level forces multi-tile bands (and
     gather reuse across tiles); results stay bit-identical."""
     from repro.synth import compile_tile_plan, execute_packed_streamed
@@ -158,11 +114,9 @@ def test_streamed_multi_tile_levels():
     tp = compile_tile_plan(plan, mapped.n_pis, mapped.k, tile_rows=8)
     assert tp.n_tiles > len(plan.levels)     # levels actually split
     words = random_words(mapped.n_pis, 9, seed=2)
-    want = execute_packed(mapped, words)
-    for gather in ("fancy", "dma"):
-        np.testing.assert_array_equal(
-            want, execute_packed_streamed(mapped, words, tplan=tp,
-                                          gather=gather))
+    np.testing.assert_array_equal(
+        execute_packed(mapped, words),
+        execute_packed_streamed(mapped, words, tplan=tp, gather=gather))
 
 
 def test_pack_tile_meta_round_trips_the_tile_plan():
@@ -223,11 +177,11 @@ def test_tile_plan_structure():
     assert len(np.unique(rows)) == rows.shape[0]
 
 
+@pytest.mark.parametrize("gather", ["dma", "vmem"])
 @settings(max_examples=10, deadline=None)
 @given(n=st.integers(1, 5), n_outs=st.integers(1, 3),
-       tile_rows=st.sampled_from([1, 2, 8, 32]),
-       gather=st.sampled_from(["fancy", "dma", "vmem"]), data=st.data())
-def test_streamed_exhaustive_property(n, n_outs, tile_rows, gather, data):
+       tile_rows=st.sampled_from([1, 2, 8, 32]), data=st.data())
+def test_streamed_exhaustive_property(gather, n, n_outs, tile_rows, data):
     """Random mapped netlists agree with the host fold on every input
     pattern through the streamed kernel, in every gather mode, at tile
     sizes from degenerate (1 slot/tile) to larger-than-any-level."""
@@ -306,13 +260,13 @@ def test_gather_mode_at_the_vmem_budget(block_w, lanes):
     assert gather_mode(at, cap - 1, block_w) == "dma"
 
 
-def _streamed_executor(mapped, **kw):
-    from repro.synth.executor import _compile_plan, _StreamedExecutor
+def _streamed_executor(mapped):
+    from repro.synth.executor import _compile_plan, _DeviceExecutor
     # the executor reads only these attributes of its BitplaneNetwork
     bitnet = types.SimpleNamespace(
         mapped=mapped, _plan=_compile_plan(mapped), in_bits=1, out_bits=1,
         out_levels=np.arange(2, dtype=np.float32), device=None)
-    return _StreamedExecutor(bitnet, interpret=True, use_cache=False, **kw)
+    return _DeviceExecutor(bitnet, interpret=True)
 
 
 def test_streamed_executor_gather_counters(monkeypatch):
@@ -335,42 +289,29 @@ def test_streamed_executor_gather_counters(monkeypatch):
     assert small.gather == "dma"
     assert small.fetch_args["gather"] == "dma"
     assert small.fetch_args["staged_rows"] == staged_rows(small.tp) > 0
-    # an explicit mode is kept as given
-    assert _streamed_executor(mapped, gather="fancy").fetch_args[
-        "staged_rows"] == 0
 
 
 def test_over_vmem_netlist_runs_streamed():
-    """A wire plane exceeding the monolithic kernel's VMEM budget fails
-    plan validation as before — but the streamed engine executes it
-    argmax-identically to the numpy fold (the whole point of tiling)."""
-    from repro.check import validate_device_plan
-    from repro.synth import (compile_device_plan, compile_tile_plan,
-                             execute_packed_streamed)
-    from repro.synth.executor import _compile_plan as cp
-    from repro.check import estimate_tile_vmem_bytes
-    from repro.check.plan_check import estimate_vmem_bytes
+    """A budget below the whole wire plane still passes plan validation,
+    which checks the tile step's working set, and the netlist runs with
+    the plane in HBM bit-identically to the numpy fold (the whole point
+    of tiling)."""
+    from repro.check import estimate_tile_vmem_bytes, validate_device_plan
+    from repro.check.plan_check import resident_plane_bytes
+    from repro.synth import compile_device_plan, execute_packed_streamed
     mapped = _random_mapped(6, 10, 16)
-    dp = compile_device_plan(mapped)
-    dp_t = compile_device_plan(mapped, tile_rows=8)
-    # a budget between the tiled working set and the whole-plane
-    # footprint: the monolithic plan is rejected at it
-    mono = estimate_vmem_bytes(dp)
-    tiled = estimate_tile_vmem_bytes(dp_t.tiles)
-    assert tiled < mono          # tiling shrinks the working set
-    budget = (mono + tiled) // 2
-    rep = validate_device_plan(dp, vmem_budget_bytes=budget,
+    dp = compile_device_plan(mapped, tile_rows=8)
+    plane = resident_plane_bytes(dp.tiles)
+    tiled = estimate_tile_vmem_bytes(dp.tiles)
+    assert tiled < plane          # tiling shrinks the working set
+    rep = validate_device_plan(dp, vmem_budget_bytes=(plane + tiled) // 2,
                                use_cache=False)
-    assert any(i.code == "vmem-budget" for i in rep.issues)
-    # the same netlist with a tile schedule passes the same budget...
-    rep_t = validate_device_plan(dp_t, vmem_budget_bytes=budget,
-                                 use_cache=False)
-    assert rep_t.ok, [str(i) for i in rep_t.issues]
-    # ...and executes bit-identically (hence argmax-identically)
+    assert rep.ok, [str(i) for i in rep.issues]
     words = random_words(mapped.n_pis, 33, seed=7)
     np.testing.assert_array_equal(
         execute_packed(mapped, words),
-        execute_packed_streamed(mapped, words, tplan=dp_t.tiles))
+        execute_packed_streamed(mapped, words, tplan=dp.tiles,
+                                gather="dma"))
 
 
 def test_plan_check_tile_budget_reject():
@@ -392,66 +333,74 @@ def test_plan_check_tile_budget_reject():
     assert any(i.code == "tile-gather" for i in rep2.issues)
 
 
-def test_executor_registry_typed_error_and_custom_engine():
-    from repro.synth import executors
-    from repro.synth.executor import BitplaneNetwork, _NumpyExecutor
+def _build_with_engine(how, engine):
+    """Build a network (or serving engine) of a small net on ``engine``
+    the way each public entry point does."""
+    from repro.core.logic_infer import LogicNetwork
+    from repro.core.quant import ActQuantSpec
+    from repro.core.truthtable import LayerTables
+    from repro.serving.engine import LogicEngine
+    from repro.synth import BitplaneNetwork, store
 
-    with np.testing.assert_raises(executors.UnknownEngineError):
-        executors.get("definitely-not-an-engine")
-    try:
-        executors.get("definitely-not-an-engine")
-    except executors.UnknownEngineError as e:
-        assert "numpy" in str(e) and "pallas-streamed" in str(e)
-        assert "pallas" in e.known
-    for builtin in ("numpy", "pallas", "pallas-streamed"):
-        assert builtin in executors.names()
+    spec = ActQuantSpec("signed", 2)
+    rng = np.random.default_rng(0)
+    idx = np.stack([rng.choice(6, 3, replace=False)
+                    for _ in range(5)]).astype(np.int32)
+    tab = rng.integers(0, spec.n_levels, (5, spec.n_levels ** 3))
+    net = LogicNetwork([LayerTables(idx, tab.astype(np.int8), spec, spec,
+                                    1.0, 0.9)], spec, 1.0, 6, 5)
+    if how == "BitplaneNetwork":
+        return BitplaneNetwork(net, _random_mapped(0, 3, 2), engine=engine)
+    if how == "from_logic_network":
+        return BitplaneNetwork.from_logic_network(net, engine=engine)
+    if how == "from_store":
+        return BitplaneNetwork.from_store(net, engine=engine)
+    return LogicEngine(net, 5, backend="bitplane", engine=engine)
 
 
-def test_autotune_cache_concurrent_writers(tmp_path, monkeypatch):
-    """Many threads recording tuned shapes into one cache file: the
-    mkstemp+replace write means the file is a valid JSON snapshot at
-    every instant and no entry is torn — a pid-suffixed temp name would
-    let two threads of this one process interleave."""
-    import json
-    import threading
+@pytest.mark.parametrize("how", ["BitplaneNetwork", "from_logic_network",
+                                 "from_store", "LogicEngine"])
+def test_unknown_engine_typed_error(how, monkeypatch):
+    """The engine names are ``ENGINES``; any other, the deleted
+    monolithic ``"pallas"`` engine among them, raises the typed error
+    naming both, before any synthesis or netlist load."""
+    import repro.synth as synth
+    from repro.synth import ENGINES, UnknownEngineError, store
 
-    from repro.kernels.lut_eval import autotune
+    def forbidden(*a, **kw):
+        raise AssertionError("synthesis or a netlist load was started")
+    monkeypatch.setattr(synth, "synthesize", forbidden)
+    monkeypatch.setattr(store, "load", forbidden)
+    assert ENGINES == ("numpy", "pallas-streamed")
+    for name in ("pallas", "definitely-not-an-engine"):
+        with pytest.raises(UnknownEngineError) as e:
+            _build_with_engine(how, name)
+        assert e.value.name == name
+        assert "numpy" in str(e.value) and "pallas-streamed" in str(e.value)
 
-    path = tmp_path / "tiles.json"
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(path))
-    stop = threading.Event()
-    torn = []
 
-    def reader():
-        while not stop.is_set():
-            if path.exists():
-                try:
-                    json.loads(path.read_text())
-                except ValueError as e:        # torn/partial write
-                    torn.append(e)
-
-    def writer(i):
-        for j in range(25):
-            autotune.record(f"fp{i}", "cpu", False,
-                            tile_rows=32, block_w=128, us=float(j))
-
-    r = threading.Thread(target=reader)
-    ws = [threading.Thread(target=writer, args=(i,)) for i in range(4)]
-    r.start()
-    for w in ws:
-        w.start()
-    for w in ws:
-        w.join()
-    stop.set()
-    r.join()
-    assert not torn
-    # every fingerprint landed (last-write-wins per key, no lost keys
-    # is NOT guaranteed across writers — but each writer's own final
-    # key must be readable)
-    final = json.loads(path.read_text())
-    assert final, "cache file empty after concurrent writes"
-    for key, ent in final.items():
-        assert ent["tile_rows"] == 32 and ent["block_w"] == 128
-    assert autotune.lookup(next(iter(final)).split(":")[0], "cpu",
-                           False) == (32, 128)
-    assert not list(tmp_path.glob("*.tmp")), "leaked temp files"
+@pytest.mark.parametrize("entry", ["lut_eval_streamed",
+                                   "lut_eval_streamed_pallas"])
+def test_unknown_gather_mode_raises(entry):
+    """Only ``dma`` and ``vmem`` exist: any other mode raises instead of
+    running one of them."""
+    from repro.kernels.lut_eval import lut_eval_streamed
+    from repro.kernels.lut_eval.lut_eval import (GATHER_MODES,
+                                                 lut_eval_streamed_pallas,
+                                                 pack_tile_meta)
+    from repro.synth import compile_tile_plan
+    from repro.synth.executor import _compile_plan as cp
+    assert GATHER_MODES == ("dma", "vmem")
+    mapped = _random_mapped(0, 9, 3)
+    tp = compile_tile_plan(cp(mapped), mapped.n_pis, mapped.k)
+    words = random_words(mapped.n_pis, 8, seed=0)
+    with pytest.raises(ValueError, match="'dma', 'vmem'"):
+        if entry == "lut_eval_streamed":
+            lut_eval_streamed(words, tp, gather="gather")
+        else:
+            lut_eval_streamed_pallas(
+                jnp.asarray(words.view(np.int32)),
+                jnp.asarray(pack_tile_meta(tp, "dma")), n_pis=tp.n_pis,
+                n_tiles=tp.n_tiles, tile_rows=tp.tile_rows,
+                gather_cap=tp.gather_cap, n_rows=tp.n_rows, k=tp.k,
+                gather="gather")

@@ -11,7 +11,7 @@ import pytest
 
 from repro.check.tracecheck import (check_trace, check_trace_file,
                                     synthetic_trace_events)
-from repro.obs import (FLUSH_REASONS, LatencyTable, MetricsRegistry,
+from repro.obs import (FLUSH_REASONS, MetricsRegistry,
                        NULL_TRACER, SpanTracer, TraceEvent,
                        load_trace_events, to_chrome_trace, to_jsonl,
                        write_chrome_trace, write_jsonl)
@@ -314,44 +314,7 @@ def test_tracecheck_file_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Kernel latency table (model only; device timing covered by benchmarks)
-# ---------------------------------------------------------------------------
-
-def _grid_table():
-    rows = [{"source": "grid", "level_width": w, "k": 6, "fanin": f,
-             "device_us": float(w * (1.0 if f <= 3 else 2.0)),
-             "w_words": 128}
-            for w in (4, 16) for f in (2, 4)]
-    return LatencyTable(rows=rows, meta={"backend": "cpu"})
-
-
-def test_latency_table_interpolation_and_extrapolation():
-    t = _grid_table()
-    assert t.estimate_level_us(4, fanin=2) == 4.0       # exact grid point
-    assert t.estimate_level_us(10, fanin=2) == 10.0     # linear in width
-    assert t.estimate_level_us(32, fanin=2) == 32.0     # extrapolated
-    assert t.estimate_level_us(4, fanin=6) == 8.0       # nearest fanin = 4
-    with pytest.raises(ValueError):
-        t.estimate_level_us(4, fanin=2, k=4)            # no k=4 rows
-
-
-def test_latency_table_artifact_roundtrip(tmp_path):
-    t = _grid_table()
-    path = str(tmp_path / "lut_table.json")
-    t.save(path)
-    back = LatencyTable.load(path)
-    assert back.rows == t.rows and back.meta == t.meta
-    with open(path) as f:
-        assert json.load(f)["kind"] == "lut_level_latency_table"
-    other = str(tmp_path / "not_table.json")
-    with open(other, "w") as f:
-        json.dump({"kind": "something_else"}, f)
-    with pytest.raises(ValueError):
-        LatencyTable.load(other)
-
-
-# ---------------------------------------------------------------------------
-# Calibrated-estimate seeding of the execution EWMAs
+# Estimate seeding of the execution EWMAs
 # ---------------------------------------------------------------------------
 
 def test_sched_ewma_seeded_from_estimate():

@@ -10,8 +10,7 @@ import pytest
 
 from repro.check.tracecheck import (check_phase_reconciliation,
                                     synthetic_trace_events)
-from repro.obs import (BucketRing, BurnRateMonitor, EmptyLatencyTable,
-                       LatencyTable, LatencyTableError, MetricsRegistry,
+from repro.obs import (BucketRing, BurnRateMonitor, MetricsRegistry,
                        MetricsServer, SpanTracer,
                        TraceEvent, WindowedMetrics, analyze_events,
                        analyze_trace, to_prometheus_text,
@@ -331,55 +330,6 @@ def test_degraded_check_rate_limited():
     clk.advance_us(s._monitor_interval_us + 1.0)
     s.submit(np.ones((1, 3), np.float32))
     assert len(calls) == 2
-
-
-# ---------------------------------------------------------------------------
-# LatencyTable hardening
-# ---------------------------------------------------------------------------
-
-def _grid_table(scale=1.0):
-    rows = [{"source": "grid", "level_width": w, "k": 6, "fanin": f,
-             "device_us": float(w), "w_words": 128}
-            for w in (4, 16) for f in (2, 4)]
-    return LatencyTable(rows=rows, meta={}, scale=scale)
-
-
-def test_latency_table_empty_and_bad_queries():
-    empty = LatencyTable(rows=[], meta={})
-    with pytest.raises(EmptyLatencyTable):
-        empty.estimate_level_us(4, fanin=2)
-    t = _grid_table()
-    with pytest.raises(LatencyTableError):
-        t.estimate_level_us(float("nan"), fanin=2)
-    with pytest.raises(LatencyTableError):
-        t.estimate_level_us(4, fanin=float("inf"))
-    # EmptyLatencyTable is a LatencyTableError is a ValueError, so
-    # existing except-ValueError callers keep working
-    assert issubclass(EmptyLatencyTable, LatencyTableError)
-    assert issubclass(LatencyTableError, ValueError)
-
-
-def test_latency_table_out_of_grid_clamps():
-    t = _grid_table()
-    assert t.estimate_level_us(1, fanin=2) == 4.0    # below grid: clamp
-    assert t.estimate_level_us(0, fanin=2) == 4.0
-    assert t.estimate_level_us(-3, fanin=2) == 4.0   # negative: clamp to 0
-    # above grid: proportional per-LUT scaling, never a 2-point slope
-    assert t.estimate_level_us(64, fanin=2) == 64.0
-
-
-def test_latency_table_scale_blend_and_roundtrip(tmp_path):
-    t = _grid_table()
-    assert t.blend_scale(2.0, alpha=1.0) == 2.0
-    assert t.estimate_level_us(4, fanin=2) == 8.0    # estimates rescale
-    t.blend_scale(float("nan"))                      # ignored
-    t.blend_scale(-1.0)
-    assert t.scale == 2.0
-    t.blend_scale(1e9, alpha=1.0)                    # clamped, not poisoned
-    assert t.scale == LatencyTable.SCALE_MAX
-    path = str(tmp_path / "t.json")
-    t.save(path)
-    assert LatencyTable.load(path).scale == t.scale
 
 
 # ---------------------------------------------------------------------------

@@ -559,13 +559,13 @@ def test_logic_replicas_pinned_one_device_each(jsc_small):
     from repro.serve import build_logic_replicas
     net, xte = jsc_small
     rs = build_logic_replicas(net, 5, n_replicas=2, backend="bitplane",
-                              max_batch=64, engine="pallas")
+                              max_batch=64, engine="pallas-streamed")
     devs = jax.devices()
     for i, r in enumerate(rs.replicas):
         ex = r.fn.bitnet.executor
         assert ex.device == devs[i % len(devs)]
-        assert ex._leaf.committed and ex._leaf.devices() == {ex.device}
-        out = ex.device_labels(np.zeros((ex.dp.n_pis, 2), np.uint32), 5)
+        assert ex._meta.committed and ex._meta.devices() == {ex.device}
+        out = ex.device_labels(np.zeros((ex.tp.n_pis, 2), np.uint32), 5)
         assert out.committed and out.devices() == {ex.device}
     want = rs.replicas[0].fn.bitnet.classify(xte[:40], 5)
     np.testing.assert_array_equal(rs(xte[:40]), want)

@@ -193,7 +193,7 @@ def test_jsc_s_structural_report(jsc_s):
 
 
 def test_jsc_s_bitplane_engine_matches_gather(jsc_s):
-    """gather, numpy-bitplane and pallas-bitplane backends are
+    """gather, numpy-bitplane and device-bitplane backends are
     argmax-identical end to end, including the ragged final flush
     through the aggregator's ``pad_rows`` (600 = 4*128 + 88)."""
     from repro.serving.engine import LogicEngine
@@ -202,20 +202,21 @@ def test_jsc_s_bitplane_engine_matches_gather(jsc_s):
     gather = LogicEngine(net, 5, max_batch=128)
     bitplane = LogicEngine(net, 5, max_batch=128, backend="bitplane")
     pallas = LogicEngine(net, 5, max_batch=128, backend="bitplane",
-                         engine="pallas")
+                         engine="pallas-streamed")
     want = gather.classify(xte[:600])
     np.testing.assert_array_equal(want, bitplane.classify(xte[:600]))
     np.testing.assert_array_equal(want, pallas.classify(xte[:600]))
 
 
 def test_jsc_s_pallas_engine_bit_identical(jsc_s):
-    """The fused lut_eval device pipeline is *bit*-identical to the
-    numpy fold (codes and packed words, not just argmax)."""
+    """The fused lut_eval device pipeline (``pallas-streamed``) is
+    *bit*-identical to the numpy fold (codes and packed words, not just
+    argmax)."""
     from repro.synth.executor import BitplaneNetwork
     from repro.synth.simulate import pack_bits
     net, data = jsc_s
     bit = compile_logic_network(net, effort=1)
-    dev = BitplaneNetwork(net, bit.mapped, engine="pallas")
+    dev = BitplaneNetwork(net, bit.mapped, engine="pallas-streamed")
     (xte, _) = data[1]
     for n in (64, 97):                       # full + ragged lane words
         codes = np.asarray(net.quantize_inputs(jnp.asarray(xte[:n])))

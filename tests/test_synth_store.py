@@ -11,8 +11,8 @@ from repro.core.logic_infer import LogicNetwork
 from repro.core.quant import ActQuantSpec
 from repro.core.truthtable import LayerTables
 from repro.synth import store
+from repro.synth import UnknownEngineError
 from repro.synth.executor import BitplaneNetwork, compile_tile_plan, staged_rows
-from repro.synth.executors import UnknownEngineError
 from repro.synth.lutmap import MappedLUT
 
 SPEC = ActQuantSpec("signed", 2)        # 3 levels, 2-bit codes

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.lut_eval.lut_eval import (_meta_layout, lut_eval_pallas,
+from repro.kernels.lut_eval.lut_eval import (_meta_layout,
                                              lut_eval_streamed_pallas,
                                              record_gather_cap)
 
@@ -25,7 +25,7 @@ K = 6
 N_CLASSES = 5
 # JSC-S as served: 16 inputs x 2 bits = 32 PIs, ~90 LUTs in 4 levels;
 # loadgen's max_batch=256 rows pack into W = 8 words.
-JSC_S = dict(n_pis=32, n_slots=96, n_tiles=4, gather_cap=96)
+JSC_S = dict(n_pis=32, n_tiles=4, gather_cap=96)
 # JSC-L-sized tile plan: 16 inputs x 3 bits = 48 PIs, ~12k LUT slots.
 JSC_L = dict(n_pis=48, n_tiles=380, gather_cap=192)
 # JSC-M as served (bench/configs/jsc-m.json): 48 PIs, 60,415 LUTs in
@@ -67,22 +67,6 @@ def _custom_call_hlo(compiled) -> str:
     return text
 
 
-@pytest.mark.parametrize("w", [8, 128])
-def test_monolithic_kernel_compiles_jsc_s(one_chip, w):
-    n, s = JSC_S["n_pis"], JSC_S["n_slots"]
-    n_wires = 1 + n + s
-
-    def run(words, leaf, tt, ow):
-        return lut_eval_pallas(words, leaf, tt, ow, n_pis=n, n_slots=s,
-                               n_wires=n_wires, k=K, block_w=min(w, 128),
-                               interpret=False)
-
-    compiled = jax.jit(run).lower(
-        _shape(one_chip, n, w), _shape(one_chip, s, K),
-        _shape(one_chip, s, 1 << K), _shape(one_chip, s)).compile()
-    _custom_call_hlo(compiled)
-
-
 def _compile_streamed(sharding, n_pis, n_tiles, gather_cap, w, gather,
                       tile_rows=32):
     n_rows = 1 + n_pis + n_tiles * tile_rows
@@ -112,8 +96,7 @@ def test_streamed_dma_kernel_compiles_jsc_s(one_chip, w, gather):
 @pytest.mark.parametrize("gather", ["dma", "vmem"])
 @pytest.mark.parametrize("w", [8, 256])
 def test_streamed_dma_kernel_compiles_jsc_l(one_chip, w, gather):
-    """A JSC-L-sized plan: the monolithic kernel's SMEM leaf table does
-    not fit at this size, so the streamed engine is its only path."""
+    """A JSC-L-sized plan, with the plane in HBM and in VMEM."""
     _custom_call_hlo(_compile_streamed(
         one_chip, JSC_L["n_pis"], JSC_L["n_tiles"], JSC_L["gather_cap"], w,
         gather))
@@ -145,14 +128,16 @@ def _random_netlist(n_pis: int, n_ands: int, n_outs: int, seed: int = 0):
     return synthesize(aig)
 
 
-def test_streamed_executor_classify_compiles(one_chip, monkeypatch):
+@pytest.mark.parametrize("w", [8, 128])
+def test_streamed_executor_classify_compiles(one_chip, monkeypatch, w):
     """The fused classify jit the aggregator calls (pack -> streamed
-    kernel -> complement -> decode -> argmax) at the serving shape, in
+    kernel -> complement -> decode -> argmax) at the serving shape
+    (W = 8, 256 rows) and a full-lane pack (W = 128, 4,096 rows), in
     the gather mode the plan's size gives on a v5e core, and with the
     plane in HBM where a core's budget is smaller than the plane."""
     from repro.check.plan_check import (V5E_VMEM_BYTES, gather_mode,
                                         resident_plane_bytes)
-    from repro.synth.executor import _compile_plan, _StreamedExecutor
+    from repro.synth.executor import _compile_plan, _DeviceExecutor
     lut_eval = importlib.import_module("repro.kernels.lut_eval.lut_eval")
 
     mapped = _random_netlist(JSC_S["n_pis"], 600, N_CLASSES * 3)
@@ -164,14 +149,14 @@ def test_streamed_executor_classify_compiles(one_chip, monkeypatch):
     # the described chip is a v5e; this process's default device is not
     monkeypatch.setattr(lut_eval, "vmem_capacity_bytes",
                         lambda interpret: V5E_VMEM_BYTES)
-    ex = _StreamedExecutor(bitnet, interpret=False, use_cache=False)
+    ex = _DeviceExecutor(bitnet, interpret=False)
     assert ex.gather == gather_mode(ex.tp, V5E_VMEM_BYTES) == "vmem"
     monkeypatch.setattr(lut_eval, "vmem_capacity_bytes",
                         lambda interpret: resident_plane_bytes(ex.tp))
-    over = _StreamedExecutor(bitnet, interpret=False, use_cache=False)
+    over = _DeviceExecutor(bitnet, interpret=False)
     assert over.gather == "dma"
     for e in (ex, over):
         compiled = e._argmax_words.lower(
-            _shape(one_chip, JSC_S["n_pis"], 8),
+            _shape(one_chip, JSC_S["n_pis"], w),
             n_classes=N_CLASSES).compile()
         _custom_call_hlo(compiled)
