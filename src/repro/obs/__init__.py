@@ -7,9 +7,9 @@ NullaNet Tiny's whole pitch is latency, so latency has to be visible
                near-zero overhead when disabled); every request carries
                submit → queue-wait → batch-formation (with flush
                reason) → pack (quantize, bitpack) → dispatch →
-               device-exec (h2d, fetch) → scatter spans joined by one
-               batch id, beside the dispatch thread's waits, gc pauses
-               and compiles. While a ``jax.profiler`` trace is active,
+               device-exec (h2d, launch) → fetch → scatter spans joined
+               by one batch id, beside the dispatch thread's waits, gc
+               pauses and compiles. While a ``jax.profiler`` trace is active,
                every thread span is also a ``TraceAnnotation`` of the
                same name, so it lands in the device trace beside the
                ``XLA Ops``;

@@ -6,14 +6,18 @@ reconstruction leans on two structural facts of the serving stack:
 
   * async request spans carry a tracer-allocated ``scope_id``, so a
     request's begin/instants/end pair up across threads by id;
-  * the scheduler serializes batches on one dispatch thread and thread
+  * the scheduler forms batches on one dispatch thread and thread
     spans record at context *exit*, so each batch appears in buffer
     order as ``[e queue_wait]*n → X batch_form → (X quantize, X bitpack,
-    X aggregate_pack, X h2d, X fetch, X device_exec, X replica_dispatch)
-    → X exec → [e request]*n → X scatter`` — a linear scan with a
-    current-batch state machine rebinds every request to the batch that
-    served it. The batch spans and the ``queue_wait`` ends also carry
-    the batch's ``args.batch`` id. Spans that belong to no batch
+    X aggregate_pack, X h2d, X device_exec, X fetch, X replica_dispatch)
+    → X exec → ([X fetch] → X collect) → [e request]*n → X scatter`` —
+    a linear scan binds every request to the batch whose ``batch_form``
+    follows its ``queue_wait`` end. The batch spans carry the batch's
+    ``args.batch`` id, and the scan credits each span to the batch of
+    that id: with two batches in flight, the completion thread's
+    ``fetch``/``collect``/``scatter`` of one batch interleave with the
+    dispatch thread's spans of the next. A span without the id goes to
+    the batch formed last. Spans that belong to no batch
     (``UNBATCHED_SPANS``: the dispatch thread's waits, gc pauses,
     compiles) may fall anywhere in that order and are skipped.
 
@@ -22,9 +26,13 @@ Per-request phase decomposition (all µs):
   ``queue_wait``  enqueue → batch formation (per-request, measured)
   ``batch_form``  payload concatenation for the batch it rode
   ``pack``        bitplane aggregation (quantize + scatter to lanes)
-  ``device_exec`` netlist evaluation on the engine
+  ``device_exec`` netlist evaluation on the engine: the launch
+                  (``device_exec`` span, h2d included) and the wait for
+                  the labels (``fetch``)
   ``dispatch``    executor time not inside pack/device — replica pick,
-                  failover, mesh placement (``exec − pack − device``)
+                  failover, mesh placement, and, with batches in flight,
+                  the wait behind the one before (``exec − pack −
+                  device``)
   ``scatter``     result slicing back to futures (*after* the latency
                   stamp — reported, but outside the reconciliation sum)
 
@@ -94,6 +102,19 @@ class BatchRecord:
     exec_us: float = 0.0
     scatter_us: float = 0.0
     members: List[int] = dataclasses.field(default_factory=list)
+    exec_t0_us: Optional[float] = None
+
+    def add_exec(self, ts_us: float, dur_us: float) -> None:
+        """Widen ``exec_us`` to cover a span of the batch's execution:
+        its ``exec`` span (dispatch thread) and, when the labels came
+        back on the completion thread, its ``collect`` span — dispatch
+        to labels on the host, what the request's latency holds."""
+        if self.exec_t0_us is None:
+            self.exec_t0_us, self.exec_us = ts_us, dur_us
+            return
+        t1 = max(self.exec_t0_us + self.exec_us, ts_us + dur_us)
+        self.exec_t0_us = min(self.exec_t0_us, ts_us)
+        self.exec_us = t1 - self.exec_t0_us
 
     @property
     def dispatch_us(self) -> float:
@@ -245,6 +266,7 @@ def analyze_events(events: Sequence[TraceEvent],
     batches: List[BatchRecord] = []
     pending: List[int] = []         # queue_wait-closed, awaiting batch_form
     current: Optional[BatchRecord] = None
+    by_id: Dict[int, BatchRecord] = {}
     counts = {"rejects": 0, "failovers": 0, "orphan_ends": 0}
 
     def req(sid: int) -> RequestRecord:
@@ -306,15 +328,21 @@ def analyze_events(events: Sequence[TraceEvent],
                     reqs[sid].batch = current
                 pending = []
                 batches.append(current)
-            elif current is not None and ev.name == "aggregate_pack":
-                current.pack_us += ev.dur_us
-            elif current is not None and ev.name == "device_exec":
-                current.device_us += ev.dur_us
-            elif current is not None and ev.name == "exec" \
-                    and ev.cat == "exec":
-                current.exec_us += ev.dur_us
-            elif current is not None and ev.name == "scatter":
-                current.scatter_us += ev.dur_us
+                if _arg(ev, "batch") is not None:
+                    by_id[_arg(ev, "batch")] = current
+                continue
+            bid = _arg(ev, "batch")
+            b = current if bid is None else by_id.get(bid)
+            if b is None:
+                continue
+            if ev.name == "aggregate_pack":
+                b.pack_us += ev.dur_us
+            elif ev.name in ("device_exec", "fetch"):
+                b.device_us += ev.dur_us
+            elif ev.name in ("exec", "collect") and ev.cat == "exec":
+                b.add_exec(ev.ts_us, ev.dur_us)
+            elif ev.name == "scatter":
+                b.scatter_us += ev.dur_us
         elif ev.ph == "i":
             if ev.name == "reject":
                 counts["rejects"] += 1

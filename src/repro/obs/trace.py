@@ -5,8 +5,10 @@ a straight mapping:
 
   * **thread spans** (``ph="X"``) — work done start-to-finish on one
     thread: batch formation, aggregate pack (``quantize``,
-    ``bitpack``), device exec (``h2d``, ``fetch``), scatter, and the
-    dispatch thread's ``sched_wait`` for arrivals or a flush deadline.
+    ``bitpack``), device exec (``h2d`` and the launch), the wait for
+    the labels (``fetch``; on the completion thread, inside ``collect``,
+    when two batches are in flight), scatter, and the dispatch
+    thread's ``sched_wait`` for arrivals or a flush deadline.
     Nested calls on the same thread nest in Perfetto by time
     containment, so the aggregator's ``pack``/``device_exec`` spans
     render inside the scheduler's ``exec`` span with no extra plumbing;

@@ -16,9 +16,12 @@ built with (``repro.synth.ENGINES``): under the device engine
 (``"pallas-streamed"``) the packed words are handed straight to the
 kernel and only the scattered argmax labels come back — pack → all
 levels → complement → argmax is one fused jit, so between enqueue and
-verdict nothing touches the host. The numpy engine
-keeps the host fold (``execute_packed``) + decode. Aggregation itself
-is engine-agnostic; ``classify_packed`` dispatches.
+verdict nothing touches the host. The call returns once that program is
+launched: its labels are ``PendingLabels``, which ``np.asarray`` waits
+for, so the scheduler can pack and launch the next batch meanwhile. The
+numpy engine keeps the host fold (``execute_packed``) + decode and
+returns an ndarray. Aggregation itself is engine-agnostic;
+``classify_packed`` dispatches.
 """
 from __future__ import annotations
 
@@ -39,7 +42,8 @@ class BitplaneAggregator:
     uint32 lane-word through the whole netlist.
 
     Not thread-safe by design — the scheduler serializes executor calls
-    on one dispatch thread, so the ``n_*`` counters need no lock and
+    on one dispatch thread (its completion thread only awaits the
+    returned labels), so the ``n_*`` counters need no lock and
     carry no ``_GUARDED_BY`` annotation. Wrap in ``ReplicaSet`` for
     concurrent dispatch.
     """
@@ -57,7 +61,7 @@ class BitplaneAggregator:
         self.n_pad_rows = 0         # shape-stability padding rows added
         self.n_partial_packs = 0    # flushes whose last lane-word is partial
         if pad_rows:                # warm the single device-program shape
-            self(np.zeros((1, bitnet.net.n_inputs), np.float32))
+            np.asarray(self(np.zeros((1, bitnet.net.n_inputs), np.float32)))
             self.n_evals = self.n_rows = 0
             self.n_pad_rows = self.n_partial_packs = 0
 
@@ -86,13 +90,15 @@ class BitplaneAggregator:
             return pack_bits(planes)
 
     def __call__(self, x: np.ndarray,
-                 deadline_us: Optional[float] = None) -> np.ndarray:
-        """Evaluate one request pack. ``deadline_us`` (the tightest
-        absolute SLO deadline in the batch, forwarded by the scheduler)
-        is what triggers partial-pack flushes upstream: the scheduler
-        dispatches before the lane-word is full whenever that deadline
-        cannot absorb further fill-wait, and ``n_partial_packs`` counts
-        how often the pack went out with idle lanes as a result."""
+                 deadline_us: Optional[float] = None):
+        """Evaluate one request pack; returns its labels, pending on the
+        device engine (``np.asarray`` waits for them). ``deadline_us``
+        (the tightest absolute SLO deadline in the batch, forwarded by
+        the scheduler) is what triggers partial-pack flushes upstream:
+        the scheduler dispatches before the lane-word is full whenever
+        that deadline cannot absorb further fill-wait, and
+        ``n_partial_packs`` counts how often the pack went out with idle
+        lanes as a result."""
         x = np.asarray(x)
         true_rows = x.shape[0]
         tr = self.tracer
@@ -105,8 +111,9 @@ class BitplaneAggregator:
         with tr.span("aggregate_pack", cat="pack", args=pack_args):
             pi_words = self.pack_requests(x)
         # engine dispatch happens inside classify_packed: the device
-        # engine ships the words to the device and returns only the
-        # scattered per-request argmax; numpy is the host fold + decode.
+        # engine ships the words to the device and launches the program
+        # (h2d + launch: the labels stay pending); numpy is the host
+        # fold + decode.
         with tr.span("device_exec", cat="exec", args=exec_args):
             labels = self.bitnet.classify_packed(pi_words, true_rows,
                                                  self.n_classes)
@@ -123,9 +130,10 @@ class BitplaneAggregator:
 
     def set_tracer(self, tracer) -> None:
         """Adopt ``tracer`` (propagated through the underlying network
-        to its engine, so the ``h2d``/``fetch`` spans nest inside
-        ``device_exec``); the scheduler calls this automatically when
-        constructed with one."""
+        to its engine, so the ``h2d`` span nests inside ``device_exec``
+        and the labels' ``fetch`` is recorded where they are awaited);
+        the scheduler calls this automatically when constructed with
+        one."""
         self.tracer = tracer
         self.bitnet.tracer = tracer
 

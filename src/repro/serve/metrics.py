@@ -81,6 +81,7 @@ class BatchStat:
     rows: int
     occupancy: float
     exec_us: float
+    overlapped: bool = False    # dispatched with another batch in flight
 
 
 class LaneStats:
@@ -191,10 +192,11 @@ class ServeMetrics:
             self.rejected[reason] = self.rejected.get(reason, 0) + 1
 
     def record_batch(self, rows: int, exec_us: float,
-                     now_us: Optional[float] = None) -> None:
+                     now_us: Optional[float] = None,
+                     overlapped: bool = False) -> None:
         occ = rows / self.max_batch if self.max_batch else 1.0
         with self._lock:
-            self.batches.append(BatchStat(rows, occ, exec_us))
+            self.batches.append(BatchStat(rows, occ, exec_us, overlapped))
         if self._sinks and now_us is not None:
             self._fan_out("record_batch", rows=rows, exec_us=exec_us,
                           now_us=now_us, occupancy=occ)
@@ -277,6 +279,7 @@ class ServeMetrics:
                         if span_us > 0 else 0.0),
                 "span_us": span_us,
                 "n_batches": len(self.batches),
+                "n_overlapped": sum(b.overlapped for b in self.batches),
                 "mean_batch_rows": float(np.mean(rows)) if rows else 0.0,
                 "mean_batch_occupancy": float(np.mean(occ)) if occ else 0.0,
                 "mean_queue_depth": (self.queue_depth_sum

@@ -135,7 +135,8 @@ class ReplicaSet:
                                       args={"rid": r.rid,
                                             "attempt": attempt,
                                             "policy": self.policy}):
-                    out = r.fn(x)
+                    # labels on the host: a device error fails over here
+                    out = np.asarray(r.fn(x))
                 dt = self.clock.now_us() - t0
                 with self._lock:
                     r.inflight -= 1

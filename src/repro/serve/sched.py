@@ -24,7 +24,18 @@ Two drivers sit on top of the core:
     ``serve_queue`` compatibility wrapper, simulated loadgen);
   * threaded — ``start()`` spawns a flush loop that sleeps until the
     earliest flush obligation (SLO deadline or age cap) and wakes on
-    submit (real-time open-loop serving).
+    submit (real-time open-loop serving), and a completion thread.
+
+An executor may return labels that are not yet on the host (anything
+but an ``np.ndarray``, e.g. the device engine's ``PendingLabels``):
+under the threaded driver, for a batch flushed because it is full, the
+dispatch thread then hands the batch to the completion thread, which
+materialises the labels (``np.asarray``) and scatters them in dispatch
+order, and goes on to form, pack and launch the next batch meanwhile —
+at most ``PIPELINE_DEPTH`` batches in flight. Executors that return
+ndarrays, batches flushed on time or drained, and ``poll`` driven
+without ``start()`` run each batch start to finish on the calling
+thread.
 
 The executor contract is one callable ``(B, ...) -> (B,)``: it receives
 the concatenated rows of every request in the batch and returns one
@@ -38,6 +49,7 @@ admission can reject wrong-width payloads before they poison a batch.
 from __future__ import annotations
 
 import bisect
+import collections
 import dataclasses
 import heapq
 import inspect
@@ -51,6 +63,11 @@ from repro.obs.trace import NULL_TRACER
 
 from .clock import SystemClock
 from .metrics import ServeMetrics
+
+# batches the threaded driver keeps in flight: while the completion
+# thread waits for one batch's labels, the dispatch thread forms, packs
+# and launches the next
+PIPELINE_DEPTH = 2
 
 # ---------------------------------------------------------------------------
 # Futures + typed rejection
@@ -292,16 +309,22 @@ class MicroBatchScheduler:
 
     # lock-discipline contract, enforced by repro.check's concurrency
     # lint: these fields may only be touched under ``with self._cond:``
-    # (outside __init__). _exec_ewma_us/_n_execs are deliberately not
-    # listed: they are written by whichever single thread drives poll()
-    # and only read under the lock as a flush-timing *estimate*, where a
-    # stale value is harmless.
+    # (outside __init__)
     _GUARDED_BY = {
         "_stopping": "_cond",
         "_shutdown": "_cond",
         "_n_features": "_cond",
         "_monitor_next_us": "_cond",
+        "_inflight": "_cond",
+        "_collect_stop": "_cond",
     }
+    # _exec_ewma_us/_n_execs have one writer at a time — the completion
+    # thread while batches are in flight, else the thread that drives
+    # poll(), which runs a batch itself only once none is in flight —
+    # and are only read as a flush-timing *estimate*, where a stale
+    # value is harmless. _collector is set before the dispatch thread
+    # starts and cleared after both threads are joined.
+    _LOCK_FREE = ("_exec_ewma_us", "_n_execs", "_collector")
     # helpers that require _cond already held by the caller
     _LOCKED_METHODS = ("_degraded_check",)
 
@@ -338,11 +361,21 @@ class MicroBatchScheduler:
             executor.set_tracer(tracer)
         self.queue = BoundedPriorityQueue(self.cfg.max_queue,
                                           self.cfg.n_priorities)
-        self._cond = threading.Condition()
+        lock = threading.RLock()
+        self._cond = threading.Condition(lock)
+        # the batches in flight change under the same lock, but their
+        # waiters (the completion thread, a dispatch thread waiting for
+        # a slot) wait here, so that submits do not wake them
+        self._inflight_cv = threading.Condition(lock)
         self._thread: Optional[threading.Thread] = None
+        self._collector: Optional[threading.Thread] = None
         self._hooked = False        # process hooks attached by start()
         self._stopping = False
         self._shutdown = False
+        # batches handed to the completion thread, in dispatch order:
+        # (batch, pending labels, t0, batch id, rows, in flight before it)
+        self._inflight: collections.deque = collections.deque()
+        self._collect_stop = False
         # smoothed batch execution time; a given estimate seeds it so
         # the first flush margins aren't cold
         self._exec_ewma_us = float(self.cfg.exec_estimate_us or 0.0)
@@ -534,6 +567,16 @@ class MicroBatchScheduler:
     def _run_batch(self, batch: List[ServeRequest],
                    reason: str = "drain") -> None:
         tracer = self.tracer
+        # a full batch is throughput-bound: it may launch while another
+        # is in flight. One flushed on time (or drained) is latency-
+        # bound: it runs alone, start to finish on this thread, after
+        # the batches in flight (more, smaller batches in flight only
+        # add host work per batch to its latency)
+        pipelined = self._collector is not None and reason == "size"
+        with self._cond:
+            while not pipelined and self._inflight:
+                self._inflight_cv.wait()
+            inflight = len(self._inflight)
         rows = sum(r.rows for r in batch)
         t_form = self.clock.now_us()
         bid = form_args = None
@@ -569,27 +612,50 @@ class MicroBatchScheduler:
         t0 = self.clock.now_us()
         try:
             with tracer.span("exec", cat="exec",
-                             args={"rows": rows, "batch": bid}):
+                             args={"rows": rows, "batch": bid,
+                                   "inflight": inflight}):
                 if self._pass_deadline:
                     res = self.executor(xcat, deadline_us=tightest)
                 else:
                     res = self.executor(xcat)
+                if not pipelined and not isinstance(res, np.ndarray):
+                    res = np.asarray(res)   # serial: wait for the labels
         except Exception as e:              # fail the whole batch, keep serving
-            now = self.clock.now_us()
-            self.metrics.record_error(len(batch))
-            for r in batch:
-                r.future.t_done_us = now
-                if r.trace_id is not None:
-                    tracer.aend("request", r.trace_id,
-                                args={"outcome": "error",
-                                      "error": type(e).__name__})
-                r.future.set_exception(e)
+            self._fail(batch, e)
             return
         finally:
             if bid is not None:
                 tracer.set_batch(None)
+        if not isinstance(res, np.ndarray):
+            # labels still on the device: the completion thread waits
+            # for them; the dispatch thread goes on to the next batch
+            with self._cond:
+                self._inflight.append((batch, res, t0, bid, rows, inflight))
+                self._inflight_cv.notify_all()
+            return
+        self._finish(batch, res, t0, bid, rows, inflight)
+
+    def _fail(self, batch: List[ServeRequest], e: Exception) -> None:
+        """Fail every request of ``batch`` with the executor's error."""
+        tracer = self.tracer
         now = self.clock.now_us()
-        self.metrics.record_batch(rows, now - t0, now_us=now)
+        self.metrics.record_error(len(batch))
+        for r in batch:
+            r.future.t_done_us = now
+            if r.trace_id is not None:
+                tracer.aend("request", r.trace_id,
+                            args={"outcome": "error",
+                                  "error": type(e).__name__})
+            r.future.set_exception(e)
+
+    def _finish(self, batch: List[ServeRequest], res, t0: float,
+                bid: Optional[int], rows: int, inflight: int) -> None:
+        """Record the batch (dispatch ``t0`` to labels on the host) and
+        scatter its labels to the requests' futures."""
+        tracer = self.tracer
+        now = self.clock.now_us()
+        self.metrics.record_batch(rows, now - t0, now_us=now,
+                                  overlapped=inflight > 0)
         dt = now - t0
         self._n_execs += 1
         self._exec_ewma_us = (dt if self._n_execs == 1
@@ -614,13 +680,57 @@ class MicroBatchScheduler:
                         "latency_us": now - r.t_enqueue_us})
                 r.future.set_result(out[0] if r.x.ndim <= 1 else out)
 
+    def _collect_loop(self) -> None:
+        """The completion thread: in dispatch order, wait for each
+        handed-over batch's labels (the ``collect`` span, with the
+        batch's id set so the engine's ``fetch`` carries it), then
+        record and scatter the batch. A materialisation error, or labels
+        of the wrong length, fail that batch only."""
+        tracer = self.tracer
+        while True:
+            with self._cond:
+                while not self._inflight and not self._collect_stop:
+                    self._inflight_cv.wait()
+                if not self._inflight:
+                    return
+                batch, res, t0, bid, rows, inflight = self._inflight[0]
+            if bid is not None:
+                tracer.set_batch(bid)
+            try:
+                with tracer.span("collect", cat="exec", args={"batch": bid}):
+                    res = np.asarray(res)
+                if res.shape[0] != rows:
+                    raise AssertionError(
+                        f"executor returned {res.shape[0]} rows for a "
+                        f"{rows}-row batch")
+            except Exception as e:
+                self._fail(batch, e)
+            else:
+                self._finish(batch, res, t0, bid, rows, inflight)
+            finally:
+                if bid is not None:
+                    tracer.set_batch(None)
+                with self._cond:
+                    self._inflight.popleft()
+                    self._inflight_cv.notify_all()
+
+    def _await_slot(self) -> None:
+        """Block the dispatch thread while ``PIPELINE_DEPTH`` batches
+        are in flight."""
+        with self._cond:
+            while len(self._inflight) >= PIPELINE_DEPTH:
+                self._inflight_cv.wait()
+
     def poll(self, now_us: Optional[float] = None, force: bool = False) -> int:
         """Run every batch due at ``now_us`` (clock-now if omitted);
         ``force`` flushes regardless of deadlines. Returns requests
         resolved — completed, shed past-deadline, or failed with the
-        executor's error."""
+        executor's error — or, under the threaded driver, handed to the
+        completion thread with their labels pending."""
         done = 0
         while True:
+            if self._collector is not None:
+                self._await_slot()
             now = self.clock.now_us() if now_us is None else now_us
             expired, batch, reason = self._due_batch(now, force)
             self._shed(expired, now)
@@ -646,10 +756,15 @@ class MicroBatchScheduler:
         assert self._thread is None, "scheduler already started"
         with self._cond:
             self._stopping = False
+            self._collect_stop = False
         if self.tracer.enabled and not self._hooked:
             # gc pauses and compiles while serving; stop() takes them off
             self.tracer.attach_process_hooks()
             self._hooked = True
+        self._collector = threading.Thread(target=self._collect_loop,
+                                           daemon=True,
+                                           name="microbatch-collect")
+        self._collector.start()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="microbatch-sched")
         self._thread.start()
@@ -700,7 +815,8 @@ class MicroBatchScheduler:
             self.poll(force=stopping)
 
     def stop(self, drain: bool = True) -> None:
-        """Stop the driver thread, reject all further submissions, then
+        """Stop the driver thread, let the completion thread scatter
+        every batch in flight, reject all further submissions, then
         resolve what is queued (flush by default, typed shutdown-reject
         with ``drain=False``).
 
@@ -716,6 +832,13 @@ class MicroBatchScheduler:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        if self._collector is not None:
+            # every batch in flight is scattered before stop returns
+            with self._cond:
+                self._collect_stop = True
+                self._inflight_cv.notify_all()
+            self._collector.join()
+            self._collector = None
         with self._cond:
             self._shutdown = True       # latch before the final flush
         if drain:

@@ -533,7 +533,9 @@ class _DeviceExecutor:
         """Packed PI words -> int32 words on the device (the ``h2d``
         span: ``device_put`` returns once the host has handed the
         buffer over, so the rest of the copy lands in ``fetch``)."""
-        with self.tracer.span("h2d", cat="exec"):
+        tr = self.tracer
+        with tr.span("h2d", cat="exec",
+                     args={"batch": tr.batch} if tr.enabled else None):
             return self._put(
                 np.ascontiguousarray(pi_words, np.uint32).view(np.int32))
 
@@ -543,21 +545,46 @@ class _DeviceExecutor:
         return self._argmax_words(self._put_words(pi_words),
                                   n_classes=n_classes)
 
-    def classify_words(self, pi_words: np.ndarray, n_rows: int,
-                       n_classes: int) -> np.ndarray:
-        """Packed PI words straight to the device; only the per-request
-        argmax labels come back (the serve aggregation hot path). The
-        ``fetch`` span covers dispatch, kernel, argmax and the copy
-        back, up to the labels on the host; its args are the engine's
-        ``fetch_args``."""
-        words = self._put_words(pi_words)
-        with self.tracer.span("fetch", cat="exec", args=self.fetch_args):
-            return np.asarray(
-                self._argmax_words(words, n_classes=n_classes))[:n_rows]
-
     def classify_packed(self, pi_words: np.ndarray, n_rows: int,
-                        n_classes: int) -> np.ndarray:
-        return self.classify_words(pi_words, n_rows, n_classes)
+                        n_classes: int) -> "PendingLabels":
+        """Packed PI words straight to the device; returns as soon as
+        the program is launched (the serve aggregation hot path). Only
+        the per-request argmax labels come back, when the caller asks
+        for them (``PendingLabels``)."""
+        return PendingLabels(self.device_labels(pi_words, n_classes),
+                             n_rows, self.tracer, self.fetch_args)
+
+
+class PendingLabels:
+    """Per-request labels of a launched device program, not yet on the
+    host. The copy back starts as soon as the program ends;
+    ``np.asarray`` waits for it, on whichever thread asks, as the
+    ``fetch`` span (the engine's ``fetch_args``, plus the calling
+    thread's ``tracer.batch`` when it has one), slices the first
+    ``n_rows`` labels and keeps them: a second ``np.asarray`` returns
+    the same host array without waiting again."""
+
+    __slots__ = ("_dev", "_n_rows", "_tracer", "_args", "_host")
+
+    def __init__(self, dev, n_rows: int, tracer, fetch_args: dict):
+        dev.copy_to_host_async()
+        self._dev = dev
+        self._n_rows = n_rows
+        self._tracer = tracer
+        self._args = fetch_args
+        self._host: Optional[np.ndarray] = None
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        if self._host is None:
+            tr = self._tracer
+            args = self._args
+            if tr.enabled and tr.batch is not None:
+                args = {**args, "batch": tr.batch}
+            with tr.span("fetch", cat="exec", args=args):
+                self._host = np.asarray(self._dev)[: self._n_rows]
+            self._dev = None
+        out = self._host if dtype is None else self._host.astype(dtype)
+        return out.copy() if copy and out is self._host else out
 
 
 _EXECUTORS = {"numpy": _NumpyExecutor, "pallas-streamed": _DeviceExecutor}
@@ -705,8 +732,9 @@ class BitplaneNetwork:
         """Packed PI bitplanes -> per-lane argmax labels, (n_rows,) int32.
 
         The serve-aggregation entry point: on the device engine the
-        words go straight to the kernel and only the scattered argmax
-        returns; on numpy it is the host fold + decode."""
+        words go straight to the kernel and the call returns once the
+        program is launched, with ``PendingLabels`` that ``np.asarray``
+        waits for; on numpy it is the host fold + decode, an ndarray."""
         return self.executor.classify_packed(pi_words, n_rows, n_classes)
 
 

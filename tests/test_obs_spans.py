@@ -72,8 +72,11 @@ def _served_trace(net, x, engine):
 
 @pytest.mark.parametrize("engine,parent,children", [
     ("numpy", "aggregate_pack", ("quantize", "bitpack")),
-    ("pallas-streamed", "device_exec", ("h2d", "fetch")),
-], ids=["pack", "device_call"])
+    # the device call launches the program; its labels are awaited
+    # after it (here, serially, inside the same exec span)
+    ("pallas-streamed", "exec", ("device_exec", "fetch")),
+    ("pallas-streamed", "device_exec", ("h2d",)),
+], ids=["pack", "device_call", "launch"])
 def test_spans_nest_inside_their_layer(tiny_net, engine, parent, children):
     net, x = tiny_net
     evs = _served_trace(net, x, engine)
@@ -86,9 +89,10 @@ def test_spans_nest_inside_their_layer(tiny_net, engine, parent, children):
             assert sum(_inside(i, o) for o in outers) == 1, name
     # the children are disjoint and in order inside each parent
     for o in outers:
-        a, b = (next(e for e in _x(evs, n) if _inside(e, o))
-                for n in children)
-        assert a.ts_us + a.dur_us <= b.ts_us
+        inner = [next(e for e in _x(evs, n) if _inside(e, o))
+                 for n in children]
+        for a, b in zip(inner, inner[1:]):
+            assert a.ts_us + a.dur_us <= b.ts_us
 
 
 def test_batch_spans_and_queue_waits_share_one_batch_id(tiny_net):

@@ -2,6 +2,9 @@
 priority lanes, per-lane SLO deadlines (EDF formation, expiry shedding,
 miss-rate accounting), replica failover, bitplane aggregation, and
 cross-backend bit-identity of scheduled results on JSC-S."""
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -437,6 +440,198 @@ def test_least_loaded_prefers_idle_replica():
 
 
 # ---------------------------------------------------------------------------
+# Two batches in flight: executors whose labels are not yet on the host
+# ---------------------------------------------------------------------------
+
+class _Pending:
+    """Labels that reach the host only once ``gate`` is set (or raise
+    there, as a device error surfaces when the labels are awaited)."""
+
+    def __init__(self, x, gate, fail):
+        self._x, self._gate, self._fail = x, gate, fail
+
+    def __array__(self, dtype=None, copy=None):
+        assert self._gate.wait(10), "gate never opened"
+        if self._fail:
+            raise RuntimeError("device error")
+        return self._x.sum(axis=-1)
+
+
+class _GatedExecutor:
+    """Returns ``_Pending`` labels; batch i's labels wait on ``gates[i]``
+    and fail if ``i`` is in ``fail``."""
+
+    def __init__(self, fail=()):
+        self.gates: list = []
+        self.fail = set(fail)
+
+    def __call__(self, x):
+        gate = threading.Event()
+        self.gates.append(gate)
+        return _Pending(x, gate, len(self.gates) - 1 in self.fail)
+
+
+def _until(cond, timeout=10.0):
+    t_end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < t_end, "timed out"
+        time.sleep(0.002)
+
+
+def _pipelined(ex, tracer=None):
+    # each 2-row request fills a batch: a size flush at once
+    return MicroBatchScheduler(ex, SchedConfig(max_batch=2,
+                                               max_wait_us=1e6),
+                               tracer=tracer).start()
+
+
+def _req(i):
+    return np.full((2, 3), float(i), np.float32)
+
+
+def test_pipeline_keeps_at_most_two_batches_in_flight():
+    from repro.obs import SpanTracer
+    from repro.serve.sched import PIPELINE_DEPTH
+    assert PIPELINE_DEPTH == 2
+    ex, tracer = _GatedExecutor(), SpanTracer()
+    s = _pipelined(ex, tracer)
+    try:
+        futs = [s.submit(_req(i)) for i in range(4)]
+        _until(lambda: len(ex.gates) == 2)
+        time.sleep(0.05)
+        # two launched and unanswered: the third waits for a slot
+        assert len(ex.gates) == 2 and not any(f.done() for f in futs)
+        for i in (0, 1):            # each answer lets one more launch
+            ex.gates[i].set()
+            _until(lambda: len(ex.gates) == i + 3)
+            assert futs[i].done() and not futs[i + 1].done()
+        for i in (2, 3):
+            ex.gates[i].set()
+        got = [f.result(10) for f in futs]
+    finally:
+        s.stop()
+    for i, g in enumerate(got):
+        np.testing.assert_array_equal(g, [3.0 * i, 3.0 * i])
+    evs = tracer.events()
+    execs = [e for e in evs if e.name == "exec"]
+    assert [e.args["inflight"] for e in execs] == [0, 1, 1, 1]
+    assert s.metrics.snapshot()["n_overlapped"] == 3
+    # the interleaved spans of overlapping batches still add up to each
+    # request's latency (queue_wait + batch_form + exec..collect)
+    from repro.check.tracecheck import check_phase_reconciliation, check_trace
+    rep = check_trace(evs)
+    check_phase_reconciliation(evs, report=rep)
+    assert rep.ok and rep.info["phase_recon"]["n_checked"] == 4, rep.format()
+
+
+@pytest.mark.parametrize("release,fail", [((1, 0, 2), ()), ((0, 1, 2), (1,))],
+                         ids=["fifo", "materialise_error"])
+def test_pipeline_scatters_in_dispatch_order(release, fail):
+    """Batch 1's labels released before batch 0's are still scattered
+    after them; a batch whose labels raise fails alone, and the
+    scheduler goes on serving."""
+    ex = _GatedExecutor(fail)
+    s = _pipelined(ex)
+    try:
+        futs = [s.submit(_req(i)) for i in range(3)]
+        _until(lambda: len(ex.gates) == 2)
+        first = release[0]
+        ex.gates[first].set()
+        time.sleep(0.05)
+        # done only once every batch before it is
+        assert futs[first].done() == (first == 0)
+        for i in release[1:]:
+            _until(lambda: len(ex.gates) > i)
+            ex.gates[i].set()
+        for f in futs:
+            _until(f.done)
+        for i, f in enumerate(futs):
+            if i in fail:
+                with pytest.raises(RuntimeError, match="device error"):
+                    f.result(0)
+            else:
+                np.testing.assert_array_equal(f.result(0), [3.0 * i] * 2)
+        assert [f.t_done_us for f in futs] == sorted(
+            f.t_done_us for f in futs)
+        late = s.submit(_req(7))
+        _until(lambda: len(ex.gates) == 4)
+        ex.gates[3].set()
+        np.testing.assert_array_equal(late.result(10), [21.0, 21.0])
+    finally:
+        s.stop()
+    snap = s.metrics.snapshot()
+    assert snap["errors"] == len(fail)
+    assert snap["completed"] == 4 - len(fail)
+
+
+@pytest.mark.parametrize("drain", [True, False])
+def test_stop_resolves_batches_in_flight(drain):
+    """stop() returns only once the batches in flight are scattered:
+    the two launched before it, and the one the dispatch thread flushes
+    from the queue as it stops."""
+    ex = _GatedExecutor()
+    s = _pipelined(ex)
+    futs = [s.submit(_req(i)) for i in range(3)]
+    _until(lambda: len(ex.gates) == 2)
+    stopper = threading.Thread(target=s.stop, kwargs={"drain": drain})
+    stopper.start()
+    time.sleep(0.05)
+    assert stopper.is_alive() and not any(f.done() for f in futs)
+    ex.gates[0].set()
+    _until(lambda: len(ex.gates) == 3)
+    time.sleep(0.05)
+    assert stopper.is_alive() and not futs[1].done()
+    ex.gates[1].set()
+    ex.gates[2].set()
+    stopper.join(10)
+    assert not stopper.is_alive()
+    for i, f in enumerate(futs):
+        np.testing.assert_array_equal(f.result(0), [3.0 * i] * 2)
+    with pytest.raises(RequestRejected) as e:
+        s.submit(_req(3))
+    assert e.value.reason == RejectReason.SHUTDOWN
+
+
+def _open_pending(x):
+    gate = threading.Event()
+    gate.set()
+    return _Pending(x, gate, False)
+
+
+@pytest.mark.parametrize("executor,rows", [
+    (_sum_executor([]), 2),         # labels already on the host
+    (_open_pending, 1),             # 1-row requests: flushed on time
+], ids=["ndarray_labels", "flushed_on_time"])
+def test_batches_that_stay_serial(executor, rows):
+    """Each batch runs start to finish on the dispatch thread — no batch
+    in flight behind another, no ``collect`` span — where its labels
+    are already on the host, or where it was flushed on time, not
+    because it was full."""
+    from repro.obs import SpanTracer
+    tracer = SpanTracer()
+    s = MicroBatchScheduler(executor, SchedConfig(max_batch=2,
+                                                  max_wait_us=100.0),
+                            tracer=tracer).start()
+    try:
+        for i in range(6):
+            x = _req(i)[:rows]
+            np.testing.assert_array_equal(s.submit(x).result(10),
+                                          x.sum(axis=-1))
+    finally:
+        s.stop()
+    evs = tracer.events()
+    execs = [e for e in evs if e.name == "exec"]
+    assert len(execs) == 6 and all(e.args["inflight"] == 0 for e in execs)
+    reasons = {e.args["flush_reason"] for e in evs
+               if e.name == "batch_form"}
+    assert reasons == ({"size"} if rows == 2 else {"max_wait"})
+    assert not [e for e in evs if e.name == "collect"]
+    assert {e.tid for e in execs} == {
+        e.tid for e in evs if e.name == "scatter"}
+    assert s.metrics.snapshot()["n_overlapped"] == 0
+
+
+# ---------------------------------------------------------------------------
 # Scheduled serving on JSC-S: all backends, bit-identical to classify
 # ---------------------------------------------------------------------------
 
@@ -519,6 +714,70 @@ def test_aggregator_pack_makes_no_device_transfer(jsc_small, pad_rows):
     with jax.transfer_guard("disallow"):
         got = agg.pack_requests(rows)
     np.testing.assert_array_equal(got, want)
+
+
+def _device_aggregator(jsc_small):
+    """A padded aggregator on the device engine (interpreted here) and
+    the numpy-engine aggregator over the same netlist."""
+    from repro.serving.engine import LogicEngine
+    from repro.synth import BitplaneNetwork
+    net, _ = jsc_small
+    ref = LogicEngine(net, 5, max_batch=64, backend="bitplane").bitnet
+    dev = BitplaneNetwork(net, ref.mapped, engine="pallas-streamed")
+    return (BitplaneAggregator(dev, 5, pad_rows=64),
+            BitplaneAggregator(ref, 5))
+
+
+def test_device_aggregator_returns_pending_labels(jsc_small):
+    _, xte = jsc_small
+    agg, ref = _device_aggregator(jsc_small)
+    x = xte[:40]
+    pending = agg(x)
+    assert not isinstance(pending, np.ndarray)
+    got = np.asarray(pending)
+    assert got.shape == (40,)
+    assert np.asarray(pending) is got            # awaited once, then kept
+    words = agg.pack_requests(x)
+    np.testing.assert_array_equal(
+        got, np.asarray(agg.bitnet.classify_packed(words, 40, 5)))
+    np.testing.assert_array_equal(got, ref(x))
+
+
+def test_device_aggregator_threaded_trace_reconciles(jsc_small):
+    """Threaded serving on the device engine: the labels are awaited on
+    the completion thread, every batch's h2d/device_exec/fetch spans
+    carry its own batch id, and the trace passes the trace checks and
+    the phase reconciliation."""
+    from repro.check.tracecheck import check_phase_reconciliation, check_trace
+    from repro.obs import SpanTracer
+    _, xte = jsc_small
+    agg, ref = _device_aggregator(jsc_small)
+    tracer = SpanTracer()
+    # 24 requests of 8 rows: three full batches, each launched while
+    # the one before may still be in flight
+    s = MicroBatchScheduler(agg, SchedConfig(max_batch=64, max_wait_us=1e6,
+                                             max_queue=400),
+                            tracer=tracer).start()
+    try:
+        futs = [s.submit(xte[i: i + 8]) for i in range(0, 192, 8)]
+        got = np.concatenate([f.result(60) for f in futs])
+    finally:
+        s.stop()
+    np.testing.assert_array_equal(got, ref(xte[:192]))
+    evs = tracer.events()
+    rep = check_trace(evs)
+    check_phase_reconciliation(evs, report=rep)
+    assert rep.ok, rep.format()
+    spans = {n: [e for e in evs if e.ph == "X" and e.name == n]
+             for n in ("batch_form", "h2d", "device_exec", "fetch",
+                       "collect")}
+    ids = sorted(e.args["batch"] for e in spans["batch_form"])
+    for name in ("h2d", "device_exec", "fetch", "collect"):
+        assert sorted(e.args["batch"] for e in spans[name]) == ids, name
+    # launched on the dispatch thread, awaited on the completion thread
+    (launch_tid,) = {e.tid for e in spans["device_exec"]}
+    (collect_tid,) = {e.tid for e in spans["fetch"] + spans["collect"]}
+    assert launch_tid != collect_tid
 
 
 def test_serve_queue_wrapper_reports_true_latency(jsc_small):

@@ -190,8 +190,9 @@ def test_streamed_fetch_span_carries_the_plan_counts(built):
     bn.tracer = tr
     words = pack_bits(np.random.default_rng(1).integers(
         0, 2, (mapped.n_pis, 64)).astype(np.uint8))
-    bn.classify_packed(words, 64, 5)
-    bn.classify_packed(words, 64, 5)
+    # the fetch span is the wait for the launched program's labels
+    np.asarray(bn.classify_packed(words, 64, 5))
+    np.asarray(bn.classify_packed(words, 64, 5))
     fetch = [e for e in tr.events() if e.name == "fetch"]
     assert len(fetch) == 2 and fetch[0].args is fetch[1].args
     tp = bn.executor.tp
