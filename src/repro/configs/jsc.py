@@ -14,8 +14,11 @@ synthetic data — see DESIGN.md §7.
 These presets are cuts, sized for training and tests. ``JSC_M`` here is
 64-32-32-5 with 2-bit codes; the published LogicNets JSC-M
 (16-64-32-32-32-5, fan-in 4, 3-bit codes in every layer) is
-``bench/configs/jsc-m.json``, which the benchmark serves from a netlist
-synthesized ahead of time (``repro.synth.store``).
+``bench/configs/jsc-m.json``. ``JSC_L`` here is 32-64-192-16-5 with
+2-bit hidden codes; the published LogicNets JSC-L (16-32-64-192-192-16-5,
+fan-in 4, 3-bit codes in every layer) is ``bench/configs/jsc-l.json``.
+The benchmark serves both published nets from netlists synthesized ahead
+of time (``repro.synth.store``).
 """
 from repro.models.mlp import MLPConfig
 

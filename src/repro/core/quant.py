@@ -245,11 +245,15 @@ def encode_inputs_host(spec: ActQuantSpec, x, alpha) -> np.ndarray:
     """Numpy twin of ``encode_levels(spec, apply_act_quant(spec, x, alpha),
     alpha)``: real inputs -> int32 level codes, on the host.
 
-    Every intermediate is float32 and in the jax path's order (clip,
-    ``scale = n / max(alpha, 1e-8)``, round half to even, divide, then
-    ``round(q * n / alpha)`` shifted and clipped to the codes), so the
-    codes are bit-identical to it. Two places follow XLA where numpy
-    differs: XLA reads subnormals as zero, so the sign test is
+    ``alpha`` is read as float32, the parameter's precision. The
+    multi-level kinds (``pact``, ``signed``) round the exact product
+    ``clip(x, lo, alpha) * n / alpha``, half to even: it is formed in
+    float64, where a float32 input times ``n`` loses nothing. The jax
+    path forms it in float32, which can round a product just above a
+    half-integer onto it (``0.8333334 * 3`` -> 2.5 -> code 5 instead of
+    6), so the two agree except at such ties. The one-level kinds are
+    bit-identical to the jax path, and two places follow XLA where
+    numpy differs: XLA reads subnormals as zero, so the sign test is
     ``x > -tiny`` (a negative subnormal is ``>= 0`` there), and XLA
     converts a NaN to code 0, so the last clip drops NaNs
     (``fmax``/``fmin``).
@@ -263,20 +267,18 @@ def encode_inputs_host(spec: ActQuantSpec, x, alpha) -> np.ndarray:
     if spec.kind == "binary":
         q = (x >= np.float32(0.5)).astype(np.float32) * a
         return (q > a * np.float32(0.5)).astype(np.int32)
+    a = np.float64(a)
     if spec.kind == "pact":
         n = (1 << spec.bits) - 1
-        lo, shift, top = np.float32(0), 0, n
+        lo, shift, top = 0.0, 0, n
     elif spec.kind == "signed":
         n = (1 << (spec.bits - 1)) - 1
         lo, shift, top = -a, n, 2 * n
     else:
         raise ValueError(spec.kind)
-    nf = np.float32(n)
-    scale = nf / np.maximum(a, np.float32(1e-8))
-    q = np.rint(np.clip(x, lo, a) * scale) / scale
-    codes = np.rint(q * nf / a) + np.float32(shift)
-    return np.fmin(np.fmax(codes, np.float32(0)),
-                   np.float32(top)).astype(np.int32)
+    scale = n / np.maximum(a, 1e-8)
+    codes = np.rint(np.clip(x.astype(np.float64), lo, a) * scale) + shift
+    return np.fmin(np.fmax(codes, 0.0), float(top)).astype(np.int32)
 
 
 def decode_levels(spec: ActQuantSpec, codes: Array, alpha: float) -> Array:

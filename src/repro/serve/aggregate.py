@@ -68,7 +68,7 @@ class BitplaneAggregator:
     def pack_requests(self, x: np.ndarray) -> np.ndarray:
         """(B, n_features) real inputs -> (n_pi_wires, ceil(B/32)) words.
 
-        The input quantizer runs on the host (float32 numpy), so the
+        The input quantizer runs on the host (numpy), so the
         pack makes no device transfer. With ``pad_rows`` set, short
         batches are zero-padded to that row count first: one word count
         for the device program, so its kernel and argmax compile once

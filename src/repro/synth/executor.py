@@ -711,8 +711,9 @@ class BitplaneNetwork:
 
     def quantize_codes(self, x) -> np.ndarray:
         """Real inputs -> (B, n_inputs) int32 input codes, quantized on
-        the host (float32 numpy, bit-identical to
-        ``LogicNetwork.quantize_inputs``)."""
+        the host (numpy; the exact product's codes, which
+        ``LogicNetwork.quantize_inputs`` gives except at float32 ties,
+        see ``encode_inputs_host``)."""
         return encode_inputs_host(self.net.in_spec, x, self.net.in_alpha)
 
     def apply_codes(self, codes: np.ndarray) -> np.ndarray:

@@ -26,8 +26,9 @@ N_CLASSES = 5
 # JSC-S as served: 16 inputs x 2 bits = 32 PIs, ~90 LUTs in 4 levels;
 # loadgen's max_batch=256 rows pack into W = 8 words.
 JSC_S = dict(n_pis=32, n_tiles=4, gather_cap=96)
-# JSC-L-sized tile plan: 16 inputs x 3 bits = 48 PIs, ~12k LUT slots.
-JSC_L = dict(n_pis=48, n_tiles=380, gather_cap=192)
+# JSC-L as served (bench/configs/jsc-l.json): 48 PIs, 139,296 LUTs in
+# 4,369 tiles of 32 slots, up to 154 staged leaf rows per tile.
+JSC_L = dict(n_pis=48, n_tiles=4369, gather_cap=154)
 # JSC-M as served (bench/configs/jsc-m.json): 48 PIs, 60,415 LUTs in
 # 1,902 tiles of 32 slots, up to 159 staged leaf rows per tile.
 JSC_M = dict(n_pis=48, n_tiles=1902, gather_cap=159)
@@ -96,7 +97,9 @@ def test_streamed_dma_kernel_compiles_jsc_s(one_chip, w, gather):
 @pytest.mark.parametrize("gather", ["dma", "vmem"])
 @pytest.mark.parametrize("w", [8, 256])
 def test_streamed_dma_kernel_compiles_jsc_l(one_chip, w, gather):
-    """A JSC-L-sized plan, with the plane in HBM and in VMEM."""
+    """JSC-L's served plan: its 139,857-row plane (71.6 MB at 128
+    lanes) over the VMEM budget, so served from HBM with staged leaf
+    rows; and the same plan with the plane resident."""
     _custom_call_hlo(_compile_streamed(
         one_chip, JSC_L["n_pis"], JSC_L["n_tiles"], JSC_L["gather_cap"], w,
         gather))
